@@ -595,6 +595,7 @@ def bench_async_saturation(model, params, cfg, *, requests=8, max_new=8,
 _SHARDED_PROG = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["JAX_PLATFORMS"] = "cpu"
 import json, time
 import jax
 import jax.numpy as jnp
@@ -664,6 +665,9 @@ def bench_sharded(*, requests=4, max_new=6):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
         [p for p in (env.get("PYTHONPATH"), "src") if p])
+    # the child is a fake-device CPU run; pinning the platform keeps it
+    # off an accelerator the parent process already holds
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", prog], capture_output=True,
                        text=True, timeout=570, env=env)
     assert r.returncode == 0, r.stderr[-3000:]
@@ -849,6 +853,8 @@ def main():
                     help="add the sharded-vs-single section (spawns an "
                          "8-fake-device subprocess; ~1 min extra)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import setup_compile_cache
+    print(f"serve,compile_cache={setup_compile_cache()}")
     run(json_path=args.json, requests=args.requests, max_new=args.max_new,
         bits=args.bits, sharded=args.sharded, bench_json=args.bench_json,
         trace_out=args.trace_out)

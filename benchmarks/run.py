@@ -34,6 +34,8 @@ def main() -> None:
     p.add_argument("--only", default="")
     args = p.parse_args()
 
+    from repro.launch.compile_cache import setup_compile_cache
+    print(f"compile_cache,{setup_compile_cache()}")
     failures = []
     for name, mod_name in BENCHES:
         if args.only and args.only not in name:

@@ -21,9 +21,9 @@ The ``dense``/``bcq_xla`` paths are *kind-aware* through
 XLA fallback; only the per-plane ``bcq_xla_planes`` contraction is
 BCQ-specific.
 
-The Pallas backends target TPU; on this CPU container they run
-under ``interpret=True`` (set ``repro.core.lut_gemm.INTERPRET = True`` —
-done automatically when no TPU is present).
+The Pallas backends target TPU; off-TPU they run under
+``interpret=True``.  :func:`interpret_mode` decides that at each launch,
+never at import (importing ``repro`` initializes no JAX backend).
 
 Launch geometry for the Pallas backends (block sizes, LUT read mode,
 hFFLUT) is resolved per call through :mod:`repro.tune` — tuned JSON-cache
@@ -43,9 +43,10 @@ from repro.core.bcq import BCQWeight, dequantize, unpack_planes
 Backend = Literal["dense", "bcq_xla", "lut_pallas", "mxu_pallas",
                   "ternary_pallas"]
 
-# interpret=True when running on CPU (kernel tests / examples); the dry-run
-# and production configs use bcq_xla for traced code anyway.
-INTERPRET = jax.default_backend() != "tpu"
+def interpret_mode() -> bool:
+    """True off-TPU: Pallas kernels then run in the interpreter (tests,
+    examples).  Resolved where a kernel launches."""
+    return jax.default_backend() != "tpu"
 
 
 def bcq_xla_matmul(x: jax.Array, w: BCQWeight, out_dtype=None) -> jax.Array:
@@ -116,11 +117,11 @@ def bcq_apply(x: jax.Array, w: BCQWeight, backend: Backend = "bcq_xla",
     if backend == "lut_pallas":
         from repro.kernels.lut_gemm import lut_gemm
         # block sizes / read mode resolved via repro.tune dispatch
-        return lut_gemm(x, w, interpret=INTERPRET, out_dtype=out_dtype)
+        return lut_gemm(x, w, interpret=interpret_mode(), out_dtype=out_dtype)
     if backend == "mxu_pallas":
         from repro.kernels.bcq_matmul import bcq_matmul
-        return bcq_matmul(x, w, interpret=INTERPRET, out_dtype=out_dtype)
+        return bcq_matmul(x, w, interpret=interpret_mode(), out_dtype=out_dtype)
     if backend == "ternary_pallas":
         from repro.kernels.ternary_matmul import ternary_matmul
-        return ternary_matmul(x, w, interpret=INTERPRET, out_dtype=out_dtype)
+        return ternary_matmul(x, w, interpret=interpret_mode(), out_dtype=out_dtype)
     raise ValueError(f"unknown backend {backend!r}")

@@ -49,6 +49,7 @@ __all__ = [
     "pack_planes",
     "unpack_planes",
     "dequantize",
+    "bit_slabs",
     "tile_operands",
 ]
 
@@ -187,6 +188,16 @@ def dequantize(w: PlaneBundle, dtype=jnp.float32) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
+def bit_slabs(xp: jax.Array) -> jax.Array:
+    """[B, N] (N % 8 == 0) -> f32 [8, B, N/8] activation bit slabs:
+    ``out[s, b, j] = x[b, 8j + s]`` is the activation that pairs with
+    bit ``s`` of packed byte ``j`` — the plane kernels unpack each bit
+    with one shift-and-mask instead of interleaving bits back into
+    weight order in-kernel."""
+    b, n = xp.shape
+    return xp.astype(jnp.float32).reshape(b, n // 8, 8).transpose(2, 0, 1)
+
+
 def tile_operands(x2: jax.Array, w: PlaneBundle, *, block_b: int,
                   block_m: int, block_n: int
                   ) -> Tuple[jax.Array, jax.Array, jax.Array,
@@ -194,16 +205,17 @@ def tile_operands(x2: jax.Array, w: PlaneBundle, *, block_b: int,
     """Pad (activations, bundle) out to kernel-launch geometry.
 
     x2: [b, in_features] flattened activation batch.  Returns
-    ``(xp, packed, alpha, z, b, m, block_m, block_n)`` where every array
-    is zero-padded to block multiples: xp [bp, npad], packed
-    [q, mp, npad//8], alpha [n_alpha, mp, agp], z [mp, agp] or None.
-    ``block_m``/``block_n`` come back clamped to the (row-aligned,
-    group-aligned) weight extents so callers pass the effective values
-    to the tiled launcher.
+    ``(xs, packed, alpha, z, b, m, block_m, block_n)`` where every array
+    is zero-padded to block multiples: xs f32 [8, bp, npad//8] activation
+    bit slabs (:func:`bit_slabs`), packed [q, mp, npad//8], alpha
+    [n_alpha, mp, agp], z [mp, agp] or None.  ``block_m``/``block_n``
+    come back clamped to the (row-aligned, group-aligned) weight extents
+    so callers pass the effective values to the tiled launcher.
 
     Zero padding is correct for every kind: padded x columns contribute
-    0 to LUT entries and activation-sums alike, and padded weight rows
-    produce garbage only in output rows that are sliced off ([:b, :m]).
+    0 to LUT entries and activation-sums alike, padded groups carry zero
+    scales, and padded weight rows produce garbage only in output rows
+    that are sliced off ([:b, :m]).
     """
     b = x2.shape[0]
     q, m, _ = w.packed.shape
@@ -227,4 +239,4 @@ def tile_operands(x2: jax.Array, w: PlaneBundle, *, block_b: int,
             .at[:, :m, :ag].set(alpha)
         if z is not None:
             z = jnp.zeros((mp, agp), z.dtype).at[:m, :ag].set(z)
-    return xp, packed, alpha, z, b, m, block_m, block_n
+    return bit_slabs(xp), packed, alpha, z, b, m, block_m, block_n

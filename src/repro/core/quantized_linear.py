@@ -32,19 +32,24 @@ def set_capture(fn):
 
 
 def linear_apply(w, x: jax.Array, bias: Optional[jax.Array] = None,
-                 backend: Optional[str] = None, out_dtype=None) -> jax.Array:
+                 backend: Optional[str] = None, out_dtype=None,
+                 row_parallel: bool = False) -> jax.Array:
     """y = x @ W^T (+ bias).  W is dense [out, in] or BCQWeight.
 
     ``backend``: preference name from the registry ("auto"/None, "dense",
     "bcq_xla", "lut_pallas", "mxu_pallas", ...) — resolution and fallback
     happen in :func:`repro.quant.backends.execute_linear`.
+    ``row_parallel`` marks a linear whose INPUT dim is model-sharded
+    (attention out, MLP down): under a serving mesh its kernel runs per
+    input shard and the partial products are summed.
     """
     if _CAPTURE is not None:
         _CAPTURE(w, x)
     # function-level import: quant.backends imports core submodules, so a
     # module-level import would be order-sensitive during package init
     from repro.quant.backends import execute_linear
-    y = execute_linear(x, w, backend=backend, out_dtype=out_dtype or x.dtype)
+    y = execute_linear(x, w, backend=backend, out_dtype=out_dtype or x.dtype,
+                       row_parallel=row_parallel)
     if bias is not None:
         y = y + bias.astype(y.dtype)
     return y

@@ -5,13 +5,14 @@ MXU performs a 128x128 systolic matmul at fixed cost whether operands are
 +-1 or arbitrary bf16.  The *transferable* win of the BCQ format on TPU is
 that weights live in HBM as packed uint8 bit-planes (q/16 of bf16 bytes):
 LLM decode is memory-bound, so cutting weight bytes moves the memory-
-roofline term directly (DESIGN.md §2).
+roofline term directly.
 
-This kernel streams packed planes HBM->VMEM, reconstructs the dense weight
-tile in VMEM (q shift/mask unpacks + alpha-scaled accumulate + offset), and
-issues a single MXU matmul per tile.  Same math as the LUT kernel, same
-compressed storage, MXU-optimal compute — it is the "optimized version"
-reported next to the paper-faithful kernel in EXPERIMENTS.md §Perf.
+This kernel streams packed planes HBM->VMEM and, per byte chunk of the
+reduction dim, reconstructs the weight tile in VMEM one bit slab at a
+time (shift/mask unpack + alpha-scaled accumulate + offset) and issues
+one MXU matmul per slab against the matching activation slab.  Same math
+as the LUT kernel, same compressed storage, MXU-native compute.  The bit
+slab layout is described in ``kernels/lut_common.py``.
 """
 from __future__ import annotations
 
@@ -19,76 +20,52 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.experimental import pallas as pl
 
-
-def _unpack_pm1(packed_tile: jax.Array) -> jax.Array:
-    """uint8[TM, TN//8] -> f32 {-1,+1} [TM, TN] (LSB-first)."""
-    tm, nb = packed_tile.shape
-    p32 = packed_tile.astype(jnp.int32)
-    cols = [((p32 >> s) & 1) for s in range(8)]
-    bits = jnp.stack(cols, axis=-1).reshape(tm, nb * 8)
-    return bits.astype(jnp.float32) * 2.0 - 1.0
+from repro.kernels.lut_common import (dot_nt, expand_groups, for_each_chunk,
+                                      plane_call, plane_pm1)
 
 
 def _bcq_matmul_kernel(x_ref, packed_ref, alpha_ref, z_ref, o_ref, *,
-                       group_size: int):
-    q = packed_ref.shape[0]
-    tb, tn = x_ref.shape
-    tm = packed_ref.shape[1]
-    tag = alpha_ref.shape[-1]
+                       group_size: int, chunk: int):
+    q, _, n_bytes = packed_ref.shape
+    gb = group_size // 8
 
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
+    def body(off, acc):
+        cols = pl.ds(off, chunk)
+        planes = [packed_ref[i, :, cols].astype(jnp.int32) for i in range(q)]
+        alphas = [expand_groups(alpha_ref[i], off, chunk, gb)
+                  for i in range(q)]
+        zc = expand_groups(z_ref[...], off, chunk, gb)
+        for s in range(8):
+            # dequantized slab s:  W = sum_i alpha_i * B_i + z
+            w = alphas[0] * plane_pm1(planes[0], s)
+            for i in range(1, q):
+                w = w + alphas[i] * plane_pm1(planes[i], s)
+            acc = acc + dot_nt(x_ref[s, :, cols], w + zc)
+        return acc
 
-    # dequantize the weight tile in VMEM:  W = sum_i alpha_i * B_i + z
-    w = jnp.zeros((tm, tn), jnp.float32)
-    for i in range(q):
-        pm1 = _unpack_pm1(packed_ref[i])                     # [TM, TN]
-        alpha_cols = jnp.broadcast_to(
-            alpha_ref[i][:, :, None].astype(jnp.float32),
-            (tm, tag, group_size)).reshape(tm, tn)
-        w = w + alpha_cols * pm1
-    z_cols = jnp.broadcast_to(
-        z_ref[...][:, :, None].astype(jnp.float32),
-        (tm, tag, group_size)).reshape(tm, tn)
-    w = w + z_cols
-
-    x = x_ref[...].astype(jnp.float32)
-    o_ref[...] += lax.dot_general(
-        x, w, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(o_ref.dtype)
+    o_ref[...] = for_each_chunk(n_bytes, chunk, body,
+                                jnp.zeros(o_ref.shape, jnp.float32))
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("group_size", "block_b", "block_m", "block_n",
-                     "interpret", "out_dtype"),
+                     "interpret"),
 )
-def bcq_matmul_tiled(x, packed, alpha, z, *, group_size: int = 128,
-                     block_b: int = 8, block_m: int = 128, block_n: int = 512,
-                     interpret: bool = False, out_dtype=jnp.float32):
-    """Raw tiled call; dims must divide blocks. x:[B,N] -> [B,M]."""
-    b, n = x.shape
-    q, m, _ = packed.shape
-    assert n % block_n == 0 and m % block_m == 0 and b % block_b == 0
-    assert block_n % group_size == 0
-    tag = block_n // group_size
-    grid = (b // block_b, m // block_m, n // block_n)
-    kernel = functools.partial(_bcq_matmul_kernel, group_size=group_size)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_b, block_n), lambda bi, mi, ni: (bi, ni)),
-            pl.BlockSpec((q, block_m, block_n // 8),
-                         lambda bi, mi, ni: (0, mi, ni)),
-            pl.BlockSpec((q, block_m, tag), lambda bi, mi, ni: (0, mi, ni)),
-            pl.BlockSpec((block_m, tag), lambda bi, mi, ni: (mi, ni)),
-        ],
-        out_specs=pl.BlockSpec((block_b, block_m), lambda bi, mi, ni: (bi, mi)),
-        out_shape=jax.ShapeDtypeStruct((b, m), out_dtype),
-        interpret=interpret,
-    )(x, packed, alpha, z)
+def bcq_matmul_tiled(xs, packed, alpha, z, *, group_size: int = 128,
+                     block_b: int = 8, block_m: int = 128,
+                     block_n: int = 1024, interpret: bool = False):
+    """Raw tiled call; dims must divide blocks.  xs: f32 [8, B, N//8]
+    activation bit slabs -> f32 [B, M].  ``block_n`` is the reduction
+    chunk in weights."""
+    _, b, nb = xs.shape
+    _, m, _ = packed.shape
+    chunk = block_n // 8
+    assert nb % chunk == 0 and m % block_m == 0 and b % block_b == 0
+    assert block_n % group_size == 0 and group_size % 8 == 0
+    kernel = functools.partial(_bcq_matmul_kernel, group_size=group_size,
+                               chunk=chunk)
+    return plane_call(kernel, xs, packed, alpha, z, block_b=block_b,
+                      block_m=block_m, interpret=interpret)

@@ -39,11 +39,10 @@ def bcq_matmul(x: jax.Array, w: BCQWeight, *, block_b: Optional[int] = None,
         block_m = cfg.block_m if block_m is None else block_m
         block_n = cfg.block_n if block_n is None else block_n
 
-    xp, packed, alpha, z, b, m, block_m, block_n = tile_operands(
+    xs, packed, alpha, z, b, m, block_m, block_n = tile_operands(
         x2, w, block_b=block_b, block_m=block_m, block_n=block_n)
 
     y = _k.bcq_matmul_tiled(
-        xp, packed, alpha, z, group_size=w.group_size, block_b=block_b,
-        block_m=block_m, block_n=block_n, interpret=interpret,
-        out_dtype=jnp.float32)
+        xs, packed, alpha, z, group_size=w.group_size, block_b=block_b,
+        block_m=block_m, block_n=block_n, interpret=interpret)
     return y[:b, :m].reshape(*lead, m).astype(out_dtype)

@@ -1,30 +1,27 @@
 """Pallas TPU kernel for FIGLUT's LUT-based FP-INT GEMM (paper §III).
 
-Per (batch-tile, out-tile, in-tile) grid cell:
+Per (out-tile, batch-tile) grid cell, over byte chunks of the reduction
+dim (``block_n`` weights per chunk):
 
-  1. **LUT generation** (§III-E): the activation tile x[TB, TN] is reshaped
-     into mu-groups and multiplied by the +-1 sign matrix — a (G, mu)x(mu, P)
-     matmul that runs on the MXU, the systolic analogue of the paper's
-     two-step adder tree.  With ``half_lut=True`` only the MSB=1 half of the
-     table is built (hFFLUT, §III-D).
-  2. **RAC** (§III-C): every output row's mu-bit weight pattern keys a read
-     from the VMEM-resident LUT.  VMEM has no banking at the Pallas
-     programming-model level, so k = TM concurrent readers are conflict-free
-     by construction — the software realization of the FFLUT+mux design.
-     Reads are implemented either as a 2^mu-way select sweep (``select``,
-     VPU, mirrors the paper's mux) or as a one-hot contraction (``onehot``,
-     MXU-friendly).
-  3. **bit-serial accumulate** (§III-B): plane value sums are grouped per
-     alpha-group, scaled by alpha_i, and accumulated in FP32; the offset term
-     z * sum(x_group) (Eq. (3)) is folded in once per tile.
+  1. **LUT generation** (§III-E): the chunk's activation bit slabs are
+     combined into the mu-group tables — entry p of a key position is
+     the signed sum of its mu slabs.  With ``half_lut=True`` only the
+     MSB=1 half of each table is built (hFFLUT, §III-D).
+  2. **RAC** (§III-C): every output row's mu-bit weight pattern keys a
+     read from the VMEM-resident table, either as a one-hot contraction
+     per entry on the MXU (``onehot``) or as a 2^mu-way mux sweep on the
+     VPU (``select``, the paper's multiplexer).  VMEM has no banking at
+     the Pallas level, so the k = block_m concurrent readers are
+     conflict-free by construction.
+  3. **bit-serial accumulate** (§III-B): each plane's reads are weighted
+     by its per-group alpha (expanded to the chunk's bytes) and summed
+     in FP32; the offset term z * sum(x_group) (Eq. (3)) is folded in
+     per chunk.
 
 Storage streamed from HBM is the *packed* uint8 bit-planes — q/16 of the
-bf16 dense bytes — which is the memory-roofline win on TPU (DESIGN.md §2).
-
-Weight-stationary note: the grid iterates n (reduction) innermost and m
-before b, so a weight tile's packed planes stay resident while batch tiles
-stream — matching the paper's weight-stationary dataflow (§III-B) at the
-granularity Pallas exposes (block revisiting, not per-PE registers).
+bf16 dense bytes — which is the memory-roofline win on TPU.  Layout
+choices that keep the body compilable are documented in
+``kernels/lut_common.py``.
 """
 from __future__ import annotations
 
@@ -34,84 +31,65 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-# LUT build / key extraction / half-table sign-decode read are shared
-# with the dedicated ternary kernel — one home for the hFFLUT math.
-from repro.kernels.lut_common import (ReadMode, build_lut, extract_keys,
-                                      read_lut)
+from repro.kernels.lut_common import (ReadMode, build_lut, dot_nt,
+                                      expand_groups, for_each_chunk,
+                                      plane_call, read_lut)
 
 
 def _lut_gemm_kernel(x_ref, packed_ref, alpha_ref, z_ref, o_ref, *,
                      mu: int, half_lut: bool, group_size: int,
-                     read_mode: ReadMode, n_grid: int):
-    q = packed_ref.shape[0]
-    tb, tn = x_ref.shape
-    tm = packed_ref.shape[1]
-    tag = alpha_ref.shape[-1]
+                     read_mode: ReadMode, chunk: int):
+    q, _, n_bytes = packed_ref.shape
+    gb = group_size // 8
+    keys_per_byte = 8 // mu
+    key_mask = (1 << mu) - 1
 
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
+    def body(off, acc):
+        cols = pl.ds(off, chunk)
+        slabs = [x_ref[s, :, cols] for s in range(8)]
+        tables = [build_lut(slabs, h, mu, half_lut)
+                  for h in range(keys_per_byte)]
+        for i in range(q):
+            byte = packed_ref[i, :, cols].astype(jnp.int32)
+            a = expand_groups(alpha_ref[i], off, chunk, gb)
+            for h in range(keys_per_byte):
+                acc = acc + read_lut(tables[h], (byte >> (mu * h)) & key_mask,
+                                     a, mu, half_lut, read_mode)
+        # offset term: z[m, G(n)] * x[b, n] summed over the chunk's bytes
+        xsum = functools.reduce(lambda u, v: u + v, slabs)
+        return acc + dot_nt(xsum, expand_groups(z_ref[...], off, chunk, gb))
 
-    x = x_ref[...].astype(jnp.float32)                    # [TB, TN]
-
-    # -- 1. LUT generation (MXU): groups @ S^T ----------------------------
-    lut = build_lut(x, mu, half_lut)                      # [TB, G, P]
-
-    # -- 2/3. per-plane RAC + alpha accumulate ----------------------------
-    per_ag = group_size // mu
-    acc = jnp.zeros((tb, tm), jnp.float32)
-    for i in range(q):
-        keys = extract_keys(packed_ref[i], mu)            # [TM, G]
-        vals = read_lut(lut, keys, mu, half_lut, read_mode)    # [TB, TM, G]
-        vals_ag = vals.reshape(tb, tm, tag, per_ag).sum(-1)    # [TB, TM, AG]
-        alpha_i = alpha_ref[i].astype(jnp.float32)        # [TM, AG]
-        acc = acc + jnp.einsum("bma,ma->bm", vals_ag, alpha_i,
-                               preferred_element_type=jnp.float32)
-    # offset term  z[m,AG] * sum_G x   (Eq. (3))
-    xsum = x.reshape(tb, tag, group_size).sum(-1)         # [TB, AG]
-    acc = acc + jnp.einsum("ba,ma->bm", xsum, z_ref[...].astype(jnp.float32),
-                           preferred_element_type=jnp.float32)
-
-    o_ref[...] += acc.astype(o_ref.dtype)
+    o_ref[...] = for_each_chunk(n_bytes, chunk, body,
+                                jnp.zeros(o_ref.shape, jnp.float32))
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("mu", "half_lut", "group_size", "read_mode",
-                     "block_b", "block_m", "block_n", "interpret", "out_dtype"),
+                     "block_b", "block_m", "block_n", "interpret"),
 )
-def lut_gemm_tiled(x, packed, alpha, z, *, mu: int = 4, half_lut: bool = True,
-                   group_size: int = 128, read_mode: ReadMode = "onehot",
-                   block_b: int = 8, block_m: int = 128, block_n: int = 512,
-                   interpret: bool = False, out_dtype=jnp.float32):
-    """Raw tiled kernel call. All dims must already divide the block sizes.
+def lut_gemm_tiled(xs, packed, alpha, z, *, mu: int = 4,
+                   half_lut: bool = True, group_size: int = 128,
+                   read_mode: ReadMode = "onehot", block_b: int = 8,
+                   block_m: int = 128, block_n: int = 1024,
+                   interpret: bool = False):
+    """Raw tiled kernel call. All dims must already divide the blocks.
 
-    x: [B, N] fp; packed: uint8[q, M, N//8]; alpha: f32[q, M, N//group_size];
-    z: f32[M, N//group_size].  Returns [B, M] out_dtype (FP32 accumulation).
+    xs: f32 [8, B, N//8] activation bit slabs
+    (``core.plane.bit_slabs``); packed: uint8[q, M, N//8];
+    alpha: f32[q, M, N//group_size]; z: f32[M, N//group_size].
+    ``block_n`` is the reduction chunk in weights.  Returns f32 [B, M].
     """
-    b, n = x.shape
-    q, m, _ = packed.shape
-    assert n % block_n == 0 and m % block_m == 0 and b % block_b == 0, (
-        f"shapes ({b},{m},{n}) must divide blocks ({block_b},{block_m},{block_n})")
-    assert block_n % group_size == 0 and group_size % mu == 0
-    tag = block_n // group_size
-    grid = (b // block_b, m // block_m, n // block_n)
-
+    _, b, nb = xs.shape
+    _, m, _ = packed.shape
+    chunk = block_n // 8
+    assert nb % chunk == 0 and m % block_m == 0 and b % block_b == 0, (
+        f"shapes ({b},{m},{nb * 8}) must divide blocks "
+        f"({block_b},{block_m},{block_n})")
+    assert block_n % group_size == 0 and group_size % 8 == 0
+    assert 8 % mu == 0, f"mu={mu} must divide 8"
     kernel = functools.partial(
         _lut_gemm_kernel, mu=mu, half_lut=half_lut, group_size=group_size,
-        read_mode=read_mode, n_grid=grid[2])
-
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_b, block_n), lambda bi, mi, ni: (bi, ni)),
-            pl.BlockSpec((q, block_m, block_n // 8),
-                         lambda bi, mi, ni: (0, mi, ni)),
-            pl.BlockSpec((q, block_m, tag), lambda bi, mi, ni: (0, mi, ni)),
-            pl.BlockSpec((block_m, tag), lambda bi, mi, ni: (mi, ni)),
-        ],
-        out_specs=pl.BlockSpec((block_b, block_m), lambda bi, mi, ni: (bi, mi)),
-        out_shape=jax.ShapeDtypeStruct((b, m), out_dtype),
-        interpret=interpret,
-    )(x, packed, alpha, z)
+        read_mode=read_mode, chunk=chunk)
+    return plane_call(kernel, xs, packed, alpha, z, block_b=block_b,
+                      block_m=block_m, interpret=interpret)

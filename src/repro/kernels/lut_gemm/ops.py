@@ -53,13 +53,11 @@ def lut_gemm(x: jax.Array, w: BCQWeight, *, mu: int = 4,
         block_m = cfg.block_m if block_m is None else block_m
         block_n = cfg.block_n if block_n is None else block_n
 
-    xp, packed, alpha, z, b, m, block_m, block_n = tile_operands(
+    xs, packed, alpha, z, b, m, block_m, block_n = tile_operands(
         x2, w, block_b=block_b, block_m=block_m, block_n=block_n)
 
     y = _k.lut_gemm_tiled(
-        xp, packed, alpha, z, mu=mu, half_lut=half_lut,
+        xs, packed, alpha, z, mu=mu, half_lut=half_lut,
         group_size=w.group_size, read_mode=read_mode, block_b=block_b,
-        block_m=block_m, block_n=block_n, interpret=interpret,
-        out_dtype=jnp.float32,
-    )
+        block_m=block_m, block_n=block_n, interpret=interpret)
     return y[:b, :m].reshape(*lead, m).astype(out_dtype)

@@ -105,11 +105,11 @@ def paged_attention_int8(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     # q scaling still happens in f32 before the rounding
     qg = (q.reshape(b, hkv, rep, d).astype(jnp.float32) * scale
           ).astype(jnp.bfloat16)
-    out = _k.paged_attention_int8_tiled(
-        qg, k_pool, v_pool, k_scale, v_scale,
-        jnp.asarray(pos_pool, jnp.int32), jnp.asarray(tables, jnp.int32),
-        jnp.asarray(positions, jnp.int32),
-        block_size=bs, block_h=block_h, interpret=interpret)
+    out = _k.paged_attention_tiled(
+        qg, k_pool, v_pool, jnp.asarray(pos_pool, jnp.int32),
+        jnp.asarray(tables, jnp.int32), jnp.asarray(positions, jnp.int32),
+        k_scale, v_scale, block_size=bs, block_h=block_h,
+        interpret=interpret)
     return out.reshape(b, h, d).astype(out_dtype or q.dtype)
 
 

@@ -31,6 +31,17 @@ FP32 accumulator, with running max / sum in VMEM scratch.  Rows with no
 live slot at all (idle batch rows parked on the trash block) produce
 zeros — the engine discards their outputs.
 
+Every kernel body is two-dimensional, which is what the TPU compiler
+lowers.  The caller lays the queries of one head tile out as rows
+``[R, D]`` ordered head-major (``R = block_h * rep`` for decode,
+``block_h * rep * C`` for a prefill chunk), so a row's kv head is a
+contiguous row range.  Per pool slot ``i`` the scores of all rows
+against all ``block_h`` keys of that slot are one MXU product
+``[R, D] x [block_h, D]^T``; the row's own head is picked by a
+head-ownership mask and a lane sum, and the values are folded back the
+same way.  The slot positions ride as a ``[NB, 1, BS]`` view so their
+block is whole in its last two dims.
+
 Variant coverage (the FLUTE offline-restructure-then-fuse pattern: all
 layout work happens at quantize/admission time so the in-loop index
 math stays trivial):
@@ -70,228 +81,194 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
+#: scoped VMEM the paged kernels may claim (prefill tiles hold a whole
+#: chunk of query rows)
+VMEM_LIMIT = 64 * 1024 * 1024
 
-def _paged_attn_kernel(tables_ref, qpos_ref, q_ref, k_ref, v_ref, pos_ref,
-                       o_ref, m_ref, l_ref, *, block_size: int, pages: int):
+
+def _dot_nt(a, b):
+    return lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _attend_block(q, k_ref, v_ref, ks_ref, vs_ref, ok, o_ref, m_ref, l_ref,
+                  *, rows_per_head: int, row0=0):
+    """Fold one pool block into the online softmax of the row tile.
+
+    q: [R, D] pre-scaled rows, head-major (rows ``h*rows_per_head ..``
+    of the head tile belong to its kv head h; this row tile starts at
+    row ``row0``); k_ref/v_ref blocks [1, BS, bh, D];
+    ks_ref/vs_ref int8 scale blocks [1, BS, bh] or None; ok: bool
+    [1 or R, BS] liveness.  The int8 fold follows ``decode_attend``:
+    k_scale before the softmax, v_scale on the values only, so ``l``
+    sums UNSCALED probabilities."""
+    r = q.shape[0]
+    bs, bh = k_ref.shape[1], k_ref.shape[2]
+    row = lax.broadcasted_iota(jnp.int32, (r, bh), 0) + row0
+    col = lax.broadcasted_iota(jnp.int32, (r, bh), 1) * rows_per_head
+    own = (row >= col) & (row < col + rows_per_head)       # row's kv head
+    lane = lax.broadcasted_iota(jnp.int32, (r, bs), 1)
+
+    def kv(ref, i):
+        t = ref[0, i]                                       # [bh, D]
+        if t.dtype != q.dtype:                              # int8 pools
+            t = t.astype(jnp.float32).astype(q.dtype)
+        return t
+
+    s = jnp.zeros((r, bs), jnp.float32)
+    for i in range(bs):
+        s_i = _dot_nt(q, kv(k_ref, i))                     # [R, bh]
+        if ks_ref is not None:
+            s_i = s_i * ks_ref[0, pl.ds(i, 1), :]           # dequant fold
+        s_i = jnp.sum(jnp.where(own, s_i, 0.0), axis=1, keepdims=True)
+        s = jnp.where(lane == i, s_i, s)
+    s = jnp.where(ok, s, NEG_INF)
+
+    m_prev = m_ref[...]                                     # [R, 1]
+    l_prev = l_ref[...]
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+    # fully-masked-so-far rows have m == NEG_INF: exp(NEG_INF - NEG_INF)
+    # would be 1, so masked probabilities are forced to 0 explicitly
+    p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    m_ref[...] = m_new
+    l_ref[...] = l_prev * corr + p.sum(axis=1, keepdims=True)
+
+    pv = None
+    for i in range(bs):
+        p_i = jnp.sum(jnp.where(lane == i, p, 0.0), axis=1, keepdims=True)
+        w = jnp.where(own, p_i, 0.0)                        # [R, bh]
+        if vs_ref is not None:
+            w = w * vs_ref[0, pl.ds(i, 1), :]
+        v_i = kv(v_ref, i)
+        part = lax.dot_general(w.astype(v_i.dtype), v_i,
+                               (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+        pv = part if pv is None else pv + part
+    o_ref[0, 0] = o_ref[0, 0] * corr + pv
+
+
+def _init_acc(o_ref, m_ref, l_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def _finish(o_ref, l_ref):
+    # rows with zero live slots keep l == 0 -> output 0 (discarded)
+    o_ref[0, 0] = o_ref[0, 0] / jnp.maximum(l_ref[...], 1e-30)
+
+
+def _slot_live(entry, pos_ref, j, block_size):
+    """[1, BS]: allocated entry and stored position == logical index."""
+    logical = j * block_size + lax.broadcasted_iota(
+        jnp.int32, (1, block_size), 1)
+    slot_pos = pos_ref[0]                                   # [1, BS]
+    return (entry >= 0) & (slot_pos == logical), slot_pos
+
+
+def _paged_decode_kernel(tables_ref, qpos_ref, q_ref, k_ref, v_ref, *rest,
+                         block_size: int, pages: int, rep: int, int8: bool):
+    if int8:
+        ks_ref, vs_ref, pos_ref, o_ref, m_ref, l_ref = rest
+    else:
+        (pos_ref, o_ref, m_ref, l_ref), ks_ref, vs_ref = rest, None, None
     b = pl.program_id(0)
     j = pl.program_id(2)                       # logical page (innermost)
 
     @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        o_ref[...] = jnp.zeros_like(o_ref)
+    def _():
+        _init_acc(o_ref, m_ref, l_ref)
 
-    q = q_ref[0]                               # [bh, rep, d] (pre-scaled)
-    k = k_ref[0]                               # [bs, bh, d]
-    v = v_ref[0]
-    s = jnp.einsum("hrd,khd->hrk", q, k,
-                   preferred_element_type=jnp.float32)   # [bh, rep, bs]
-
-    # liveness mask (the paged_view rule, applied to scores)
-    entry = tables_ref[b, j]
-    qpos = qpos_ref[b]
-    logical = j * block_size + lax.broadcasted_iota(
-        jnp.int32, (1, block_size), 1)
-    slot_pos = pos_ref[...]                    # [1, bs]
-    ok = (entry >= 0) & (slot_pos == logical) & (slot_pos <= qpos)
-    okb = ok[:, None, :]                       # [1, 1, bs] -> broadcast
-    s = jnp.where(okb, s, NEG_INF)
-
-    # online softmax update; the output block is the FP32 accumulator
-    m_prev = m_ref[...]                        # [bh, rep]
-    l_prev = l_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    # fully-masked-so-far rows have m == NEG_INF: exp(NEG_INF - NEG_INF)
-    # would be 1, so masked probabilities are forced to 0 explicitly
-    p = jnp.where(okb, jnp.exp(s - m_new[..., None]), 0.0)
-    corr = jnp.exp(m_prev - m_new)             # [bh, rep]
-    m_ref[...] = m_new
-    l_ref[...] = l_prev * corr + p.sum(axis=-1)
-    pv = jnp.einsum("hrk,khd->hrd", p.astype(v.dtype), v,
-                    preferred_element_type=jnp.float32)
-    o_ref[0] = o_ref[0] * corr[..., None] + pv
+    live, slot_pos = _slot_live(tables_ref[b, j], pos_ref, j, block_size)
+    ok = live & (slot_pos <= qpos_ref[b])      # the paged_view rule
+    _attend_block(q_ref[0, 0], k_ref, v_ref, ks_ref, vs_ref, ok, o_ref,
+                  m_ref, l_ref, rows_per_head=rep)
 
     @pl.when(j == pages - 1)
-    def _finish():
-        l = l_ref[...]
-        # rows with zero live slots keep l == 0 -> output 0 (discarded)
-        o_ref[0] = o_ref[0] / jnp.maximum(l, 1e-30)[..., None]
+    def _():
+        _finish(o_ref, l_ref)
+
+
+def _pool_specs(block_size, block_h, d, int8, prefetch):
+    """BlockSpecs of the pool operands (k, v[, k_scale, v_scale], pos),
+    all addressed through the scalar-prefetched block table; unallocated
+    (-1) entries fetch the trash block 0, whose contents are read but
+    masked by the liveness rule."""
+    def blk(bi, ji, tables):
+        return jnp.maximum(tables[bi, ji], 0)
+
+    if prefetch == 2:                          # decode: (b, h, page)
+        pool = lambda bi, hi, ji, t, qp: (blk(bi, ji, t), 0, hi, 0)
+        scl = lambda bi, hi, ji, t, qp: (blk(bi, ji, t), 0, hi)
+        pos = lambda bi, hi, ji, t, qp: (blk(bi, ji, t), 0, 0)
+    else:                                      # prefill: (b, h, rows, page)
+        pool = lambda bi, hi, ri, ji, t: (blk(bi, ji, t), 0, hi, 0)
+        scl = lambda bi, hi, ri, ji, t: (blk(bi, ji, t), 0, hi)
+        pos = lambda bi, hi, ri, ji, t: (blk(bi, ji, t), 0, 0)
+    specs = [pl.BlockSpec((1, block_size, block_h, d), pool),
+             pl.BlockSpec((1, block_size, block_h, d), pool)]
+    if int8:
+        specs += [pl.BlockSpec((1, block_size, block_h), scl),
+                  pl.BlockSpec((1, block_size, block_h), scl)]
+    specs.append(pl.BlockSpec((1, 1, block_size), pos))
+    return specs
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("block_size", "block_h", "interpret"),
 )
-def paged_attention_tiled(q, k_pool, v_pool, pos_pool, tables, positions, *,
-                          block_size: int, block_h: int,
-                          interpret: bool = False):
-    """Raw tiled kernel call (shapes already grouped / validated).
+def paged_attention_tiled(q, k_pool, v_pool, pos_pool, tables, positions,
+                          k_scale=None, v_scale=None, *, block_size: int,
+                          block_h: int, interpret: bool = False):
+    """Raw tiled decode call (shapes already grouped / validated).
 
-    q: [B, Hkv, rep, D] in KV storage dtype, *pre-scaled* by the caller
-    (scale applied in f32 then rounded to the storage dtype — identical
-    rounding to ``decode_attend``).
-    k_pool / v_pool: [NB, BS, Hkv, D]; pos_pool: int32 [NB, BS].
-    tables: int32 [B, pages]; positions: int32 [B].
-    Returns f32 [B, Hkv, rep, D].  ``block_h`` must divide Hkv.
+    q: [B, Hkv, rep, D] *pre-scaled* by the caller — in the KV storage
+    dtype for float pools (identical rounding to ``decode_attend``), in
+    bf16 for int8 pools.  k_pool / v_pool: [NB, BS, Hkv, D]; pos_pool:
+    int32 [NB, BS]; tables: int32 [B, pages]; positions: int32 [B].
+    ``k_scale``/``v_scale`` (f32 [NB, BS, Hkv]) switch on the int8
+    dequant fold.  Returns f32 [B, Hkv, rep, D].  ``block_h`` must
+    divide Hkv.
     """
     b, hkv, rep, d = q.shape
     nb, bs = pos_pool.shape
     pages = tables.shape[1]
+    int8 = k_scale is not None
     assert hkv % block_h == 0, (hkv, block_h)
     assert bs == block_size and k_pool.shape[:2] == (nb, bs)
+    if int8:
+        assert k_scale.shape == (nb, bs, hkv), (k_scale.shape, (nb, bs, hkv))
+    nhb, r = hkv // block_h, block_h * rep
+    rows = q.reshape(b, nhb, r, d)             # head-major rows per tile
 
-    kernel = functools.partial(_paged_attn_kernel, block_size=block_size,
-                               pages=pages)
-
-    # unallocated (-1) table entries fetch the trash block 0 — its
-    # contents are read but masked by the liveness rule above
+    kernel = functools.partial(_paged_decode_kernel, block_size=block_size,
+                               pages=pages, rep=rep, int8=int8)
+    tile = lambda bi, hi, ji, t, qp: (bi, hi, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                 # tables, positions
-        grid=(b, hkv // block_h, pages),
-        in_specs=[
-            pl.BlockSpec((1, block_h, rep, d),
-                         lambda bi, hi, ji, tables, qpos: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, block_size, block_h, d),
-                         lambda bi, hi, ji, tables, qpos:
-                         (jnp.maximum(tables[bi, ji], 0), 0, hi, 0)),
-            pl.BlockSpec((1, block_size, block_h, d),
-                         lambda bi, hi, ji, tables, qpos:
-                         (jnp.maximum(tables[bi, ji], 0), 0, hi, 0)),
-            pl.BlockSpec((1, block_size),
-                         lambda bi, hi, ji, tables, qpos:
-                         (jnp.maximum(tables[bi, ji], 0), 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_h, rep, d),
-                               lambda bi, hi, ji, tables, qpos:
-                               (bi, hi, 0, 0)),
+        grid=(b, nhb, pages),
+        in_specs=[pl.BlockSpec((1, 1, r, d), tile)]
+        + _pool_specs(block_size, block_h, d, int8, prefetch=2),
+        out_specs=pl.BlockSpec((1, 1, r, d), tile),
         scratch_shapes=[
-            pltpu.VMEM((block_h, rep), jnp.float32),   # running max
-            pltpu.VMEM((block_h, rep), jnp.float32),   # running sum
+            pltpu.VMEM((r, 1), jnp.float32),   # running max
+            pltpu.VMEM((r, 1), jnp.float32),   # running sum
         ],
     )
-    return pl.pallas_call(
+    args = [tables, positions, rows, k_pool, v_pool]
+    if int8:
+        args += [k_scale, v_scale]
+    args.append(pos_pool.reshape(nb, 1, bs))
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, nhb, r, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
-    )(tables, positions, q, k_pool, v_pool, pos_pool)
-
-
-def _paged_attn_int8_kernel(tables_ref, qpos_ref, q_ref, k_ref, v_ref,
-                            ks_ref, vs_ref, pos_ref, o_ref, m_ref, l_ref, *,
-                            block_size: int, pages: int):
-    """int8-KV decode: per-slot scale rows ride the block-table DMA and
-    fold into the score / value epilogues (gathered ``decode_attend``
-    ordering: k_scale before softmax, v_scale after — so ``l`` sums the
-    UNSCALED probabilities)."""
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    q = q_ref[0]                               # [bh, rep, d] bf16 pre-scaled
-    k = k_ref[0].astype(q.dtype)               # [bs, bh, d] int8 -> bf16
-    v = v_ref[0].astype(q.dtype)
-    ks = ks_ref[0]                             # [bs, bh] f32
-    vs = vs_ref[0]
-    s = jnp.einsum("hrd,khd->hrk", q, k,
-                   preferred_element_type=jnp.float32)   # [bh, rep, bs]
-    s = s * ks.T[:, None, :]                   # dequant fold, pre-softmax
-
-    entry = tables_ref[b, j]
-    qpos = qpos_ref[b]
-    logical = j * block_size + lax.broadcasted_iota(
-        jnp.int32, (1, block_size), 1)
-    slot_pos = pos_ref[...]                    # [1, bs]
-    ok = (entry >= 0) & (slot_pos == logical) & (slot_pos <= qpos)
-    okb = ok[:, None, :]
-    s = jnp.where(okb, s, NEG_INF)
-
-    m_prev = m_ref[...]
-    l_prev = l_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    p = jnp.where(okb, jnp.exp(s - m_new[..., None]), 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    m_ref[...] = m_new
-    # l accumulates UNSCALED p: v_scale is a value-side factor, not a
-    # probability reweighting — normalizing by scaled sums would diverge
-    # from softmax-then-(p * v_scale)
-    l_ref[...] = l_prev * corr + p.sum(axis=-1)
-    pw = p * vs.T[:, None, :]                  # [bh, rep, bs]
-    pv = jnp.einsum("hrk,khd->hrd", pw.astype(v.dtype), v,
-                    preferred_element_type=jnp.float32)
-    o_ref[0] = o_ref[0] * corr[..., None] + pv
-
-    @pl.when(j == pages - 1)
-    def _finish():
-        l = l_ref[...]
-        o_ref[0] = o_ref[0] / jnp.maximum(l, 1e-30)[..., None]
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("block_size", "block_h", "interpret"),
-)
-def paged_attention_int8_tiled(q, k_pool, v_pool, k_scale, v_scale,
-                               pos_pool, tables, positions, *,
-                               block_size: int, block_h: int,
-                               interpret: bool = False):
-    """int8-KV tiled kernel call.
-
-    q: [B, Hkv, rep, D] *compute* dtype (bf16), pre-scaled by the caller.
-    k_pool / v_pool: int8 [NB, BS, Hkv, D]; k_scale / v_scale: f32
-    [NB, BS, Hkv] (per-slot, per-kv-head dequant scales).
-    Returns f32 [B, Hkv, rep, D].
-    """
-    b, hkv, rep, d = q.shape
-    nb, bs = pos_pool.shape
-    pages = tables.shape[1]
-    assert hkv % block_h == 0, (hkv, block_h)
-    assert bs == block_size and k_pool.shape[:2] == (nb, bs)
-    assert k_scale.shape == (nb, bs, hkv), (k_scale.shape, (nb, bs, hkv))
-
-    kernel = functools.partial(_paged_attn_int8_kernel,
-                               block_size=block_size, pages=pages)
-
-    def _pool_idx(bi, hi, ji, tables, qpos):
-        return (jnp.maximum(tables[bi, ji], 0), 0, hi, 0)
-
-    def _scale_idx(bi, hi, ji, tables, qpos):
-        return (jnp.maximum(tables[bi, ji], 0), 0, hi)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                 # tables, positions
-        grid=(b, hkv // block_h, pages),
-        in_specs=[
-            pl.BlockSpec((1, block_h, rep, d),
-                         lambda bi, hi, ji, tables, qpos: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, block_size, block_h, d), _pool_idx),
-            pl.BlockSpec((1, block_size, block_h, d), _pool_idx),
-            pl.BlockSpec((1, block_size, block_h), _scale_idx),
-            pl.BlockSpec((1, block_size, block_h), _scale_idx),
-            pl.BlockSpec((1, block_size),
-                         lambda bi, hi, ji, tables, qpos:
-                         (jnp.maximum(tables[bi, ji], 0), 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_h, rep, d),
-                               lambda bi, hi, ji, tables, qpos:
-                               (bi, hi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((block_h, rep), jnp.float32),
-            pltpu.VMEM((block_h, rep), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d), jnp.float32),
-        interpret=interpret,
-    )(tables, positions, q, k_pool, v_pool, k_scale, v_scale, pos_pool)
+    )(*args)
+    return out.reshape(b, hkv, rep, d)
 
 
 def _paged_attn_mla_kernel(tables_ref, qpos_ref, qe_ref, qr_ref, ckv_ref,
@@ -323,7 +300,7 @@ def _paged_attn_mla_kernel(tables_ref, qpos_ref, qe_ref, qr_ref, ckv_ref,
     qpos = qpos_ref[b]
     logical = j * block_size + lax.broadcasted_iota(
         jnp.int32, (1, block_size), 1)
-    slot_pos = pos_ref[...]                    # [1, bs]
+    slot_pos = pos_ref[0]                      # [1, bs]
     ok = (entry >= 0) & (slot_pos == logical) & (slot_pos <= qpos)
     s = jnp.where(ok, s, NEG_INF)              # [1, bs] broadcasts over h
 
@@ -385,9 +362,9 @@ def paged_attention_mla_tiled(q_eff, q_rope, ckv_pool, krope_pool,
                          lambda bi, hi, ji, tables, qpos: (bi, hi, 0)),
             pl.BlockSpec((1, block_size, lora), _pool_idx),
             pl.BlockSpec((1, block_size, dr), _pool_idx),
-            pl.BlockSpec((1, block_size),
+            pl.BlockSpec((1, 1, block_size),
                          lambda bi, hi, ji, tables, qpos:
-                         (jnp.maximum(tables[bi, ji], 0), 0)),
+                         (jnp.maximum(tables[bi, ji], 0), 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_h, lora),
                                lambda bi, hi, ji, tables, qpos:
@@ -402,67 +379,53 @@ def paged_attention_mla_tiled(q_eff, q_rope, ckv_pool, krope_pool,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, lora), jnp.float32),
         interpret=interpret,
-    )(tables, positions, q_eff, q_rope, ckv_pool, krope_pool, pos_pool)
+    )(tables, positions, q_eff, q_rope, ckv_pool, krope_pool,
+      pos_pool.reshape(nb, 1, bs))
 
 
-def _paged_prefill_kernel(tables_ref, qpos_ref, q_ref, k_ref, v_ref, *rest,
-                          block_size: int, pages: int, int8: bool):
-    """Chunked-prefill flash attention over pool blocks: the chunk's C
-    queries (each with its own absolute position) attend over every live
-    slot causally visible to them — prior context AND the already-
+#: most query rows one prefill grid step holds (its score tiles must fit
+#: the scoped VMEM; 4096 rows of 32 heads x 128-token chunks still do)
+MAX_ROWS = 2048
+
+
+def _row_tile(r: int) -> int:
+    """Rows per prefill grid step: all of them, or the largest divisor of
+    ``r`` that is a multiple of 8 and at most :data:`MAX_ROWS`."""
+    if r <= MAX_ROWS:
+        return r
+    return max((t for t in range(8, MAX_ROWS + 1, 8) if r % t == 0),
+               default=r)
+
+
+def _paged_prefill_kernel(tables_ref, q_ref, qpos_ref, k_ref, v_ref, *rest,
+                          block_size: int, pages: int, rows_per_head: int,
+                          int8: bool):
+    """Chunked-prefill flash attention over pool blocks: the chunk's
+    query rows (each with its own absolute position) attend over every
+    live slot causally visible to them — prior context AND the already-
     inserted chunk itself — with per-query masking across the chunk
     boundary.  Pad query rows (``pos < 0``) see no live slot and yield
     zeros (``l == 0`` guard)."""
     if int8:
         ks_ref, vs_ref, pos_ref, o_ref, m_ref, l_ref = rest
     else:
-        pos_ref, o_ref, m_ref, l_ref = rest
+        (pos_ref, o_ref, m_ref, l_ref), ks_ref, vs_ref = rest, None, None
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(3)
 
     @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        o_ref[...] = jnp.zeros_like(o_ref)
+    def _():
+        _init_acc(o_ref, m_ref, l_ref)
 
-    q = q_ref[0]                               # [C, bh, rep, d] pre-scaled
-    k = k_ref[0].astype(q.dtype)               # [bs, bh, d]
-    v = v_ref[0].astype(q.dtype)
-    s = jnp.einsum("chrd,khd->chrk", q, k,
-                   preferred_element_type=jnp.float32)   # [C, bh, rep, bs]
-    if int8:
-        s = s * ks_ref[0].T[None, :, None, :]  # [1, bh, 1, bs]
-
-    entry = tables_ref[b, j]
-    q_pos = qpos_ref[0]                        # [C]
-    logical = j * block_size + lax.broadcasted_iota(
-        jnp.int32, (1, block_size), 1)
-    slot_pos = pos_ref[...]                    # [1, bs]
-    live = (entry >= 0) & (slot_pos == logical)          # [1, bs]
-    ok = live & (slot_pos <= q_pos[:, None])             # [C, bs] causal
-    okb = ok[:, None, None, :]                 # [C, 1, 1, bs]
-    s = jnp.where(okb, s, NEG_INF)
-
-    m_prev = m_ref[...]                        # [C, bh, rep]
-    l_prev = l_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    p = jnp.where(okb, jnp.exp(s - m_new[..., None]), 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    m_ref[...] = m_new
-    l_ref[...] = l_prev * corr + p.sum(axis=-1)
-    if int8:
-        pw = p * vs_ref[0].T[None, :, None, :]
-    else:
-        pw = p
-    pv = jnp.einsum("chrk,khd->chrd", pw.astype(v.dtype), v,
-                    preferred_element_type=jnp.float32)
-    o_ref[0] = o_ref[0] * corr[..., None] + pv
+    live, slot_pos = _slot_live(tables_ref[b, j], pos_ref, j, block_size)
+    ok = live & (slot_pos <= qpos_ref[0])      # [T, BS] per-query causal
+    _attend_block(q_ref[0, 0], k_ref, v_ref, ks_ref, vs_ref, ok, o_ref,
+                  m_ref, l_ref, rows_per_head=rows_per_head,
+                  row0=pl.program_id(2) * q_ref.shape[2])
 
     @pl.when(j == pages - 1)
-    def _finish():
-        l = l_ref[...]
-        o_ref[0] = o_ref[0] / jnp.maximum(l, 1e-30)[..., None]
+    def _():
+        _finish(o_ref, l_ref)
 
 
 @functools.partial(
@@ -486,50 +449,43 @@ def paged_prefill_tiled(q, k_pool, v_pool, pos_pool, tables, positions,
     assert hkv % block_h == 0, (hkv, block_h)
     assert bs == block_size and k_pool.shape[:2] == (nb, bs)
     assert positions.shape == (b, c)
+    nhb = hkv // block_h
+    r = block_h * rep * c
+    # rows of one head tile, head-major: (head, rep, chunk token)
+    rows = q.reshape(b, c, nhb, block_h * rep, d).transpose(0, 2, 3, 1, 4) \
+        .reshape(b, nhb, r, d)
+    qpos = jnp.broadcast_to(positions[:, None, :], (b, block_h * rep, c)) \
+        .reshape(b, r, 1)
 
+    # a long chunk's rows are tiled so one tile's scores fit VMEM; every
+    # row tile streams the sequence's pool blocks again
+    tr = _row_tile(r)
     kernel = functools.partial(_paged_prefill_kernel, block_size=block_size,
-                               pages=pages, int8=int8)
-
-    def _pool_idx(bi, hi, ji, tables):
-        return (jnp.maximum(tables[bi, ji], 0), 0, hi, 0)
-
-    def _scale_idx(bi, hi, ji, tables):
-        return (jnp.maximum(tables[bi, ji], 0), 0, hi)
-
-    # chunk positions are a regular VMEM input (C can be large), so only
-    # the block tables ride the scalar-prefetch slot
-    in_specs = [
-        pl.BlockSpec((1, c), lambda bi, hi, ji, tables: (bi, 0)),
-        pl.BlockSpec((1, c, block_h, rep, d),
-                     lambda bi, hi, ji, tables: (bi, 0, hi, 0, 0)),
-        pl.BlockSpec((1, block_size, block_h, d), _pool_idx),
-        pl.BlockSpec((1, block_size, block_h, d), _pool_idx),
-    ]
-    args = [jnp.asarray(tables, jnp.int32), positions, q, k_pool, v_pool]
-    if int8:
-        in_specs += [pl.BlockSpec((1, block_size, block_h), _scale_idx),
-                     pl.BlockSpec((1, block_size, block_h), _scale_idx)]
-        args += [k_scale, v_scale]
-    in_specs.append(pl.BlockSpec((1, block_size),
-                                 lambda bi, hi, ji, tables:
-                                 (jnp.maximum(tables[bi, ji], 0), 0)))
-    args.append(pos_pool)
-
+                               pages=pages, rows_per_head=rep * c, int8=int8)
+    tile = lambda bi, hi, ri, ji, t: (bi, hi, ri, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,                 # tables only
-        grid=(b, hkv // block_h, pages),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, c, block_h, rep, d),
-                               lambda bi, hi, ji, tables:
-                               (bi, 0, hi, 0, 0)),
+        grid=(b, nhb, r // tr, pages),
+        in_specs=[pl.BlockSpec((1, 1, tr, d), tile),
+                  pl.BlockSpec((1, tr, 1),
+                               lambda bi, hi, ri, ji, t: (bi, ri, 0))]
+        + _pool_specs(block_size, block_h, d, int8, prefetch=1),
+        out_specs=pl.BlockSpec((1, 1, tr, d), tile),
         scratch_shapes=[
-            pltpu.VMEM((c, block_h, rep), jnp.float32),
-            pltpu.VMEM((c, block_h, rep), jnp.float32),
+            pltpu.VMEM((tr, 1), jnp.float32),
+            pltpu.VMEM((tr, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    args = [jnp.asarray(tables, jnp.int32), rows, qpos, k_pool, v_pool]
+    if int8:
+        args += [k_scale, v_scale]
+    args.append(pos_pool.reshape(nb, 1, bs))
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, c, hkv, rep, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, nhb, r, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(*args)
+    return out.reshape(b, nhb, block_h * rep, c, d).transpose(0, 3, 1, 2, 4) \
+        .reshape(b, c, hkv, rep, d)

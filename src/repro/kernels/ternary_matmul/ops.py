@@ -52,11 +52,11 @@ def ternary_matmul(x: jax.Array, w: PlaneBundle, *, mu: int = 4,
         block_m = cfg.block_m if block_m is None else block_m
         block_n = cfg.block_n if block_n is None else block_n
 
-    xp, packed, alpha, _, b, m, block_m, block_n = tile_operands(
+    xs, packed, alpha, _, b, m, block_m, block_n = tile_operands(
         x2, w, block_b=block_b, block_m=block_m, block_n=block_n)
 
     y = _k.ternary_matmul_tiled(
-        xp, packed, alpha, mu=mu, group_size=w.group_size,
+        xs, packed, alpha, mu=mu, group_size=w.group_size,
         read_mode=read_mode, block_b=block_b, block_m=block_m,
-        block_n=block_n, interpret=interpret, out_dtype=jax.numpy.float32)
+        block_n=block_n, interpret=interpret)
     return y[:b, :m].reshape(*lead, m).astype(out_dtype)

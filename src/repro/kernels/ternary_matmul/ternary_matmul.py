@@ -16,11 +16,10 @@ in-kernel instead of riding the generic bit-serial path:
      stored plane, no second alpha row (``lut_common.ternary_plane_bytes``).
   3. **single-alpha accumulate**: y += (a/2) * (V1 + V2) per alpha
      group; there is no offset term (ternary has none), so the z row,
-     its DMA and its epilogue einsum all disappear.
+     its DMA and its epilogue all disappear.
 
-Per-tile arithmetic vs the generic lut_gemm at q=2: one LUT build
-instead of one, two keyed reads (same), ONE alpha einsum instead of
-two, no offset einsum — plus 2/3 of the scale-row traffic and no z row.
+The bit slab layout and LUT reads are shared with ``lut_gemm``
+(``kernels/lut_common.py``).
 """
 from __future__ import annotations
 
@@ -30,80 +29,66 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.lut_common import (ReadMode, build_lut, extract_keys,
-                                      read_lut, ternary_plane_bytes)
+from repro.kernels.lut_common import (ReadMode, build_lut, expand_groups,
+                                      for_each_chunk, plane_call, read_lut,
+                                      ternary_plane_bytes)
 
 
 def _ternary_matmul_kernel(x_ref, packed_ref, alpha_ref, o_ref, *,
-                           mu: int, group_size: int, read_mode: ReadMode):
-    tb, tn = x_ref.shape
-    tm = packed_ref.shape[1]
-    tag = alpha_ref.shape[-1]
+                           mu: int, group_size: int, read_mode: ReadMode,
+                           chunk: int):
+    n_bytes = packed_ref.shape[-1]
+    gb = group_size // 8
+    keys_per_byte = 8 // mu
+    key_mask = (1 << mu) - 1
 
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
+    def body(off, acc):
+        cols = pl.ds(off, chunk)
+        slabs = [x_ref[s, :, cols] for s in range(8)]
+        # -- 1. one half-size LUT per key position for both planes -------
+        tables = [build_lut(slabs, h, mu, True) for h in range(keys_per_byte)]
+        # -- 2. sign decode: (sign, mask) bytes -> b1/b2 plane bytes -----
+        b1, b2 = ternary_plane_bytes(packed_ref[0, :, cols].astype(jnp.int32),
+                                     packed_ref[1, :, cols].astype(jnp.int32))
+        # -- 3. single-alpha accumulate:  y += (a/2) (V1 + V2) ------------
+        half_alpha = expand_groups(alpha_ref[0], off, chunk, gb) * 0.5
+        for plane in (b1, b2):
+            for h in range(keys_per_byte):
+                acc = acc + read_lut(tables[h], (plane >> (mu * h)) & key_mask,
+                                     half_alpha, mu, True, read_mode)
+        return acc
 
-    x = x_ref[...].astype(jnp.float32)                    # [TB, TN]
-
-    # -- 1. one half-size LUT for both derived planes ---------------------
-    lut = build_lut(x, mu, half=True)                     # [TB, G, P/2]
-
-    # -- 2. sign decode: (sign, mask) bytes -> b1/b2 plane bytes ----------
-    b1, b2 = ternary_plane_bytes(packed_ref[0], packed_ref[1])
-    vals = (read_lut(lut, extract_keys(b1, mu), mu, True, read_mode)
-            + read_lut(lut, extract_keys(b2, mu), mu, True, read_mode))
-
-    # -- 3. single-alpha accumulate:  y += (a/2) (V1 + V2) ----------------
-    per_ag = group_size // mu
-    vals_ag = vals.reshape(tb, tm, tag, per_ag).sum(-1)   # [TB, TM, AG]
-    half_alpha = alpha_ref[0].astype(jnp.float32) * 0.5   # [TM, AG]
-    acc = jnp.einsum("bma,ma->bm", vals_ag, half_alpha,
-                     preferred_element_type=jnp.float32)
-    o_ref[...] += acc.astype(o_ref.dtype)
+    o_ref[...] = for_each_chunk(n_bytes, chunk, body,
+                                jnp.zeros(o_ref.shape, jnp.float32))
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("mu", "group_size", "read_mode", "block_b", "block_m",
-                     "block_n", "interpret", "out_dtype"),
+                     "block_n", "interpret"),
 )
-def ternary_matmul_tiled(x, packed, alpha, *, mu: int = 4,
+def ternary_matmul_tiled(xs, packed, alpha, *, mu: int = 4,
                          group_size: int = 128, read_mode: ReadMode = "onehot",
                          block_b: int = 8, block_m: int = 128,
-                         block_n: int = 512, interpret: bool = False,
-                         out_dtype=jnp.float32):
-    """Raw tiled kernel call. All dims must already divide the block sizes.
+                         block_n: int = 1024, interpret: bool = False):
+    """Raw tiled kernel call. All dims must already divide the blocks.
 
-    x: [B, N] fp; packed: uint8[2, M, N//8] (plane 0 = sign, plane 1 =
-    mask); alpha: f32[1, M, N//group_size].  Returns [B, M] out_dtype
-    (FP32 accumulation).
+    xs: f32 [8, B, N//8] activation bit slabs; packed: uint8[2, M, N//8]
+    (plane 0 = sign, plane 1 = mask); alpha: f32[1, M, N//group_size].
+    Returns f32 [B, M] (FP32 accumulation).
     """
-    b, n = x.shape
+    _, b, nb = xs.shape
     q, m, _ = packed.shape
+    chunk = block_n // 8
     assert q == 2, f"ternary bundle stores sign+mask planes, got {q}"
     assert alpha.shape[0] == 1, "ternary carries a single alpha row"
-    assert n % block_n == 0 and m % block_m == 0 and b % block_b == 0, (
-        f"shapes ({b},{m},{n}) must divide blocks "
+    assert nb % chunk == 0 and m % block_m == 0 and b % block_b == 0, (
+        f"shapes ({b},{m},{nb * 8}) must divide blocks "
         f"({block_b},{block_m},{block_n})")
-    assert block_n % group_size == 0 and group_size % mu == 0
-    tag = block_n // group_size
-    grid = (b // block_b, m // block_m, n // block_n)
-
+    assert block_n % group_size == 0 and group_size % 8 == 0
+    assert 8 % mu == 0, f"mu={mu} must divide 8"
     kernel = functools.partial(
         _ternary_matmul_kernel, mu=mu, group_size=group_size,
-        read_mode=read_mode)
-
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_b, block_n), lambda bi, mi, ni: (bi, ni)),
-            pl.BlockSpec((2, block_m, block_n // 8),
-                         lambda bi, mi, ni: (0, mi, ni)),
-            pl.BlockSpec((1, block_m, tag), lambda bi, mi, ni: (0, mi, ni)),
-        ],
-        out_specs=pl.BlockSpec((block_b, block_m), lambda bi, mi, ni: (bi, mi)),
-        out_shape=jax.ShapeDtypeStruct((b, m), out_dtype),
-        interpret=interpret,
-    )(x, packed, alpha)
+        read_mode=read_mode, chunk=chunk)
+    return plane_call(kernel, xs, packed, alpha, None, block_b=block_b,
+                      block_m=block_m, interpret=interpret)

@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"       # fake devices live on the host
 
 """Multi-pod dry-run: lower + compile every (arch x shape) on the
 production mesh, print memory/cost analysis, and extract roofline terms.

@@ -4,11 +4,7 @@ FUNCTIONS (never module-level constants) so importing this module never
 touches jax device state — required because the dry-run pins the device
 count via XLA_FLAGS before any jax initialization.
 
-``make_mesh`` is the single construction point: it papers over the
-``axis_types`` API (``jax.sharding.AxisType`` only exists on newer jax
-releases; on older ones every axis is implicitly Auto, which is the
-type we request anyway), so meshes build identically across the jax
-versions this repo runs on.
+``make_mesh`` is the single construction point; every axis is Auto.
 """
 from __future__ import annotations
 
@@ -16,18 +12,9 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """Mesh with Auto axis types on every jax version.
-
-    Newer jax wants ``axis_types`` spelled explicitly (and sharding-in-
-    types meshes default differently); jax <= 0.4.x has no ``AxisType``
-    at all and every axis is Auto.  Request Auto where the API exists,
-    fall back silently where it doesn't.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """Mesh with an Auto axis type on every axis."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

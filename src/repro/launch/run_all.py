@@ -42,7 +42,8 @@ def run_cell(arch, shape, multi_pod, out_dir, timeout=1500, extra=()):
     try:
         r = subprocess.run(cmd, capture_output=True, text=True,
                            timeout=timeout,
-                           env={**os.environ, "PYTHONPATH": "src"})
+                           env={**os.environ, "PYTHONPATH": "src",
+                                "JAX_PLATFORMS": "cpu"})
         ok = r.returncode == 0
         if not ok:
             skip = "SKIP:" in (r.stdout + r.stderr)

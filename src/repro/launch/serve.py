@@ -103,6 +103,29 @@ def build_spec(args):
     return None if spec.bits == 0 else spec
 
 
+def build_model(cfg, spec, *, seed: int = 0):
+    """The served model: random weights drawn from ``seed`` and, when
+    ``spec`` is given, quantized leaf by leaf
+    (:func:`repro.quant.init_quantized` — the dense model never coexists
+    with its bundles, so a full-width model fits one chip).
+
+    Returns ``(model, params, manifest)``; ``model.cfg.quant`` is
+    ``spec``.  Shared by this launcher and ``chip_smoke.py``.
+    """
+    import jax
+    from repro import quant as quant_api
+    from repro.models import Model
+
+    rng = jax.random.PRNGKey(seed)
+    if spec is None:
+        model = Model(cfg)
+        return model, model.init(rng), None
+    model = Model(cfg.replace(quant=spec))
+    params, manifest = quant_api.init_quantized(model.desc(), spec,
+                                                model.axes(), rng)
+    return model, params, manifest
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="opt_6_7b")
@@ -212,14 +235,15 @@ def main():
                          "cache; see python -m repro.tune)")
     args = ap.parse_args()
 
-    import jax
     import numpy as np
     from repro import quant as quant_api
     from repro.configs import get_config, get_reduced
     from repro.models import Model
     from repro.serve import PagedServeEngine, Request, ServeEngine
     from repro.serve.engine import supports_paging
+    from repro.launch.compile_cache import setup_compile_cache
 
+    print(f"[launch.serve] compile cache: {setup_compile_cache()}")
     if args.backend is not None:
         try:    # fail fast on both paths: before PTQ and before ckpt load
             quant_api.fallback_chain(args.backend)
@@ -227,7 +251,6 @@ def main():
             raise SystemExit(f"--backend: {e.args[0]}")
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     cfg = cfg.replace(max_seq_len=max(cfg.max_seq_len, args.cache_len))
-    model = Model(cfg)
 
     manifest = None
     if args.load_quantized:
@@ -266,18 +289,17 @@ def main():
         print(f"[launch.serve] loaded quantized checkpoint "
               f"{args.load_quantized} ({spec.describe()})")
     else:
-        params = model.init(jax.random.PRNGKey(0))
         spec = build_spec(args)
         if spec is None:
             if args.save_quantized:
                 raise SystemExit("--save-quantized requires quantization "
                                  "(remove --bits 0)")
             print("[launch.serve] serving dense FP (no quantization)")
+            model, params, _ = build_model(cfg, None)
         else:
             t0 = time.time()
             try:
-                params, manifest = quant_api.quantize_model(params, spec,
-                                                            model.axes())
+                model, params, manifest = build_model(cfg, spec)
             except ValueError as e:   # spec errors surfaced at plan time
                 raise SystemExit(f"invalid quant spec: {e}")
             print(f"[launch.serve] {spec.describe()} in "

@@ -493,7 +493,7 @@ def gqa_apply(params, cfg, x, positions, *, cache=None, cache_at=None,
                                       cache["pos"], causal=True,
                                       window=cfg.sliding_window)
     out = out.reshape(b, s, h * hd)
-    out = linear_apply(params["o"], out, backend=backend)
+    out = linear_apply(params["o"], out, backend=backend, row_parallel=True)
     return (out, cache) if cache is not None else out
 
 
@@ -584,6 +584,14 @@ def paged_shard_scope(mesh, *, tp: int = 1, shard_batch: bool = False):
         yield
     finally:
         _PAGED_SHARD.update(prev)
+
+
+def serving_mesh():
+    """The mesh of the active :func:`paged_shard_scope` when it has a
+    model axis wider than one device, else None — the serving engine's
+    jitted steps trace inside that scope, so per-shard kernel launches
+    (paged attention here, plane GEMMs in ``quant.backends``) see it."""
+    return _PAGED_SHARD["mesh"] if _PAGED_SHARD["tp"] > 1 else None
 
 
 def _fused_selected(mode: str, supported: bool) -> bool:
@@ -707,24 +715,24 @@ def paged_decode_attend(q, cache, positions, *, window=0, scale=None,
     use = _fused_selected(mode, fused_paged_supported(cache, q.shape[2],
                                                       window=window, tp=tp))
     if use:
-        from repro.core.lut_gemm import INTERPRET
+        from repro.core.lut_gemm import interpret_mode
+        interp = interpret_mode()
         from repro.kernels.paged_attention import (paged_attention,
                                                    paged_attention_int8)
         int8 = cache["k"].dtype == jnp.int8
         if int8:
             fn = functools.partial(paged_attention_int8, scale=scale,
-                                   interpret=INTERPRET)
+                                   interpret=interp)
             args = (q[:, 0], cache["k"], cache["v"], cache["k_scale"],
                     cache["v_scale"], cache["pos"], cache["block_tables"],
                     positions[:, 0])
         else:
             fn = functools.partial(paged_attention, scale=scale,
-                                   interpret=INTERPRET)
+                                   interpret=interp)
             args = (q[:, 0], cache["k"], cache["v"], cache["pos"],
                     cache["block_tables"], positions[:, 0])
         if mesh is not None:
             from jax.sharding import PartitionSpec as P
-            from repro.parallel.sharding import shard_map_compat
             dax = "data" if _PAGED_SHARD["shard_batch"] else None
             pool = P(None, None, "model", None)
             scl = P(None, None, "model")        # scale pools [NB, BS, Hkv]
@@ -734,9 +742,9 @@ def paged_decode_attend(q, cache, positions, *, window=0, scale=None,
                 + (P(None, None),               # pos pool
                    P(dax, None),                # block tables
                    P(dax))                      # positions
-            out3 = shard_map_compat(
-                fn, mesh, in_specs=in_specs,
-                out_specs=P(dax, "model", None))(*args)
+            out3 = jax.shard_map(
+                fn, mesh=mesh, in_specs=in_specs,
+                out_specs=P(dax, "model", None), check_vma=False)(*args)
             return out3[:, None]
         out = fn(*args)
         out = shard_act(out[:, None], ("batch", None, "heads", None))
@@ -762,22 +770,22 @@ def mla_paged_decode_attend(q_eff, q_rope, cache, positions, *, scale,
     h = q_eff.shape[2]
     use = _fused_selected(mode, fused_paged_supported(cache, h, tp=tp))
     if use:
-        from repro.core.lut_gemm import INTERPRET
+        from repro.core.lut_gemm import interpret_mode
+        interp = interpret_mode()
         from repro.kernels.paged_attention import paged_attention_mla
         fn = functools.partial(paged_attention_mla, scale=float(scale),
-                               interpret=INTERPRET)
+                               interpret=interp)
         args = (q_eff[:, 0], q_rope[:, 0].astype(jnp.float32),
                 cache["ckv"], cache["krope"], cache["pos"],
                 cache["block_tables"], positions[:, 0])
         if mesh is not None:
             from jax.sharding import PartitionSpec as P
-            from repro.parallel.sharding import shard_map_compat
             dax = "data" if _PAGED_SHARD["shard_batch"] else None
             # latent pools have no heads dim: they ride replicated (the
             # paged pool nulls the contiguous cache's "kv_seq" sharding —
             # see model.paged_cache_axes) and the QUERY heads shard
-            ctx3 = shard_map_compat(
-                fn, mesh,
+            ctx3 = jax.shard_map(
+                fn, mesh=mesh, check_vma=False,
                 in_specs=(P(dax, "model", None),   # q_eff [B, H, lora]
                           P(dax, "model", None),   # q_rope [B, H, dr]
                           P(None, None, None),     # ckv pool
@@ -811,11 +819,12 @@ def paged_prefill_attend(q, cache, positions, *, scale=None, mode="auto"):
     use = _fused_selected(mode, fused_paged_supported(
         cache, q.shape[2], tp=tp, kernel="paged_prefill"))
     if use:
-        from repro.core.lut_gemm import INTERPRET
+        from repro.core.lut_gemm import interpret_mode
+        interp = interpret_mode()
         from repro.kernels.paged_attention import paged_prefill
         int8 = cache["k"].dtype == jnp.int8
         fn = functools.partial(paged_prefill, scale=scale,
-                               interpret=INTERPRET)
+                               interpret=interp)
         args = (q, cache["k"], cache["v"], cache["pos"],
                 cache["block_tables"], positions) \
             + ((cache["k_scale"], cache["v_scale"]) if int8 else ())
@@ -824,10 +833,9 @@ def paged_prefill_attend(q, cache, positions, *, scale=None, mode="auto"):
                 lambda q_, k_, v_, p_, t_, pos_, ks_, vs_, **kw:
                 paged_prefill(q_, k_, v_, p_, t_, pos_,
                               k_scale=ks_, v_scale=vs_, **kw),
-                scale=scale, interpret=INTERPRET)
+                scale=scale, interpret=interp)
         if mesh is not None:
             from jax.sharding import PartitionSpec as P
-            from repro.parallel.sharding import shard_map_compat
             # prefill runs one sequence's chunk at a time (B=1), which a
             # data axis > 1 cannot split — replicate unless B divides
             dax = "data" if (_PAGED_SHARD["shard_batch"]
@@ -841,9 +849,10 @@ def paged_prefill_attend(q, cache, positions, *, scale=None, mode="auto"):
                         P(dax, None),                 # block tables
                         P(dax, None)) \
                 + ((scl, scl) if int8 else ())        # scale pools
-            out = shard_map_compat(
-                fn, mesh, in_specs=in_specs,
-                out_specs=P(dax, None, "model", None))(*args)
+            out = jax.shard_map(
+                fn, mesh=mesh, in_specs=in_specs,
+                out_specs=P(dax, None, "model", None),
+                check_vma=False)(*args)
             return out
         return shard_act(fn(*args), ("batch", None, "heads", None))
     kv = paged_view(cache)
@@ -880,7 +889,8 @@ def cross_attend(params, cfg, x, k, v, backend=None):
     kpos = jnp.broadcast_to(jnp.arange(k.shape[1], dtype=jnp.int32)[None],
                             (b, k.shape[1]))
     out = blockwise_attention(q, k, v, qpos, kpos, causal=False)
-    return linear_apply(params["o"], out.reshape(b, s, h * hd), backend=backend)
+    return linear_apply(params["o"], out.reshape(b, s, h * hd),
+                        backend=backend, row_parallel=True)
 
 
 # ---------------------------------------------------------------------------
@@ -1000,5 +1010,5 @@ def mla_apply(params, cfg, x, positions, *, cache=None, cache_at=None,
                                   causal=True, scale=scale, kv_map_fn=kv_map)
 
     out = out.reshape(b, s, h * dv).astype(x.dtype)
-    out = linear_apply(params["o"], out, backend=backend)
+    out = linear_apply(params["o"], out, backend=backend, row_parallel=True)
     return (out, cache) if cache is not None else out

@@ -90,10 +90,12 @@ def mlp_apply(params, x, backend=None):
         g = linear_apply(params["gate"], x, backend=backend)
         u = linear_apply(params["up"], x, backend=backend)
         h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
-        return linear_apply(params["down"], h, backend=backend)
+        return linear_apply(params["down"], h, backend=backend,
+                            row_parallel=True)
     h = linear_apply(params["up"], x, params.get("up_b"), backend=backend)
     h = jax.nn.gelu(h.astype(jnp.float32)).astype(x.dtype)
-    return linear_apply(params["down"], h, params.get("down_b"), backend=backend)
+    return linear_apply(params["down"], h, params.get("down_b"),
+                        backend=backend, row_parallel=True)
 
 
 # ---------------------------------------------------------------------------

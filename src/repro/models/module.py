@@ -26,6 +26,7 @@ Logical axis names used across the zoo:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Optional
 
 import jax
@@ -51,22 +52,38 @@ def is_desc(x) -> bool:
     return isinstance(x, ParamDesc)
 
 
-def _leaf_init(key, d: ParamDesc) -> jax.Array:
+def _draw(key, shape, d: ParamDesc) -> jax.Array:
+    return (jax.random.normal(key, shape, jnp.float32) * d.scale
+            ).astype(d.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _draw_stacked(key, d: ParamDesc) -> jax.Array:
+    keys = jax.random.split(key, d.shape[0])
+    return jax.lax.map(lambda k: _draw(k, d.shape[1:], d), keys)
+
+
+def init_leaf(key, d: ParamDesc) -> jax.Array:
+    """Materialize one descriptor.  Scan-stacked leaves (leading
+    ``"layers"`` axis) are drawn one layer at a time from per-layer keys,
+    so no full-stack float32 buffer ever exists."""
     if d.init == "zeros":
         return jnp.zeros(d.shape, d.dtype)
     if d.init == "ones":
         return jnp.ones(d.shape, d.dtype)
-    if d.init in ("normal", "embed", "scan_normal"):
-        return (jax.random.normal(key, d.shape, jnp.float32) * d.scale
-                ).astype(d.dtype)
-    raise ValueError(f"unknown init {d.init}")
+    if d.init not in ("normal", "embed", "scan_normal"):
+        raise ValueError(f"unknown init {d.init}")
+    if d.axes and d.axes[0] == "layers" and len(d.shape) > 2:
+        return _draw_stacked(key, d)
+    return _draw(key, d.shape, d)
 
 
 def init_params(rng: jax.Array, tree) -> Any:
-    """Materialize a descriptor tree into real arrays."""
+    """Materialize a descriptor tree into real arrays (one key per leaf,
+    in flatten order — ``quant.init_quantized`` draws the same)."""
     leaves, treedef = jax.tree_util.tree_flatten(tree, is_leaf=is_desc)
     keys = jax.random.split(rng, len(leaves))
-    out = [_leaf_init(k, d) if is_desc(d) else d for k, d in zip(keys, leaves)]
+    out = [init_leaf(k, d) if is_desc(d) else d for k, d in zip(keys, leaves)]
     return jax.tree_util.tree_unflatten(treedef, out)
 
 

@@ -103,8 +103,7 @@ class Tracer:
     ``capacity`` bounds retained events (newest win); ``profiler_bridge``
     additionally wraps every span in a ``jax.profiler.TraceAnnotation``
     so host spans line up with device profiles captured via
-    ``jax.profiler.trace`` (silently disabled when jax is unavailable —
-    the tracer itself has no jax dependency).
+    ``jax.profiler.trace`` (jax is imported only then).
     """
 
     def __init__(self, clock=time.perf_counter, capacity: int = 1 << 16,
@@ -119,11 +118,8 @@ class Tracer:
         self.tick: int = -1         # engine tick, tagged onto every event
         self._annotation = None
         if profiler_bridge:
-            try:
-                from jax.profiler import TraceAnnotation
-                self._annotation = TraceAnnotation
-            except Exception:       # jax absent or too old: host-only trace
-                self._annotation = None
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
 
     # ------------------------------------------------------------------
     def now_us(self) -> float:
@@ -270,3 +266,22 @@ def record_kernel_unsupported(kernel: str, reason: str, **meta) -> None:
         return
     t.instant(f"kernel_unsupported:{kernel}", track="engine/kernel",
               cat="kernel", kernel=kernel, reason=reason, **meta)
+
+
+def record_linear_backend(preference: str, backend: str, fallback: bool,
+                          **meta) -> None:
+    """Record which backend one quantized linear resolved to.
+
+    Called by ``quant.backends.resolve_backend`` at every resolution
+    (while jit traces, so once per compiled linear).  ``fallback`` marks
+    a resolution that left the preference's kernels for an XLA path
+    (``bcq_xla`` / ``dense``): those events are named
+    ``backend_fallback`` so a trace shows every fall-down by name.
+    No-op without an active tracer.
+    """
+    t = _ACTIVE
+    if t is None:
+        return
+    t.instant("backend_fallback" if fallback else "linear_backend",
+              track="engine/kernel", cat="kernel", preference=preference,
+              backend=backend, **meta)

@@ -189,26 +189,6 @@ def replicated(mesh: Mesh):
     return NamedSharding(mesh, P())
 
 
-def shard_map_compat(fn, mesh: Mesh, *, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions (it lived in
-    ``jax.experimental.shard_map`` before being promoted).
-
-    Always passes ``check_rep=False`` where the kwarg exists: the serve
-    engine maps Pallas kernels, whose replication factors the checker
-    cannot infer."""
-    import inspect
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    params = inspect.signature(sm).parameters
-    kw = {}
-    if "check_vma" in params:          # newest spelling of the checker
-        kw["check_vma"] = False
-    elif "check_rep" in params:
-        kw["check_rep"] = False
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 # ---------------------------------------------------------------------------
 # activation sharding constraints
 # ---------------------------------------------------------------------------

@@ -29,7 +29,7 @@ _LAZY = {
     "register_backend": "backends", "resolve_backend": "backends",
     # api
     "QuantManifest": "api", "build_manifest": "api", "plan_bits": "api",
-    "quantize_model": "api",
+    "quantize_model": "api", "init_quantized": "api",
     # checkpoint
     "load_quantized": "checkpoint", "save_quantized": "checkpoint",
 }

@@ -159,6 +159,57 @@ def quantize_model(params, spec: QuantSpec, axes_tree=None, *,
     return qparams, manifest
 
 
+def init_quantized(desc_tree, spec: QuantSpec, axes_tree, rng,
+                   ) -> Tuple[Any, QuantManifest]:
+    """Draw random weights from ``rng`` and quantize them leaf by leaf.
+
+    Returns what ``quantize_model(init_params(rng, desc_tree), spec,
+    axes_tree)`` returns — the same per-leaf keys, so the same values —
+    but each quantizable leaf is drawn, quantized and dropped before the
+    next one is drawn: the dense model never coexists with its bundles,
+    and a stacked leaf is cast to float32 one layer at a time.
+    Fractional (mixed-precision) specs rank every dense linear at once,
+    so they take the two-step path.
+    """
+    import jax
+
+    from repro.models.module import init_leaf, init_params, is_desc
+    from repro.quant import ptq  # lazy: ptq uses the format registry
+
+    if spec.is_fractional:
+        return quantize_model(init_params(rng, desc_tree), spec, axes_tree)
+    flat, treedef = jax.tree_util.tree_flatten_with_path(desc_tree,
+                                                         is_leaf=is_desc)
+    keys = jax.random.split(rng, len(flat))        # = init_params' keys
+    slots = []                                     # (path, desc, quantize?)
+    linears = {}
+    for kp, d in flat:
+        path = tuple(getattr(k, "key", getattr(k, "idx", None)) for k in kp)
+        quant = is_desc(d) and ptq._is_quant_leaf(
+            path, jax.ShapeDtypeStruct(d.shape, d.dtype),
+            ptq._axes_of(axes_tree, path))
+        if quant:
+            linears["/".join(map(str, path))] = \
+                jax.ShapeDtypeStruct(d.shape, d.dtype)
+        slots.append((path, d, quant))
+    plan = plan_bits(linears, spec)
+
+    out = []
+    for (path, d, quant), key in zip(slots, keys):
+        if not is_desc(d):
+            out.append(d)
+        elif quant:
+            out.append(ptq._quantize_leaf(
+                init_leaf(key, d), ptq._axes_of(axes_tree, path),
+                plan["/".join(map(str, path))], spec.format,
+                spec.group_size, spec.iters))
+        else:
+            out.append(init_leaf(key, d))
+    qparams = jax.tree_util.tree_unflatten(treedef, out)
+    return qparams, build_manifest(qparams, spec, plan, linears,
+                                   axes_tree=axes_tree)
+
+
 def build_manifest(qparams, spec: QuantSpec, plan: Mapping[str, int],
                    linears: Mapping[str, Any], axes_tree=None) -> QuantManifest:
     from repro.quant import ptq  # lazy: ptq uses the format registry

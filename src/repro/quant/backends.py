@@ -111,6 +111,11 @@ def fallback_chain(preference: Optional[str]) -> Tuple[str, ...]:
 # ---------------------------------------------------------------------------
 
 
+#: preferences whose chains hold Pallas kernels: resolving one of them
+#: to an XLA path is a fall-down, recorded as such on the active trace
+_KERNEL_PREFERENCES = ("auto", "ternary_pallas", "mxu_pallas", "lut_pallas")
+
+
 def resolve_backend(preference: Optional[str], w: BCQWeight) -> str:
     """Pick the backend that will execute this weight.
 
@@ -118,32 +123,98 @@ def resolve_backend(preference: Optional[str], w: BCQWeight) -> str:
     mode is a legitimate explicit request); fallback entries and ``auto``
     require ``native()`` so we never silently degrade onto an emulated
     kernel.  ``dense`` always supports everything, so resolution total.
+    Every resolution lands on the active trace
+    (``obs.trace.record_linear_backend``); one that leaves a kernel
+    preference for ``bcq_xla``/``dense`` is recorded as a
+    ``backend_fallback``.
     """
     pref = preference or "auto"
     chain = fallback_chain(pref)
-    for i, name in enumerate(chain):
-        info = get_backend(name)
+    name = "dense"
+    for i, cand in enumerate(chain):
+        info = get_backend(cand)
         explicit = i == 0 and pref != "auto"
         usable = info.available() if explicit else info.native()
         if usable and info.supports(w):
-            return name
-    return "dense"
+            name = cand
+            break
+    from repro.obs.trace import record_linear_backend
+    record_linear_backend(
+        pref, name,
+        fallback=pref in _KERNEL_PREFERENCES and get_backend(name).kernel is None,
+        m=w.out_features, n=w.in_features, kind=w.kind)
+    return name
 
 
 def execute_linear(x: jax.Array, w, *, backend: Optional[str] = None,
-                   out_dtype=None) -> jax.Array:
+                   out_dtype=None, row_parallel: bool = False) -> jax.Array:
     """y = x @ W^T for a dense array or BCQWeight leaf.
 
     This is the single execution-dispatch point of the model stack:
     ``backend`` is a *preference*, and capability negotiation picks the
-    first link of its fallback chain that can run this weight.
+    first link of its fallback chain that can run this weight.  Under a
+    serving mesh (``models.attention.paged_shard_scope`` with a model
+    axis) a Pallas kernel launches per shard (:func:`_sharded_kernel`):
+    the TPU compiler cannot partition a Mosaic kernel by itself.
     """
     out_dtype = out_dtype or x.dtype
     if not isinstance(w, BCQWeight):
         return jnp.einsum("...n,mn->...m", x, w.astype(x.dtype),
                           preferred_element_type=jnp.float32).astype(out_dtype)
-    name = resolve_backend(backend, w)
-    return get_backend(name).execute(x, w, out_dtype)
+    info = get_backend(resolve_backend(backend, w))
+    if info.kernel is not None:
+        from repro.models.attention import serving_mesh
+        mesh = serving_mesh()
+        if mesh is not None:
+            return _sharded_kernel(info.execute, x, w, out_dtype, mesh,
+                                   row_parallel)
+    return info.execute(x, w, out_dtype)
+
+
+def _sharded_kernel(run, x, w: BCQWeight, out_dtype, mesh,
+                    row_parallel: bool) -> jax.Array:
+    """Launch a plane kernel per model shard (Megatron layout).
+
+    Column-parallel (default): each shard holds whole input rows of its
+    slice of output features and writes that slice.  Row-parallel (the
+    input dim is model-sharded: attention out, MLP down): each shard
+    reduces over its slice of the input dim and the partial products are
+    summed over the model axis.  A weight whose dims do not split into
+    whole rows / whole scale groups runs replicated on every shard.
+    """
+    from jax.sharding import PartitionSpec as P
+
+    tp = dict(zip(mesh.axis_names, mesh.devices.shape))["model"]
+    lead = (None,) * (x.ndim - 1)
+    q, m, nb = w.packed.shape
+    ng = w.alpha.shape[-1]
+    if row_parallel and w.in_features == nb * 8 and ng % tp == 0 \
+            and (w.in_features // tp) % w.group_size == 0:
+        cut = dict(in_features=w.in_features // tp,
+                   out_features=w.out_features)
+        specs = (P(*lead, "model"), P(None, None, "model"),
+                 P(None, None, "model"), P(None, "model"))
+        out_spec, reduce = P(*lead, None), True
+    elif not row_parallel and m == w.out_features and m % tp == 0:
+        cut = dict(in_features=w.in_features, out_features=m // tp)
+        specs = (P(*lead, None), P(None, "model", None),
+                 P(None, "model", None), P("model", None))
+        out_spec, reduce = P(*lead, "model"), False
+    else:
+        cut = dict(in_features=w.in_features, out_features=w.out_features)
+        specs = (P(*lead, None), P(), P(), P())
+        out_spec, reduce = P(*lead, None), False
+    args = (x, w.packed, w.alpha) + (() if w.z is None else (w.z,))
+
+    def shard(xs, packed, alpha, *z):
+        ws = BCQWeight(packed=packed, alpha=alpha, z=z[0] if z else None,
+                       group_size=w.group_size, kind=w.kind, **cut)
+        y = run(xs, ws, jnp.float32)
+        return jax.lax.psum(y, "model") if reduce else y
+
+    y = jax.shard_map(shard, mesh=mesh, in_specs=specs[:len(args)],
+                      out_specs=out_spec, check_vma=False)(*args)
+    return y.astype(out_dtype)
 
 
 def kernel_for(preference: Optional[str]) -> Optional[str]:

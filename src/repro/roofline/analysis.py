@@ -25,10 +25,38 @@ import dataclasses
 import re
 from typing import Optional
 
-# hardware constants (assignment: TPU v5e-class target)
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # B/s per chip
-ICI_BW = 50e9                # B/s per link
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks of one accelerator kind."""
+    bf16_flops: float            # FLOP/s
+    int8_ops: float              # OP/s
+    hbm_bytes_per_s: float
+    hbm_bytes: float
+    source: str
+
+
+#: per-chip peaks keyed by ``jax.Device.device_kind``.  A kind missing
+#: here is an error (:func:`device_peaks`), never a default.
+PEAKS = {
+    "TPU v5 lite": DevicePeaks(
+        bf16_flops=197e12, int8_ops=393e12, hbm_bytes_per_s=819e9,
+        hbm_bytes=16e9, source="Google Cloud documentation, 'TPU v5e'"),
+}
+
+
+def device_peaks(kind: str) -> DevicePeaks:
+    """Peaks of the chip kind ``jax.devices()[0].device_kind`` reports."""
+    if kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind {kind!r}; "
+                       f"known: {sorted(PEAKS)}")
+    return PEAKS[kind]
+
+
+# the compile-only analysis below targets a described v5e chip
+PEAK_FLOPS = PEAKS["TPU v5 lite"].bf16_flops
+HBM_BW = PEAKS["TPU v5 lite"].hbm_bytes_per_s
+ICI_BW = 50e9                # B/s per link (assumed, not from the table)
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -123,9 +151,7 @@ class Roofline:
 
 def from_compiled(compiled) -> Roofline:
     """Roofline terms straight from one compiled executable."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):        # older jax: one dict per device
-        ca = ca[0] if ca else {}
+    ca = compiled.cost_analysis() or {}
     ma = compiled.memory_analysis()
     text = compiled.as_text()
     coll = collective_bytes(text)

@@ -138,7 +138,8 @@ def _pretune(model: Model, params, batch_sizes, verbose: bool = True):
         return
     # interpret mode (CPU smoke): small reps + truncated space so
     # pretune stays a warm-up, not a benchmark run
-    extra = dict(reps=2, warmup=1, max_candidates=8) if core_lg.INTERPRET else {}
+    extra = (dict(reps=2, warmup=1, max_candidates=8)
+             if core_lg.interpret_mode() else {})
     tune_mod.pretune_params(params, kernels=(kernel,),
                             batch_sizes=sorted(set(batch_sizes)),
                             dtype=jnp.dtype(model.cfg.dtype),
@@ -894,6 +895,47 @@ class PagedServeEngine:
         if self.sched.has_work():
             self._drain_tick_budget()
         return self.finished
+
+    def probe_logits(self, prompt, continuation) -> np.ndarray:
+        """Logits of one sequence through this engine's own jitted entry
+        points (sharding, paged kernels and linear backends included):
+        ``prompt`` prefilled as a single chunk, then one decode step per
+        token of ``continuation`` (fed as given, so two engines probed
+        with the same tokens stay aligned) on batch row 0.  Returns f32
+        [1 + len(continuation), V].
+
+        For checking the serving path against a reference; needs an
+        idle engine.  Its blocks come from the pool and go back to it.
+        """
+        if self.sched.has_work() or self._inflight is not None:
+            raise RuntimeError("probe_logits needs an idle engine")
+        n, n_decode = len(prompt), len(continuation)
+        blocks = self.pool.alloc("probe", self.pool.blocks_for(n + n_decode))
+        if blocks is None or len(blocks) > self.max_blocks_per_seq:
+            raise RuntimeError(f"no room for a {n + n_decode}-token probe")
+        tables = np.full_like(self.tables, -1)
+        tables[0, :len(blocks)] = blocks
+        try:
+            with self._attn_scope():
+                logits, self.cache = self._prefill_chunk(
+                    self.params,
+                    {"tokens": jnp.asarray(np.asarray(prompt, np.int32)[None])},
+                    set_block_tables(self.cache, tables[:1]),
+                    jnp.int32(0), jnp.int32(n - 1))
+                out = [np.asarray(logits[0], np.float32)]
+                for t in range(n_decode):
+                    tokens = np.zeros((self.max_batch, 1), np.int32)
+                    tokens[0, 0] = int(continuation[t])
+                    posv = np.zeros(self.max_batch, np.int32)
+                    posv[0] = n + t
+                    logits, self.cache = self._decode(
+                        self.params, jnp.asarray(tokens),
+                        set_block_tables(self.cache, tables),
+                        jnp.asarray(posv))
+                    out.append(np.asarray(logits[0], np.float32))
+        finally:
+            self.pool.free(blocks, "probe")
+        return np.stack(out)
 
 
 # ---------------------------------------------------------------------------

@@ -43,6 +43,7 @@ from collections import deque
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models.module import tree_map_with_path
 
@@ -377,6 +378,11 @@ def set_block_tables(cache, tables):
     layer caches carry a leading layers axis on every leaf; the tables
     are broadcast across it (all layers share one block table).
     """
+    if isinstance(tables, np.ndarray):
+        # a private copy: on the CPU backend jnp.asarray may alias a host
+        # array zero-copy, and the engine rewrites its table buffer while
+        # the step that reads this one can still be in flight
+        tables = np.array(tables, np.int32)
     tables = jnp.asarray(tables, jnp.int32)
 
     def fix(path, leaf):

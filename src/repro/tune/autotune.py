@@ -70,7 +70,7 @@ def _kernel_fns(kernel: str):
 
 def _default_interpret() -> bool:
     from repro.core import lut_gemm as core_lg
-    return core_lg.INTERPRET
+    return core_lg.interpret_mode()
 
 
 def tune(kernel: str, x: jax.Array, w: BCQWeight, *, mu: int = 4,

@@ -44,11 +44,14 @@ def kernel_unsupported_reason(kernel: str, *, m: int, n: int,
     path instead of collapsing every reason into one boolean).
 
     For the GEMM kernels (callers: the quant backend registry,
-    :mod:`repro.quant.backends`) ``(m, n)`` are the weight dims and the
-    constraints mirror the op wrappers' padding math: plane packing is
-    byte-granular along the input dim (group_size % 8 == 0, which also
-    covers the LUT kernel's mu=4 sub-group split), and the bit-serial
-    loop streams at most 8 planes.
+    :mod:`repro.quant.backends`) ``(m, n)`` are the weight dims.  The
+    limits are the chip's, not the interpreter's: scale groups cover
+    whole packed bytes (group_size % 8 == 0, reason ``"group_size"``);
+    the bit-serial loop streams 1..8 planes (``"bits"``); the LUT
+    kernels build tables for key widths mu in ``LUT_MUS`` (cap ``mu``,
+    reason ``"mu"``); and every block spans the whole reduction dim, so
+    the heuristic launch's double-buffered blocks must fit the kernels'
+    scoped VMEM (reason ``"vmem"``).
 
     For the paged-attention family (``paged_attention`` decode,
     ``paged_prefill`` chunked prefill; caller: ``models.attention``'s
@@ -80,8 +83,8 @@ def kernel_unsupported_reason(kernel: str, *, m: int, n: int,
 
     Reasons: ``"unknown_kernel"``, ``"tp"``, ``"heads"``, ``"shape"``,
     ``"window"``, ``"kv_dtype"``, ``"latent"``, ``"group_size"``,
-    ``"bits"``, ``"kind"``.  Every non-None return is also recorded on
-    the active trace (``record_kernel_unsupported``).
+    ``"bits"``, ``"kind"``, ``"mu"``, ``"vmem"``.  Every non-None return
+    is also recorded on the active trace (``record_kernel_unsupported``).
     """
     reason = _unsupported_reason(kernel, m=m, n=n, group_size=group_size,
                                  bits=bits, **caps)
@@ -131,6 +134,20 @@ def _unsupported_reason(kernel: str, *, m: int, n: int, group_size: int,
             return "kind"
         if kernel != "ternary_matmul" and kind == "ternary":
             return "kind"
+    from repro.kernels.lut_common import (LUT_MUS, VMEM_LIMIT,
+                                          plane_vmem_bytes)
+    from .space import LUT_KERNELS
+    if kernel in LUT_KERNELS and caps.get("mu", 4) not in LUT_MUS:
+        return "mu"
+    cfg = heuristic_config(kernel, b=8, m=m, n=n, group_size=group_size)
+    n_pad = -(-n // cfg.block_n) * cfg.block_n
+    q = 2 if kernel == "ternary_matmul" else (bits or 8)
+    need = plane_vmem_bytes(q=q, n_alpha=q, block_b=cfg.block_b,
+                            block_m=cfg.block_m, n_bytes=n_pad // 8,
+                            n_groups=n_pad // group_size,
+                            chunk=min(cfg.block_n, n_pad) // 8)
+    if need > VMEM_LIMIT:
+        return "vmem"
     return None
 
 

@@ -13,14 +13,19 @@ fallback used when no tuned entry exists (tuning disabled, cold cache,
 or interpret mode off-device): it reproduces the seed defaults clamped
 to the shape, so untuned behavior is exactly the pre-tuner behavior.
 
-TPU tiling constraints (pallas_guide: f32 min tile 8x128, lane dim 128)
-shape the grid: block_n candidates are multiples of 128, block_m/block_b
-multiples of 8.
+TPU tiling constraints (f32 min tile 8x128, lane dim 128) shape the
+grid.  The plane kernels DMA the whole reduction dim per block and walk
+it in ``block_n``-weight chunks, so ``block_n`` is a multiple of 1024
+(128 lane-aligned bytes) or the whole padded dim; ``block_m`` is the
+output tile's lane dim, a multiple of 128 or the whole padded dim;
+``block_b`` is a multiple of 8.  Every config ``clamp_config`` returns
+compiles for the chip (tests/test_tpu_compile.py).
 """
 from __future__ import annotations
 
 import dataclasses
 import itertools
+import math
 from typing import Sequence
 
 KERNELS = ("lut_gemm", "bcq_matmul", "ternary_matmul", "paged_attention",
@@ -36,13 +41,21 @@ LUT_KERNELS = ("lut_gemm", "ternary_matmul")
 # can never collide with decode's (and stale pre-prefill caches miss)
 PAGED_KERNELS = ("paged_attention", "paged_prefill")
 
-READ_MODES = ("onehot", "select", "gather")
+# LUT reads: MXU one-hot contraction or VPU mux sweep (no gathers: the
+# TPU compiler only lowers 2-D gathers)
+READ_MODES = ("onehot", "select")
 
-# enumeration grids (pre-clamp); heuristic defaults are the seed constants
+# lane-tile units of the plane kernels' blocks (see module docstring)
+LANE = 128
+CHUNK_UNIT = 8 * LANE           # weights per 128-byte chunk
+
+# enumeration grids (pre-clamp); the heuristic picks the first entries
 _BLOCK_B = (8, 16, 32)
-_BLOCK_M = (64, 128, 256)
-_BLOCK_N = (256, 512, 1024)
-_BLOCK_H = (0, 1, 2, 4, 8)        # paged_attention kv-head tile (0 = all)
+_BLOCK_M = (128, 256)
+_BLOCK_N = (1024, 2048)
+# paged kv-head tile (0 = all heads).  A partial tile is the lane dim
+# of the int8 scale blocks, so only multiples of 128 compile.
+_BLOCK_H = (0, LANE)
 
 
 def _round_up(v: int, m: int) -> int:
@@ -58,7 +71,7 @@ class KernelConfig:
 
     block_b: int = 8
     block_m: int = 128
-    block_n: int = 512
+    block_n: int = 1024
     read_mode: str = "onehot"
     half_lut: bool = True
     block_h: int = 0                 # paged_attention: kv-head tile (0 = all)
@@ -104,29 +117,45 @@ def clamp_config(cfg: KernelConfig, kernel: str, *, b: int, m: int, n: int,
 
     For ``paged_attention`` the problem dims are remapped: ``m`` is the
     kv-head count, ``n`` the per-sequence KV capacity and ``group_size``
-    the pool block size; the only live axis is ``block_h`` (clamped to a
-    divisor of the head count) and the GEMM tile fields are normalized
-    so configs dedupe.  ``paged_prefill`` shares the same remapping."""
+    the pool block size; the only live axis is ``block_h`` (a divisor of
+    the head count that is a multiple of 128, else all heads — the tiles
+    the chip compiles) and the GEMM tile fields are normalized so
+    configs dedupe.  ``paged_prefill`` shares the same remapping."""
     if kernel in PAGED_KERNELS:
-        return KernelConfig(block_h=divisor_clamp(cfg.block_h, max(m, 1)))
+        hkv = max(m, 1)
+        block_h = divisor_clamp(cfg.block_h, hkv)
+        return KernelConfig(block_h=block_h if block_h % LANE == 0
+                            else hkv)
+    # never beyond the largest tiles of the space: bigger LUT tiles
+    # overrun VMEM (tests/test_tpu_compile.py compiles the largest)
     n_pad = _round_up(max(n, 1), group_size)
-    block_n = _round_up(min(cfg.block_n, n_pad), group_size)
-    block_m = _round_up(min(cfg.block_m, _round_up(max(m, 1), 8)), 8)
-    block_b = _round_up(min(cfg.block_b, _round_up(max(b, 1), 8)), 8)
-    read_mode = cfg.read_mode if kernel in LUT_KERNELS else "onehot"
+    block_n = _snap(min(cfg.block_n, _BLOCK_N[-1]), n_pad,
+                    math.lcm(CHUNK_UNIT, group_size))
+    block_m = _snap(min(cfg.block_m, _BLOCK_M[-1]),
+                    _round_up(max(m, 1), 8), LANE)
+    block_b = _round_up(min(cfg.block_b, _BLOCK_B[-1],
+                            _round_up(max(b, 1), 8)), 8)
+    read_mode = (cfg.read_mode if kernel in LUT_KERNELS
+                 and cfg.read_mode in READ_MODES else "onehot")
     half_lut = cfg.half_lut if kernel == "lut_gemm" else True
     return KernelConfig(block_b=block_b, block_m=block_m, block_n=block_n,
                         read_mode=read_mode, half_lut=half_lut)
+
+
+def _snap(block: int, full: int, unit: int) -> int:
+    """A tile that compiles: the whole (padded) dim, or a multiple of
+    ``unit`` smaller than it."""
+    block = _round_up(max(block, 1), unit)
+    return full if block >= full else block
 
 
 def heuristic_config(kernel: str, *, b: int, m: int, n: int,
                      mu: int = 4, group_size: int = 128) -> KernelConfig:
     """Deterministic no-measurement fallback.
 
-    Reproduces the seed defaults (8, 128, 512, onehot, hFFLUT) with a
-    mild batch scaling — decode (b <= 8) keeps the minimum f32 sublane
-    tile, larger batches grow block_b so the LUT build amortizes over
-    more rows per launch.
+    (8, 128, 1024, onehot, hFFLUT) with a mild batch scaling — decode
+    (b <= 8) keeps the minimum f32 sublane tile, larger batches grow
+    block_b so the LUT build amortizes over more rows per launch.
     """
     if kernel not in KERNELS:
         raise ValueError(f"unknown kernel {kernel!r}; known: {KERNELS}")
@@ -137,7 +166,7 @@ def heuristic_config(kernel: str, *, b: int, m: int, n: int,
         return clamp_config(KernelConfig(block_h=0), kernel, b=b, m=m, n=n,
                             group_size=group_size)
     block_b = 8 if b <= 8 else (16 if b <= 16 else 32)
-    base = KernelConfig(block_b=block_b, block_m=128, block_n=512,
+    base = KernelConfig(block_b=block_b, block_m=128, block_n=1024,
                         read_mode="onehot", half_lut=True)
     return clamp_config(base, kernel, b=b, m=m, n=n, group_size=group_size)
 

@@ -50,7 +50,8 @@ def run_cell_sub(arch: str, shape: str, extra: str = "") -> dict:
     """)
     r = subprocess.run([sys.executable, "-c", prog], capture_output=True,
                        text=True, timeout=560,
-                       env={**os.environ, "PYTHONPATH": SRC})
+                       env={**os.environ, "PYTHONPATH": SRC,
+                            "JAX_PLATFORMS": "cpu"})
     assert r.returncode == 0, r.stderr[-2500:]
     line = [l for l in r.stdout.splitlines() if l.startswith("RESULT")][-1]
     return json.loads(line[len("RESULT"):])

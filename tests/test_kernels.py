@@ -27,6 +27,7 @@ SHAPES = [
     (96, 200, 5),
     (256, 384, 3),
     (33, 130, 2),
+    (64, 2048, 3),      # two 1024-weight chunks: the in-kernel chunk loop
 ]
 
 
@@ -42,7 +43,7 @@ class TestLutGemmKernel:
         np.testing.assert_allclose(np.asarray(got) / scale,
                                    np.asarray(want) / scale, atol=2e-5)
 
-    @pytest.mark.parametrize("read_mode", ["onehot", "select", "gather"])
+    @pytest.mark.parametrize("read_mode", ["onehot", "select"])
     def test_read_modes_agree(self, read_mode):
         W, x = _case(128, 256, 4, seed=11)
         wq = bcq.quantize(W, bits=3, group_size=128, iters=2)

@@ -318,6 +318,20 @@ class TestFusedPrefillKernel:
         np.testing.assert_allclose(np.asarray(got)[live],
                                    np.asarray(want)[live], atol=1e-5)
 
+    def test_row_tiles_agree(self, monkeypatch):
+        """A long chunk's query rows split over several grid steps (each
+        re-streams the pool blocks) give the untiled answer."""
+        import sys
+        kmod = sys.modules["repro.kernels.paged_attention.paged_attention"]
+        _, k, v, pos, tables, positions = _pool_case(9)
+        q, cpos = self._chunk(9, positions, b=3, c=7, h=8, d=16)
+        want = paged_prefill_ref(q, k, v, pos, tables, cpos)
+        monkeypatch.setattr(kmod, "MAX_ROWS", 8)   # 8 heads x 7 -> 7 tiles
+        assert kmod._row_tile(8 * 7) == 8
+        got = paged_prefill(q, k, v, pos, tables, cpos, interpret=True)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-5)
+
     def test_pad_rows_zero_causality_and_block_h(self):
         _, k, v, pos, tables, positions = _pool_case(8)
         q, cpos = self._chunk(8, positions, b=3, c=6, h=8, d=16)
@@ -361,15 +375,17 @@ class TestDispatch:
         cfg = tdispatch.kernel_config("paged_attention", b=4, m=4, n=128,
                                       dtype=jnp.float32, mu=2, group_size=8)
         assert isinstance(cfg, KernelConfig)
-        assert cfg.block_h in (1, 2, 4)          # a divisor of m=4
+        assert cfg.block_h == 4                  # all heads of m=4
 
     def test_divisor_clamp(self):
         assert divisor_clamp(0, 6) == 6
         assert divisor_clamp(4, 6) == 3
         assert divisor_clamp(5, 8) == 4
         assert divisor_clamp(1, 7) == 1
+        # dispatch-side clamp: only tiles the chip compiles (all heads
+        # unless a 128-multiple divisor), whatever a cache entry says
         assert clamp_config(KernelConfig(block_h=5), "paged_attention",
-                            b=1, m=8, n=64, group_size=8).block_h == 4
+                            b=1, m=8, n=64, group_size=8).block_h == 8
 
     def test_candidates_deduped_and_lead_with_heuristic(self):
         cands = candidate_configs("paged_attention", b=2, m=4, n=64,
@@ -426,7 +442,7 @@ class TestDispatch:
         assert "paged_prefill" in key            # disjoint from old keys
         poisoned = clamp_config(KernelConfig(block_h=5), "paged_prefill",
                                 b=2, m=8, n=24, group_size=4)
-        assert poisoned.block_h == 4             # clamped to a divisor of m
+        assert poisoned.block_h == 8             # clamped to a legal tile
 
     def test_paged_kernel_mode_host_mirror(self):
         cfg = get_reduced("opt_6_7b").replace(paged_kernel="fused")

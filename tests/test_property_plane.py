@@ -43,8 +43,8 @@ def test_repack_unpack_identity(W, fmt_name, group_size):
     repacked = plane.pack_planes(planes)
     np.testing.assert_array_equal(np.asarray(repacked),
                                   np.asarray(wq.packed))
-    # unpacked planes are strictly boolean-valued
-    assert set(np.unique(np.asarray(planes))) <= {0, 1}
+    # unpacked planes are ±1 by contract (bit 1 encodes +1)
+    assert set(np.unique(np.asarray(planes))) <= {-1, 1}
 
 
 @given(weight_matrices(), st.sampled_from([16, 32, 64]))

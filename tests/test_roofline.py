@@ -46,6 +46,13 @@ class TestRooflineTerms:
                         coll_breakdown={})
         assert r.bottleneck == "collective"
 
+    def test_peaks_keyed_by_device_kind(self):
+        v5e = ra.device_peaks("TPU v5 lite")
+        assert v5e.bf16_flops == ra.PEAK_FLOPS
+        assert v5e.hbm_bytes_per_s == ra.HBM_BW
+        with pytest.raises(KeyError, match="no published peaks"):
+            ra.device_peaks("cpu")
+
     def test_extrapolation_exact_for_linear(self):
         r1 = ra.Roofline(flops=10, bytes_accessed=100, coll_bytes=4,
                          coll_breakdown={"all-reduce": 4})

@@ -343,6 +343,37 @@ def test_paged_prefill_right_pad_is_dead_write():
 # ---------------------------------------------------------------------------
 
 
+def test_set_block_tables_does_not_alias_host_tables(monkeypatch):
+    """The engine rewrites its host table buffer every tick while the
+    step that reads the previous tables can still be in flight.  A host
+    array handed to JAX must stay unmodified until its transfer
+    completes, so ``set_block_tables`` hands JAX a private copy — a row
+    view of the engine's buffer once let a chunked prefill read the
+    NEXT tick's tables (all-zero tokens under load)."""
+    import repro.serve.paging as paging
+    handed = []
+    real = jnp.asarray
+
+    def spy(x, *a, **kw):
+        handed.append(x)
+        return real(x, *a, **kw)
+
+    m, _ = _model()
+    cache = m.init_paged_cache(1, num_blocks=8, block_size=4,
+                               max_blocks_per_seq=64)
+    host = np.arange(4 * 64, dtype=np.int32).reshape(4, 64)
+    monkeypatch.setattr(paging.jnp, "asarray", spy)
+    out = set_block_tables(cache, host[2:3])
+    monkeypatch.undo()
+    assert handed and not any(isinstance(x, np.ndarray)
+                              and np.shares_memory(x, host) for x in handed)
+    host.fill(-1)
+    leaves = [l for p, l in jax.tree_util.tree_flatten_with_path(out)[0]
+              if "block_tables" in jax.tree_util.keystr(p)]
+    assert leaves and all((np.asarray(l).reshape(-1, 64)
+                           == np.arange(128, 192)).all() for l in leaves)
+
+
 def test_paged_engine_matches_contiguous_greedy():
     """Token-for-token greedy equivalence on a mixed-length stream, with
     prompts longer than the largest bucket (forces chunked prefill)."""

@@ -26,7 +26,8 @@ def run_sub(code: str) -> dict:
             + textwrap.dedent(code))
     r = subprocess.run([sys.executable, "-c", prog], capture_output=True,
                        text=True, timeout=570,
-                       env={**os.environ, "PYTHONPATH": SRC})
+                       env={**os.environ, "PYTHONPATH": SRC,
+                            "JAX_PLATFORMS": "cpu"})
     assert r.returncode == 0, r.stderr[-3000:]
     return json.loads(r.stdout.strip().splitlines()[-1])
 

@@ -223,3 +223,29 @@ class TestServeEngine:
             done = eng.run([Request(uid=0, prompt=prompt, max_new_tokens=5)])
             outs.append(done[0].out_tokens)
         assert outs[0] == outs[1]
+
+
+def test_importing_repro_initializes_no_backend():
+    """Importing any module of the package must not touch a JAX backend:
+    a process that merely imports ``repro`` must not claim the chip, and
+    interpret mode is decided where a kernel launches."""
+    import subprocess
+    import sys
+    import textwrap
+    prog = textwrap.dedent("""
+        import importlib, pkgutil
+        import repro
+        from jax._src import xla_bridge
+        for m in pkgutil.walk_packages(repro.__path__, "repro."):
+            if m.name == "repro.launch.dryrun":   # fakes devices on import
+                continue
+            importlib.import_module(m.name)
+            assert not xla_bridge._backends, m.name
+        print("ok")
+    """)
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    r = subprocess.run([sys.executable, "-c", prog], capture_output=True,
+                       text=True, timeout=300,
+                       env={**os.environ, "PYTHONPATH": src,
+                            "JAX_PLATFORMS": "cpu"})
+    assert r.returncode == 0 and r.stdout.strip() == "ok", r.stderr[-2000:]
