@@ -1,0 +1,21 @@
+"""Model FLOP/s utilization of the whole window: the useful operations of
+every step dispatched in it (every linear on each live decode row and
+each real prefill token, attention over each token's context, the LM
+head where logits are produced) over the window times peak FLOP/s."""
+from bench import counts
+
+UNIT = "%"
+BETTER = "higher"
+SOURCE = "program_span"
+LAYER = "model step"
+MOVES = "output_tok_per_s"
+
+
+def read(ctx):
+    steps = ctx.steps(*ctx.window)
+    if not steps:
+        return None
+    flops = sum(counts.step_model_work(s, ctx.linears, ctx.shape).flops
+                for s in steps)
+    secs = ctx.window[1] - ctx.window[0]
+    return 100.0 * flops / (secs * ctx.peaks.bf16_flops)
