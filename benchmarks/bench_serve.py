@@ -21,7 +21,7 @@ engine, must stay <= 5% of tokens/s) and validates the exported Chrome
 trace.  A fifth section drives open-loop Poisson traffic (seeded,
 tick-indexed — no wall-clock randomness) through the double-buffered
 async tick at three offered loads, reports goodput vs offered load and
-the device-busy fraction, and asserts the async engine's tokens are
+the decode in-flight fraction, and asserts the async engine's tokens are
 identical to the sync engine's with >= 95% of its throughput on the
 saturated workload.  ``--bench-json`` writes the schema-versioned tracked-scalar
 file the perf-trajectory gate (``benchmarks.compare_trajectory``)
@@ -486,7 +486,7 @@ def bench_async_saturation(model, params, cfg, *, requests=8, max_new=8,
 
     The goodput table sweeps under/near/over capacity (service rate is
     roughly ``max_batch / max_new`` requests per tick) and reports
-    completed tokens/s plus the device-busy fraction at each load.  The
+    completed tokens/s plus the decode in-flight fraction at each load.  The
     comparison leg pins the tentpole's acceptance bar: identical tokens
     (greedy AND seeded-sampling requests) and async tokens/s >= 95% of
     the sync engine on the same workload — each timed sample covers
@@ -521,19 +521,19 @@ def bench_async_saturation(model, params, cfg, *, requests=8, max_new=8,
             "goodput_tok_per_s": s["counters"]["tokens_out"] / dt
                                  if dt > 0 else 0.0,
             "queue_delay_ms_p50": s["queue_delay_s"]["p50"] * 1e3,
-            "device_busy_fraction": s["device_busy_fraction"],
+            "decode_inflight_fraction": s["decode_inflight_fraction"],
             "preempted": s["counters"]["preempted"],
             "ticks": ticks,
         }
         print(f"serve,async_load={rate},"
               f"goodput_tok_s={row['goodput_tok_per_s']:.1f},"
               f"queue_delay_ms_p50={row['queue_delay_ms_p50']:.1f},"
-              f"busy={row['device_busy_fraction']:.2f},"
+              f"busy={row['decode_inflight_fraction']:.2f},"
               f"preempted={row['preempted']}")
         load_rows.append(row)
     # saturation keeps the device busier than a trickle
-    assert load_rows[-1]["device_busy_fraction"] \
-        > load_rows[0]["device_busy_fraction"], load_rows
+    assert load_rows[-1]["decode_inflight_fraction"] \
+        > load_rows[0]["decode_inflight_fraction"], load_rows
 
     # -- sync vs async on the saturated workload -----------------------
     sat = _arrival_ticks(rates[-1], requests, seed=23)
@@ -551,7 +551,7 @@ def bench_async_saturation(model, params, cfg, *, requests=8, max_new=8,
                 toks = {r.uid: tuple(r.out_tokens) for r in reqs}
                 assert toks_by_mode.setdefault(mode, toks) == toks
             times[mode].append(dt)
-            busy[mode] = eng.metrics.device_busy_fraction()
+            busy[mode] = eng.metrics.decode_inflight_fraction()
 
     def _tok_s():
         n = streams * sum(len(t) for t in toks_by_mode["sync"].values())
@@ -581,8 +581,8 @@ def bench_async_saturation(model, params, cfg, *, requests=8, max_new=8,
         "tok_per_s_sync": tok_s["sync"],
         "tok_per_s_async": tok_s["async"],
         "async_vs_sync": ratio,
-        "device_busy_fraction_sync": busy["sync"],
-        "device_busy_fraction_async": busy["async"],
+        "decode_inflight_fraction_sync": busy["sync"],
+        "decode_inflight_fraction_async": busy["async"],
     }
     print(f"serve,async_tok_s={tok_s['async']:.1f},"
           f"sync_tok_s={tok_s['sync']:.1f},"
@@ -748,8 +748,9 @@ def write_bench_json(path, rows, kernel_rows, int8_rows, prefix_rows,
         "async_vs_sync_ratio":
             _scalar(async_row["async_vs_sync"], "higher", 0.5,
                     abs_min=0.95),
-        "device_busy_fraction":
-            _scalar(async_row["device_busy_fraction_async"], "higher", 0.5),
+        "decode_inflight_fraction":
+            _scalar(async_row["decode_inflight_fraction_async"], "higher",
+                    0.5),
     }
     data = {"schema_version": 1, "bench": "serve", "scalars": scalars,
             "meta": {"source": "benchmarks.bench_serve",

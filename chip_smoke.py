@@ -64,7 +64,7 @@ PROBE_PROMPT, PROBE_STEPS = 64, 4
 KERNEL_TOL = 1e-3
 LOGITS_TOL = 5e-2
 
-PALLAS_BACKENDS = ("mxu_pallas", "lut_pallas", "ternary_pallas")
+GEMM_KERNELS = ("lut_gemm", "bcq_matmul", "ternary_matmul")
 
 
 def log(msg: str) -> None:
@@ -235,16 +235,18 @@ def phase_serve(model, params, seed: int, backend):
           f"{label}: ran decode on {dict(m.decode_path_steps)}, prefill on "
           f"{dict(m.prefill_path_chunks)}")
     ev = tracer.events
+    # no linear left its kernel preference, and the GEMM kernels were
+    # configured while the programs were traced
     falls = [e["args"] for e in ev if e["name"] == "backend_fallback"]
-    used = sorted({e["args"]["backend"] for e in ev
-                   if e["name"] == "linear_backend"})
+    used = sorted({e["args"]["kernel"] for e in ev
+                   if e["name"].startswith("kernel_config:")
+                   and e["args"]["kernel"] in GEMM_KERNELS})
     check(not falls, f"{label}: linears fell back: {falls[:3]}")
-    check(used and all(b in PALLAS_BACKENDS for b in used),
-          f"{label}: linear backends {used}")
+    check(bool(used), f"{label}: no GEMM kernel was configured")
     s = eng.metrics.summary()
     log(f"serve [{label}]: {len(done)} requests x {MAX_NEW} tokens ok; "
-        f"decode={eng.decode_path} prefill={eng.prefill_path}; linears on "
-        f"{used}; prefix hit blocks "
+        f"decode={eng.decode_path} prefill={eng.prefill_path}; GEMM "
+        f"kernels {used}; prefix hit blocks "
         f"{s['counters'].get('prefix_hit_blocks', 0)} "
         f"[wall {wall:.1f}s incl. compile, informational]")
     obs.trace.set_active(None)

@@ -414,7 +414,8 @@ def main():
               f"preempted={s['counters']['preempted']}")
         print(f"[launch.serve] queue delay "
               f"p50={s['queue_delay_s']['p50']*1e3:.1f}ms  "
-              f"device busy fraction={s['device_busy_fraction']:.2f}  "
+              f"decode in-flight fraction="
+              f"{s['decode_inflight_fraction']:.2f}  "
               f"cancelled={s['counters']['cancelled']} "
               f"deadline-expired={s['counters']['deadline_expired']}")
         pk = s["paged_kernel"]

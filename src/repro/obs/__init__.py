@@ -9,13 +9,16 @@ See ``docs/observability.md``.
 """
 from repro.obs.export import (format_timeline, save_chrome, timeline,
                               to_chrome, validate_chrome)
-from repro.obs.trace import (ENGINE_TRACKS, NULL, SCHEMA_VERSION, NullTracer,
-                             Tracer, activate, get_active,
-                             record_kernel_config, req_track, set_active)
+from repro.obs.trace import (BUILDS, ENGINE_TRACKS, NULL, ORIGIN,
+                             SCHEMA_VERSION, NullTracer, Tracer, activate,
+                             get_active, install_build_listener,
+                             record_kernel_config, replay_builds, req_track,
+                             set_active)
 
 __all__ = [
-    "ENGINE_TRACKS", "NULL", "SCHEMA_VERSION", "NullTracer", "Tracer",
-    "activate", "format_timeline", "get_active", "record_kernel_config",
+    "BUILDS", "ENGINE_TRACKS", "NULL", "ORIGIN", "SCHEMA_VERSION",
+    "NullTracer", "Tracer", "activate", "format_timeline", "get_active",
+    "install_build_listener", "record_kernel_config", "replay_builds",
     "req_track", "save_chrome", "set_active", "timeline", "to_chrome",
     "validate_chrome",
 ]

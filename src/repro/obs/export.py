@@ -11,8 +11,9 @@ trace-event format (the JSON object form), loadable in Perfetto
     a request's whole life (submit -> admit -> prefill chunks ->
     tokens -> retire) reads as one horizontal line.
 
-Timestamps are microseconds (the format's native unit) since tracer
-construction.  ``validate_chrome`` structurally checks an export —
+Timestamps are microseconds (the format's native unit) since the
+tracer's origin (``otherData`` names the clock and gives the origin).
+``validate_chrome`` structurally checks an export —
 tests and CI run it on real serve traces so a malformed artifact fails
 loudly instead of silently refusing to load in Perfetto.
 
@@ -77,6 +78,9 @@ def to_chrome(tracer: Tracer) -> dict:
             "schema_version": SCHEMA_VERSION,
             "events": len(tracer.events),
             "dropped": tracer.dropped,
+            "clock": getattr(tracer.clock, "__name__",
+                             type(tracer.clock).__name__),
+            "origin": tracer.origin,
         },
     }
 
@@ -134,13 +138,15 @@ def validate_chrome(obj: dict) -> List[str]:
 
 def timeline(tracer: Tracer, uid=None) -> List[dict]:
     """Flat time-ordered rows; ``uid`` filters to one request's track
-    plus the engine events that name it in their args."""
+    plus the engine events that name it in their args (``uid``, or
+    ``uids`` for the batched decode, sync, sample and emit spans)."""
     rows = []
     want = None if uid is None else f"req/{uid}"
     for ev in sorted(tracer.events, key=lambda e: e["ts"]):
         args = ev.get("args", {})
         if want is not None and ev["track"] != want \
-                and args.get("uid") != uid:
+                and args.get("uid") != uid \
+                and uid not in args.get("uids", ()):
             continue
         rows.append({
             "ts_ms": ev["ts"] / 1e3,

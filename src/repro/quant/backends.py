@@ -123,10 +123,9 @@ def resolve_backend(preference: Optional[str], w: BCQWeight) -> str:
     mode is a legitimate explicit request); fallback entries and ``auto``
     require ``native()`` so we never silently degrade onto an emulated
     kernel.  ``dense`` always supports everything, so resolution total.
-    Every resolution lands on the active trace
-    (``obs.trace.record_linear_backend``); one that leaves a kernel
-    preference for ``bcq_xla``/``dense`` is recorded as a
-    ``backend_fallback``.
+    A resolution that leaves a kernel preference for ``bcq_xla``/``dense``
+    is recorded on the active trace as a ``backend_fallback``
+    (``obs.trace.record_backend_fallback``).
     """
     pref = preference or "auto"
     chain = fallback_chain(pref)
@@ -138,11 +137,10 @@ def resolve_backend(preference: Optional[str], w: BCQWeight) -> str:
         if usable and info.supports(w):
             name = cand
             break
-    from repro.obs.trace import record_linear_backend
-    record_linear_backend(
-        pref, name,
-        fallback=pref in _KERNEL_PREFERENCES and get_backend(name).kernel is None,
-        m=w.out_features, n=w.in_features, kind=w.kind)
+    if pref in _KERNEL_PREFERENCES and get_backend(name).kernel is None:
+        from repro.obs.trace import record_backend_fallback
+        record_backend_fallback(pref, name, m=w.out_features,
+                                n=w.in_features, kind=w.kind)
     return name
 
 

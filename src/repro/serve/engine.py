@@ -73,6 +73,16 @@ class Request:
     on_token: Optional[Callable] = None   # streaming: fn(token, request)
     error: Optional[str] = None           # "too_long" | "oom" | "callback"
                                           # | "deadline" | "cancelled" | None
+    # the wait for the first token, traced as PHASES: the index of the
+    # phase in progress (len(PHASES) once answered, or when a tracer
+    # attached mid-wait) and its start on the tracer's clock
+    phase: int = 0
+    t_phase: float = 0.0
+
+
+#: a request's wait phases, each closed by the next boundary: first
+#: admission, dispatch of its first prefill chunk, its first token
+PHASES = ("queue", "prefill_wait", "prefill")
 
 
 def _emit(req: Request, tok: int) -> None:
@@ -163,6 +173,7 @@ class _InFlight:
     tokens: object                 # device int32 [max_batch]
     emits: list                    # [(SeqState, row)] in sampling order
     row_of: dict                   # uid -> row, for next tick's decode input
+    uids: list                     # the emits' uids, for the trace
     t_dispatch: float              # engine-clock time of dispatch
     tick: int
 
@@ -218,8 +229,11 @@ class PagedServeEngine:
     ``tracer`` (an :class:`repro.obs.Tracer`, or ``attach_tracer`` after
     construction) records an event-level trace of every tick — spans for
     admission, prefix lookup, prefill chunks, decode dispatch, device
-    sync and sampling on engine-phase tracks, plus a per-request track
-    from submit to retire — exportable as Chrome trace-event JSON via
+    sync and sampling on engine-phase tracks (the batched ones name
+    their requests in ``uids``), every program build on
+    ``engine/compile``, plus a per-request track from submit to retire
+    whose ``queue`` / ``prefill_wait`` / ``prefill`` spans tile the wait
+    for the first token — exportable as Chrome trace-event JSON via
     ``repro.obs.save_chrome`` (see ``docs/observability.md``).  Off by
     default; the hooks run against a no-op ``NullTracer``.  Under
     ``step_async`` the overlap is directly visible: tick N's
@@ -357,16 +371,26 @@ class PagedServeEngine:
     def attach_tracer(self, tracer) -> None:
         """Attach (or detach with ``None``) an ``obs.Tracer``.  Also
         makes it the module-level *active* tracer so kernel-config
-        resolutions in ``tune.dispatch`` — which cannot be handed an
-        instance — record into the same ring."""
+        resolutions in ``tune.dispatch`` and program builds — which
+        cannot be handed an instance — record into the same ring, and
+        writes into it the builds the process logged before.  Requests
+        already waiting for their first token get no phase spans: their
+        phases started on another clock."""
         self.trace = tracer if tracer is not None else obs_trace.NULL
         obs_trace.set_active(tracer)
+        obs_trace.replay_builds(self.trace)
         if hasattr(self, "sched"):
             self.sched.trace = self.trace
+            inflight = self._inflight.emits if self._inflight else ()
+            for req in [*self.sched.waiting,
+                        *(s.req for s in self.sched.running),
+                        *(s.req for s, _ in inflight)]:
+                req.phase = len(PHASES)
 
     # ------------------------------------------------------------------
     def submit(self, req: Request) -> None:
         self.metrics.on_submit(req.uid)
+        req.phase, req.t_phase = 0, self.trace.now_us()
         self.trace.instant("submit", track=req_track(req.uid), cat="request",
                            uid=req.uid, prompt_len=len(req.prompt),
                            max_new=req.max_new_tokens)
@@ -495,7 +519,15 @@ class PagedServeEngine:
             self._key_cache[req.uid] = base
         return jax.random.fold_in(base, index)
 
+    def _close_phase(self, req: Request, now: float) -> None:
+        """End ``req``'s phase in progress at ``now``; the next starts."""
+        self.trace.phase(PHASES[req.phase], req.uid, req.t_phase, now)
+        req.phase += 1
+        req.t_phase = now
+
     def _emit_token(self, seq, tok: int) -> None:
+        if seq.req.phase == 2:           # its first token ends "prefill"
+            self._close_phase(seq.req, self.trace.now_us())
         _emit(seq.req, tok)
         self.metrics.on_token(seq.req.uid)
         self.trace.instant(
@@ -534,7 +566,10 @@ class PagedServeEngine:
             self.trace.instant("reject", track=req_track(req.uid),
                                cat="request", uid=req.uid, error=req.error)
             self.finished.append(req)
+        now = self.trace.now_us() if plan.admitted else 0.0
         for seq in plan.admitted:
+            if seq.req.phase == 0:       # first admission ends "queue"
+                self._close_phase(seq.req, now)
             self.metrics.on_admit(seq.req.uid)
             self.trace.instant("admit", track=req_track(seq.req.uid),
                                cat="request", uid=seq.req.uid, row=seq.row,
@@ -599,6 +634,7 @@ class PagedServeEngine:
         run one decode batch and at most one prefill chunk, blocking on
         the device and sampling on the host.  Any async in-flight step
         is flushed first, so the two modes can interleave safely."""
+        builds = obs_trace.BUILDS.total
         self.flush()
         self.trace.tick = self.ticks
         with self.trace.span("tick", track="engine/tick",
@@ -606,6 +642,7 @@ class PagedServeEngine:
                              running=len(self.sched.running),
                              waiting=len(self.sched.waiting)):
             self._step_traced()
+        self.metrics.on_builds(obs_trace.BUILDS.total - builds)
 
     def _step_traced(self) -> None:
         plan = self._plan_and_apply()
@@ -621,11 +658,11 @@ class PagedServeEngine:
                 tokens[seq.row, 0] = seq.req.out_tokens[-1]
                 posv[seq.row] = seq.kv_len
             cache = set_block_tables(self.cache, tables)
+            uids = [s.uid for s in plan.decode]
             t_disp = self.clock()
             with self.trace.span("decode_dispatch", track="engine/decode",
                                  rows=len(plan.decode),
-                                 path=self.decode_path,
-                                 uids=[s.uid for s in plan.decode]):
+                                 path=self.decode_path, uids=uids):
                 with self._attn_scope():
                     logits, self.cache = self._decode(
                         self.params, jnp.asarray(tokens), cache,
@@ -634,14 +671,14 @@ class PagedServeEngine:
             # the device computation (step_async hides exactly this span
             # behind the next tick's planning and dispatch)
             with self.trace.span("device_sync", track="engine/sync",
-                                 rows=len(plan.decode)):
+                                 rows=len(plan.decode), uids=uids):
                 logits = np.asarray(logits)
             self.metrics.on_device_interval(t_disp, self.clock())
             fused_b, gathered_b = self._decode_kv_bytes(plan.decode)
             self.metrics.on_decode_step(len(plan.decode), fused_b,
                                         gathered_b, self.decode_path)
             with self.trace.span("sample", track="engine/sample",
-                                 rows=len(plan.decode)):
+                                 rows=len(plan.decode), uids=uids):
                 for seq in plan.decode:
                     seq.kv_len += 1
                     tok = _sample_host(seq.req, logits[seq.row],
@@ -652,7 +689,7 @@ class PagedServeEngine:
             logits, seq = self._dispatch_prefill(plan.prefill)
             if seq.kv_len >= seq.prefill_target:
                 with self.trace.span("sample", track="engine/sample",
-                                     rows=1):
+                                     rows=1, uids=[seq.uid]):
                     tok = _sample_host(seq.req, np.asarray(logits)[0],
                                        self.rng_seed)
                     self._emit_token(seq, tok)
@@ -663,6 +700,8 @@ class PagedServeEngine:
         """Dispatch one prefill chunk (shared by both tick modes);
         advances ``kv_len`` and returns (device logits [1, V], seq)."""
         seq, start, clen = pf.seq, pf.start, pf.length
+        if seq.req.phase == 1:           # first chunk ends "prefill_wait"
+            self._close_phase(seq.req, self.trace.now_us())
         bucket = self.sched.bucket(clen)
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :clen] = seq.tokens[start:start + clen]
@@ -696,6 +735,7 @@ class PagedServeEngine:
         (tick N's compute consumes tick N-1's cache output), which is
         what makes freeing blocks at dispatch safe — the device has, in
         program order, already read them."""
+        builds = obs_trace.BUILDS.total
         self.trace.tick = self.ticks
         with self.trace.span("tick", track="engine/tick", mode="async",
                              free_blocks=self.pool.free_blocks,
@@ -703,6 +743,7 @@ class PagedServeEngine:
                              waiting=len(self.sched.waiting),
                              inflight=self._inflight is not None):
             self._step_async_traced()
+        self.metrics.on_builds(obs_trace.BUILDS.total - builds)
 
     def _step_async_traced(self) -> None:
         prev, self._inflight = self._inflight, None
@@ -767,7 +808,7 @@ class PagedServeEngine:
             logits, seq = self._dispatch_prefill(plan.prefill)
             if seq.kv_len >= seq.prefill_target:
                 with self.trace.span("sample", track="engine/sample",
-                                     rows=1, mode="async"):
+                                     rows=1, mode="async", uids=[seq.uid]):
                     idx = len(seq.req.out_tokens) + seq.inflight
                     key = (np.asarray(self._request_key(seq.req, idx))
                            if seq.req.temperature > 0
@@ -789,10 +830,10 @@ class PagedServeEngine:
         self._sync_prev(prev)
         if emits:
             t_disp = t_disp if plan.decode else self.clock()
+            row_of = {s.uid: row for s, row in emits}
             self._inflight = _InFlight(
-                tokens=cur_tokens, emits=emits,
-                row_of={s.uid: row for s, row in emits},
-                t_dispatch=t_disp, tick=self.ticks)
+                tokens=cur_tokens, emits=emits, row_of=row_of,
+                uids=list(row_of), t_dispatch=t_disp, tick=self.ticks)
         self._tick_metrics()
 
     def _maybe_finish_async(self, seq) -> None:
@@ -812,11 +853,12 @@ class PagedServeEngine:
         if prev is None:
             return
         with self.trace.span("device_sync", track="engine/sync",
-                             rows=len(prev.emits), sync_tick=prev.tick):
+                             rows=len(prev.emits), sync_tick=prev.tick,
+                             uids=prev.uids):
             toks = np.asarray(prev.tokens)
         self.metrics.on_device_interval(prev.t_dispatch, self.clock())
         with self.trace.span("emit", track="engine/sample",
-                             rows=len(prev.emits)):
+                             rows=len(prev.emits), uids=prev.uids):
             for seq, row in prev.emits:
                 self._emit_async(seq, int(toks[row]))
 
@@ -830,6 +872,8 @@ class PagedServeEngine:
         req = seq.req
         if req.done:
             return
+        if req.phase == 2:               # its first token ends "prefill"
+            self._close_phase(req, self.trace.now_us())
         _emit(req, tok)
         self.metrics.on_token(req.uid)
         self.trace.instant(

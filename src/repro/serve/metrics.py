@@ -75,6 +75,29 @@ class Histogram:
                 "min": self.min, "max": self.max}
 
 
+class Gauge:
+    """Running mean and peak of a per-tick sample (no per-tick list)."""
+
+    __slots__ = ("n", "total", "peak")
+
+    def __init__(self):
+        self.n = 0
+        self.total = 0.0
+        self.peak = float("-inf")
+
+    def observe(self, v: float) -> None:
+        self.n += 1
+        self.total += v
+        if v > self.peak:
+            self.peak = v
+
+    def mean(self, empty: float = 0.0) -> float:
+        return self.total / self.n if self.n else empty
+
+    def max(self, empty: float = 0.0) -> float:
+        return self.peak if self.n else empty
+
+
 class ServeMetrics:
     """Per-engine counters + TTFT / inter-token latency / occupancy."""
 
@@ -95,9 +118,11 @@ class ServeMetrics:
                          "prefix_lookups": 0, "prefix_hit_requests": 0,
                          "prefix_queried_blocks": 0, "prefix_hit_blocks": 0,
                          "prefix_tokens_saved": 0, "prefix_cow_events": 0,
-                         "prefix_cow_tokens": 0, "prefix_evictions": 0}
-        # device-busy accounting: dispatch->sync windows, union-merged so
-        # overlapping double-buffered steps never double-count
+                         "prefix_cow_tokens": 0, "prefix_evictions": 0,
+                         "programs_built": 0}
+        # decode-in-flight accounting: dispatch->sync windows on the host
+        # clock, union-merged so overlapping double-buffered steps never
+        # double-count
         self._busy_time = 0.0
         self._busy_until = float("-inf")
         self._admitted_once: set = set()
@@ -106,10 +131,10 @@ class ServeMetrics:
         # negotiation change mid-run), so count per path and report both
         self.decode_path_steps: Dict[str, int] = {}
         self.prefill_path_chunks: Dict[str, int] = {}
-        self.occupancy: List[float] = []       # one sample per tick
-        self.active: List[int] = []            # concurrent running seqs
-        self.sharing: List[float] = []         # logical/physical blocks
-        self.prefix_cached: List[int] = []     # cache-held blocks per tick
+        self.occupancy = Gauge()               # pool share allocated
+        self.active = Gauge()                  # concurrent running seqs
+        self.sharing = Gauge()                 # logical/physical blocks
+        self.prefix_cached = Gauge()           # cache-held blocks
         self._t_submit: Dict[int, float] = {}
         self._t_last_tok: Dict[int, float] = {}
         self._t0 = clock()
@@ -201,17 +226,22 @@ class ServeMetrics:
                 prefix_cached: Optional[int] = None,
                 prefix_evictions: Optional[int] = None) -> None:
         self.counters["ticks"] += 1
-        self.occupancy.append(float(occupancy))
-        self.active.append(int(active))
+        self.occupancy.observe(float(occupancy))
+        self.active.observe(int(active))
         if logical_blocks is not None and physical_blocks:
             # effective-capacity gauge: block-table entries across running
             # sequences over distinct pool blocks in use.  > 1.0 means
             # sharing is letting logical context exceed physical KV.
-            self.sharing.append(logical_blocks / physical_blocks)
+            self.sharing.observe(logical_blocks / physical_blocks)
         if prefix_cached is not None:
-            self.prefix_cached.append(int(prefix_cached))
+            self.prefix_cached.observe(int(prefix_cached))
         if prefix_evictions is not None:
             self.counters["prefix_evictions"] = int(prefix_evictions)
+
+    def on_builds(self, n: int) -> None:
+        """Programs built (compiled or loaded from the persistent cache)
+        while the engine ran one tick."""
+        self.counters["programs_built"] += n
 
     def on_prefill_chunk(self, tokens: int = 0, fused_bytes: int = 0,
                          gathered_bytes: int = 0,
@@ -274,22 +304,19 @@ class ServeMetrics:
         dt = self.clock() - t0
         return self.counters["tokens_out"] / dt if dt > 0 else 0.0
 
-    def device_busy_fraction(self) -> float:
-        """Fraction of serving wall time (since first admission) covered
-        by a dispatched-but-unsynced decode step.  An *estimate of host-
-        side overlap*, not a device counter: prefill-only phases count
-        as idle on both tick modes, so the sync and async engines are
-        directly comparable — the async engine's whole point is pushing
-        this toward 1.0."""
+    def decode_inflight_fraction(self) -> float:
+        """Share of serving wall time (since first admission), on the
+        host clock, with a dispatched-but-unsynced decode step.  Host-
+        side overlap, not a device measurement: prefill-only phases
+        count as empty on both tick modes, so the sync and async engines
+        are directly comparable — the async engine's whole point is
+        pushing this toward 1.0."""
         if self._t_first_admit is None:
             return 0.0
         dt = self.clock() - self._t_first_admit
         return min(1.0, self._busy_time / dt) if dt > 0 else 0.0
 
     def summary(self) -> Dict:
-        occ = np.asarray(self.occupancy) if self.occupancy else np.zeros(1)
-        act = np.asarray(self.active) if self.active else np.zeros(1)
-        shr = np.asarray(self.sharing) if self.sharing else np.ones(1)
         ndec = max(self.counters["decode_tokens"], 1)
         npre = max(self.counters["prefill_tokens"], 1)
         nq = max(self.counters["prefix_queried_blocks"], 1)
@@ -299,10 +326,10 @@ class ServeMetrics:
             "per_token_s": self.per_token.summary(),
             "queue_delay_s": self.queue_delay.summary(),
             "throughput_tok_s": self.throughput(),
-            "device_busy_fraction": self.device_busy_fraction(),
-            "occupancy": {"mean": float(occ.mean()),
-                          "peak": float(occ.max())},
-            "peak_active": int(act.max()),
+            "decode_inflight_fraction": self.decode_inflight_fraction(),
+            "occupancy": {"mean": self.occupancy.mean(),
+                          "peak": self.occupancy.max()},
+            "peak_active": int(self.active.max()),
             "paged_kernel": {
                 "path": self.decode_path,
                 "steps_by_path": dict(self.decode_path_steps),
@@ -323,12 +350,11 @@ class ServeMetrics:
                 "tokens_saved": self.counters["prefix_tokens_saved"],
                 "cow_events": self.counters["prefix_cow_events"],
                 "evictions": self.counters["prefix_evictions"],
-                "cached_blocks_peak":
-                    max(self.prefix_cached) if self.prefix_cached else 0,
+                "cached_blocks_peak": int(self.prefix_cached.max()),
             },
             "effective_capacity": {     # 1.0 == no sharing (cache off)
-                "mean": float(shr.mean()),
-                "peak": float(shr.max()),
+                "mean": self.sharing.mean(1.0),
+                "peak": self.sharing.max(1.0),
             },
         }
 

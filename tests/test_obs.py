@@ -7,22 +7,31 @@ active-tracer escape hatch ``tune.dispatch`` records kernel-config
 resolutions through, Chrome trace-event export (structure, lane
 metadata, JSON round-trip, validator catching injected corruption),
 per-request timeline filtering, a real traced engine run producing >= 1
-span per serving phase plus per-request tracks, the metrics satellites
-(exact histogram extremes under reservoir eviction, per-path decode-step
-counts, first-admission throughput clock), README glossary sync with
+span per serving phase plus per-request tracks, request phase spans that
+tile the wait for the first token, uids on every batched engine span,
+program builds on ``engine/compile`` (one span per build, persistent-cache
+loads marked, builds before attach replayed at non-negative times, the
+process log bounded), the metrics satellites (exact histogram extremes
+under reservoir eviction, per-path decode-step counts, first-admission
+throughput clock, running per-tick gauges), README glossary sync with
 ``ServeMetrics.summary()``, and ``benchmarks.compare_trajectory``
 failing on injected regressions while passing identity/improvement.
 """
 import json
 import os
+import subprocess
+import sys
+import textwrap
 
 import numpy as np
 import pytest
 
-from repro.obs import (ENGINE_TRACKS, NULL, SCHEMA_VERSION, NullTracer,
-                       Tracer, activate, format_timeline, get_active,
-                       record_kernel_config, req_track, save_chrome,
-                       set_active, timeline, to_chrome, validate_chrome)
+from repro.obs import (BUILDS, ENGINE_TRACKS, NULL, ORIGIN, SCHEMA_VERSION,
+                       NullTracer, Tracer, activate, format_timeline,
+                       get_active, record_kernel_config, replay_builds,
+                       req_track, save_chrome, set_active, timeline,
+                       to_chrome, validate_chrome)
+from repro.obs.trace import BUILD_EVENT, BuildLog
 from repro.serve.metrics import Histogram, ServeMetrics
 
 import benchmarks.compare_trajectory as traj
@@ -110,6 +119,30 @@ class TestTracer:
         assert n.events == [] and n.tracks() == [] and n.dropped == 0
         assert NULL.now_us() == 0.0
 
+    def test_default_clock_counts_from_the_process_origin(self):
+        import time
+        a = Tracer()
+        b = Tracer()
+        assert a.origin == b.origin == ORIGIN
+        # an earlier process-clock reading maps to a non-negative time
+        t = time.perf_counter() - 1e-3
+        assert 0.0 <= a.at_us(t) == pytest.approx(b.at_us(t))
+        assert a.at_us(t) < a.now_us()
+        # an injected clock still counts from construction
+        assert _tracer().origin == 0.0 and _tracer().now_us() == 0.0
+
+    def test_phase_is_a_request_span_with_its_uid(self):
+        tr = _tracer()
+        tr.tick = 2
+        tr.phase("queue", 5, 10.0, 35.0)
+        (ev,) = tr.events
+        assert (ev["name"], ev["ph"], ev["track"], ev["cat"]) == \
+            ("queue", "X", "req/5", "request")
+        assert ev["ts"] == 10.0 and ev["dur"] == 25.0
+        assert ev["args"] == {"uid": 5, "tick": 2}
+        NULL.phase("queue", 5, 10.0, 35.0)             # no-op
+        assert NULL.events == []
+
 
 class TestActiveTracer:
     def test_activate_restores_previous(self):
@@ -181,6 +214,10 @@ class TestChromeExport:
         span = next(e for e in evs if e["ph"] == "X")
         assert span["dur"] >= 0
         assert obj["otherData"]["schema_version"] == SCHEMA_VERSION
+        assert obj["otherData"]["clock"] == "_Clock"
+        assert obj["otherData"]["origin"] == 0.0
+        assert to_chrome(Tracer())["otherData"]["clock"] == "perf_counter"
+        assert to_chrome(Tracer())["otherData"]["origin"] == ORIGIN
 
     def test_json_round_trip_still_validates(self, tmp_path):
         tr = _populated_tracer()
@@ -241,7 +278,7 @@ class TestChromeExport:
 
 class TestEngineTracing:
     @pytest.fixture(scope="class")
-    def traced_run(self):
+    def traced_engine(self):
         import jax
         import jax.numpy as jnp
         from repro.configs import get_reduced
@@ -264,7 +301,11 @@ class TestEngineTracing:
         done = eng.run(reqs, max_ticks=100)
         set_active(None)
         assert len(done) == 2 and all(r.error is None for r in done)
-        return tracer
+        return tracer, eng
+
+    @pytest.fixture(scope="class")
+    def traced_run(self, traced_engine):
+        return traced_engine[0]
 
     def test_every_serving_phase_has_a_span(self, traced_run):
         spans = {e["name"] for e in traced_run.events if e["ph"] == "X"}
@@ -290,6 +331,59 @@ class TestEngineTracing:
         assert validate_chrome(to_chrome(traced_run)) == []
         assert traced_run.dropped == 0
 
+    def test_request_phases_tile_the_wait_for_the_first_token(
+            self, traced_run):
+        for uid in (0, 1):
+            evs = {e["name"]: e for e in traced_run.events
+                   if e["track"] == req_track(uid)}
+            q, w, p = evs["queue"], evs["prefill_wait"], evs["prefill"]
+            assert all(e["ph"] == "X" and e["args"]["uid"] == uid
+                       for e in (q, w, p))
+            # the phase stamps sit just before the lifecycle instants
+            assert 0 <= evs["submit"]["ts"] - q["ts"] < 1e3
+            assert q["ts"] + q["dur"] == pytest.approx(w["ts"], abs=1e-3)
+            assert w["ts"] + w["dur"] == pytest.approx(p["ts"], abs=1e-3)
+            end = p["ts"] + p["dur"]
+            assert 0 <= evs["first_token"]["ts"] - end < 1e3
+            first_chunk = min(e["ts"] for e in traced_run.events
+                              if e["name"] == "prefill_chunk"
+                              and e["ph"] == "X" and e["args"]["uid"] == uid)
+            assert w["ts"] + w["dur"] <= first_chunk
+
+    def test_batched_spans_name_their_requests(self, traced_run):
+        for name in ("decode_dispatch", "device_sync", "sample"):
+            spans = [e for e in traced_run.events if e["name"] == name]
+            assert spans and all(e["args"]["uids"] for e in spans), name
+        served = {r["name"] for r in timeline(traced_run, uid=1)
+                  if r["track"].startswith("engine/")}
+        assert {"decode_dispatch", "device_sync", "sample",
+                "prefill_chunk"} <= served
+
+    def test_first_build_in_the_run_gives_one_compile_span(self,
+                                                           traced_run):
+        builds = [e for e in traced_run.events if e["name"] == "compile"]
+        assert all(e["track"] == "engine/compile" and e["ph"] == "X"
+                   and e["ts"] >= 0 and e["dur"] >= 0 for e in builds)
+        for prog in ("jit(decode_step)", "jit(prefill_chunk)"):
+            (ev,) = [e for e in builds if e["args"]["fun_name"] == prog]
+            assert ev["args"]["cache_hit"] is False
+            assert ev["args"]["tick"] >= 0          # built inside a tick
+
+    def test_builds_before_attach_are_replayed(self, traced_run):
+        # the parameters were drawn before the engine attached its tracer
+        first_submit = min(e["ts"] for e in traced_run.events
+                           if e["name"] == "submit")
+        early = [e for e in traced_run.events if e["name"] == "compile"
+                 and e["ts"] + e["dur"] <= first_submit]
+        assert early and all(e["ts"] >= 0 for e in early)
+
+    def test_builds_inside_ticks_are_counted(self, traced_engine):
+        tracer, eng = traced_engine
+        built = eng.metrics.summary()["counters"]["programs_built"]
+        in_ticks = [e for e in tracer.events if e["name"] == "compile"
+                    and "tick" in e["args"]]
+        assert built >= 2 and built == len(in_ticks)
+
     def test_untraced_engine_holds_null_tracer(self):
         # constructing engines is expensive; check the default wiring on
         # the scheduler level instead of building a second engine
@@ -297,6 +391,111 @@ class TestEngineTracing:
         sched = Scheduler(BlockPool(num_blocks=4, block_size=4), rows=2,
                           buckets=(16,), max_blocks_per_seq=4)
         assert isinstance(sched.trace, NullTracer)
+
+
+# ---------------------------------------------------------------------------
+# program builds
+# ---------------------------------------------------------------------------
+
+
+_CACHE_PROG = """
+import json, sys, tempfile
+import jax, jax.numpy as jnp
+jax.config.update("jax_compilation_cache_dir", tempfile.mkdtemp())
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+import repro.serve
+from repro import obs
+tracer = obs.Tracer()
+obs.set_active(tracer)
+f = lambda x: jnp.sin(x) * 2
+x = jnp.ones((8,))
+jax.jit(f)(x).block_until_ready()
+jax.clear_caches()                       # the second build loads from disk
+jax.jit(f)(x).block_until_ready()
+print(json.dumps([e["args"]["cache_hit"] for e in tracer.events
+                  if e["name"] == "compile"
+                  and e["args"]["fun_name"] == "jit(<lambda>)"]))
+"""
+
+
+class TestProgramBuilds:
+    def test_persistent_cache_load_is_marked(self, tmp_path):
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        src = os.path.join(os.path.dirname(__file__), os.pardir, "src")
+        env["PYTHONPATH"] = os.pathsep.join(
+            [src] + [p for p in [env.get("PYTHONPATH")] if p])
+        out = subprocess.run([sys.executable, "-c", _CACHE_PROG],
+                             env=env, capture_output=True, text=True,
+                             timeout=300, cwd=str(tmp_path))
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert json.loads(out.stdout.splitlines()[-1]) == [False, True]
+
+    def test_builds_before_attach_arrive_with_non_negative_times(self):
+        import jax
+        import jax.numpy as jnp
+        import repro.serve  # noqa: F401  (installs the listener)
+        set_active(None)
+        jax.jit(lambda x: x * 3 + 1)(jnp.arange(5)).block_until_ready()
+        tr = Tracer()
+        replay_builds(tr)
+        (ev,) = [e for e in tr.events if e["name"] == "compile"
+                 and e["ts"] + e["dur"] >= 0
+                 and e["args"]["fun_name"] == "jit(<lambda>)"][-1:]
+        assert 0 <= ev["ts"] and ev["ts"] + ev["dur"] <= tr.now_us()
+        n = len(tr.events)
+        replay_builds(tr)                  # nothing is written twice
+        assert len(tr.events) == n
+
+    def test_live_build_goes_to_the_active_tracer_once(self):
+        import jax
+        import jax.numpy as jnp
+        import repro.serve  # noqa: F401
+        x = jnp.arange(3)
+        tr = Tracer()
+        replay_builds(tr)
+        before = len(tr.events)
+        with activate(tr):
+            jax.jit(lambda x: x - 7)(x).block_until_ready()
+        new = [e for e in tr.events[before:] if e["name"] == "compile"]
+        assert [e["args"]["fun_name"] for e in new] == ["jit(<lambda>)"]
+        n = len(tr.events)
+        replay_builds(tr)                  # already written live
+        assert len(tr.events) == n
+
+    def test_build_log_is_bounded_and_untraced_builds_keep_no_event(self):
+        log = BuildLog(capacity=3)
+        set_active(None)
+        for i in range(10):
+            log.on_duration(BUILD_EVENT, 0.5, fun_name=f"jit(p{i})")
+        log.on_duration("/jax/other", 0.5)                 # not a build
+        assert log.total == 10 and len(log.records) == 3
+        assert [r["fun_name"] for r in log.records] == \
+            ["jit(p7)", "jit(p8)", "jit(p9)"]
+        assert NULL.events == []
+        assert len(BUILDS.records) <= BUILDS.records.maxlen
+
+    def test_untraced_engine_run_records_nothing(self):
+        import jax
+        from repro.configs import get_reduced
+        from repro.models import Model
+        from repro.serve import PagedServeEngine, Request
+        cfg = get_reduced("opt_6_7b").replace(remat=False, dtype="float32")
+        model = Model(cfg)
+        params = model.init(jax.random.PRNGKey(1))
+        set_active(None)
+        eng = PagedServeEngine(model, params, num_blocks=16, block_size=8,
+                               max_batch=2, max_seq_len=64,
+                               prefill_buckets=(16,))
+        rng = np.random.default_rng(4)
+        reqs = [Request(uid=i, prompt=rng.integers(0, cfg.vocab_size, (7,)),
+                        max_new_tokens=3) for i in range(2)]
+        done = eng.run_async(reqs, max_ticks=100)
+        assert all(r.error is None for r in done)
+        assert eng.trace is NULL and get_active() is None
+        assert NULL.events == [] and NULL.total == 0
+        assert eng.metrics.summary()["counters"]["programs_built"] >= 1
+        assert len(BUILDS.records) <= BUILDS.records.maxlen
 
 
 # ---------------------------------------------------------------------------
@@ -338,6 +537,19 @@ class TestMetricsSatellites:
         pk = m.summary()["paged_kernel"]
         assert pk["path"] == "mixed"
         assert pk["steps_by_path"] == {"fused": 2, "gather": 1}
+
+    def test_per_tick_gauges_keep_mean_and_peak_only(self):
+        m = ServeMetrics(clock=_Clock())
+        for occ, act in ((0.25, 1), (0.75, 3), (0.5, 2)):
+            m.on_tick(occ, act, logical_blocks=6, physical_blocks=3,
+                      prefix_cached=act)
+        s = m.summary()
+        assert s["occupancy"] == {"mean": pytest.approx(0.5), "peak": 0.75}
+        assert s["peak_active"] == 3
+        assert s["effective_capacity"] == {"mean": 2.0, "peak": 2.0}
+        assert s["prefix_cache"]["cached_blocks_peak"] == 3
+        assert not isinstance(m.occupancy, list)
+        assert m.occupancy.n == 3
 
     def test_throughput_clock_starts_at_first_admission(self):
         clk = _Clock()

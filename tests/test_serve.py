@@ -1090,7 +1090,7 @@ def test_async_engine_overlaps_device_windows():
         done = (eng.run(reqs, max_ticks=300) if mode == "sync"
                 else eng.run_async(reqs, max_ticks=300))
         assert all(r.error is None for r in done)
-        busy[mode] = eng.metrics.device_busy_fraction()
+        busy[mode] = eng.metrics.decode_inflight_fraction()
     assert 0.0 < busy["sync"] <= 1.0
     assert busy["async"] > busy["sync"], busy
 

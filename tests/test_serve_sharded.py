@@ -261,8 +261,8 @@ def test_sharded_async_engine_matches_sync():
     print(json.dumps({
         "equal": got == ref,
         "path": eng.decode_path,
-        "busy_async": eng.metrics.device_busy_fraction(),
-        "busy_sync": ref_eng.metrics.device_busy_fraction(),
+        "busy_async": eng.metrics.decode_inflight_fraction(),
+        "busy_sync": ref_eng.metrics.decode_inflight_fraction(),
         "pool_free": eng.pool.free_blocks == eng.pool.capacity,
     }))
     """, prelude=_COMMON)
