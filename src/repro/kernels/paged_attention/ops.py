@@ -26,6 +26,17 @@ from repro.tune.space import divisor_clamp
 from . import paged_attention as _k
 
 
+def live_pages(tables, q_pos, block_size: int, xp=jnp):
+    """Pages each row can see: ``q_pos // block_size + 1`` (capped at the
+    table width), 0 for a row with no live query (``q_pos < 0``) or one
+    parked on the trash block (``tables[b, 0] < 0``).  No page past it
+    can hold a slot the liveness rule admits.  ``xp=numpy`` gives the
+    host's count of the same rule."""
+    n = xp.minimum(q_pos // block_size + 1, tables.shape[1])
+    return xp.where((tables[:, 0] >= 0) & (q_pos >= 0), n, 0).astype(
+        xp.int32)
+
+
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     pos_pool: jax.Array, tables: jax.Array,
                     positions: jax.Array, *, scale: Optional[float] = None,
@@ -61,9 +72,11 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     # to decode_attend so fused and gathered paths stay interchangeable
     qg = (q.reshape(b, hkv, rep, d).astype(jnp.float32) * scale
           ).astype(k_pool.dtype)
+    tables = jnp.asarray(tables, jnp.int32)
+    positions = jnp.asarray(positions, jnp.int32)
     out = _k.paged_attention_tiled(
-        qg, k_pool, v_pool, jnp.asarray(pos_pool, jnp.int32),
-        jnp.asarray(tables, jnp.int32), jnp.asarray(positions, jnp.int32),
+        qg, k_pool, v_pool, jnp.asarray(pos_pool, jnp.int32), tables,
+        positions, live_pages(tables, positions, bs),
         block_size=bs, block_h=block_h, interpret=interpret)
     return out.reshape(b, h, d).astype(out_dtype or q.dtype)
 
@@ -105,11 +118,12 @@ def paged_attention_int8(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     # q scaling still happens in f32 before the rounding
     qg = (q.reshape(b, hkv, rep, d).astype(jnp.float32) * scale
           ).astype(jnp.bfloat16)
+    tables = jnp.asarray(tables, jnp.int32)
+    positions = jnp.asarray(positions, jnp.int32)
     out = _k.paged_attention_tiled(
-        qg, k_pool, v_pool, jnp.asarray(pos_pool, jnp.int32),
-        jnp.asarray(tables, jnp.int32), jnp.asarray(positions, jnp.int32),
-        k_scale, v_scale, block_size=bs, block_h=block_h,
-        interpret=interpret)
+        qg, k_pool, v_pool, jnp.asarray(pos_pool, jnp.int32), tables,
+        positions, live_pages(tables, positions, bs), k_scale, v_scale,
+        block_size=bs, block_h=block_h, interpret=interpret)
     return out.reshape(b, h, d).astype(out_dtype or q.dtype)
 
 
@@ -186,9 +200,11 @@ def paged_prefill(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     cdt = jnp.bfloat16 if int8 else k_pool.dtype
     qg = (q.reshape(b, c, hkv, rep, d).astype(jnp.float32) * scale
           ).astype(cdt)
+    tables = jnp.asarray(tables, jnp.int32)
+    positions = jnp.asarray(positions, jnp.int32)
     out = _k.paged_prefill_tiled(
-        qg, k_pool, v_pool, jnp.asarray(pos_pool, jnp.int32),
-        jnp.asarray(tables, jnp.int32), jnp.asarray(positions, jnp.int32),
+        qg, k_pool, v_pool, jnp.asarray(pos_pool, jnp.int32), tables,
+        positions, live_pages(tables, positions.max(axis=1), bs),
         k_scale, v_scale, block_size=bs, block_h=block_h,
         interpret=interpret)
     return out.reshape(b, c, h, d).astype(out_dtype or q.dtype)

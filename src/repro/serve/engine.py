@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.paged_attention.ops import live_pages
 from repro.models import Model
 from repro.models.model import sample_tokens
 from repro.obs import trace as obs_trace
@@ -472,39 +473,31 @@ class PagedServeEngine:
             self._retire(seq)
 
     # ------------------------------------------------------------------
-    def _decode_kv_bytes(self, decode) -> tuple:
-        """Analytic per-step KV traffic of both decode paths (bytes).
+    def _kv_pages(self, tables: np.ndarray, q_pos: np.ndarray) -> tuple:
+        """(live, grid) page-steps of one paged-attention call: the pages
+        the fused kernels read (:func:`live_pages` over the call's rows)
+        and ``rows x max_blocks_per_seq``, the steps of a grid that walks
+        every table entry.  The ``kv_pages_live`` / ``kv_pages_grid``
+        arguments of the ``decode_dispatch`` and ``prefill_chunk``
+        spans."""
+        live = int(live_pages(tables, q_pos, self.block_size, xp=np).sum())
+        return live, len(tables) * self.max_blocks_per_seq
 
-        fused: every *live* pool block is read exactly once per layer
-        (the kernel DMAs blocks through the block table).
-        gathered: ``paged_view`` reads B x pages pool blocks (unallocated
-        entries still fetch the trash block), writes the contiguous view,
-        and ``decode_attend`` reads it back — 3 view-sized copies per
-        layer regardless of how few blocks are actually live.  A traffic
-        model, not a measurement; benchmarks report it per token."""
-        per_layer = self.block_size * self._kv_entry_bytes
-        live = sum(len(seq.table) for seq in decode)
-        layers = self.model.cfg.n_layers
-        fused = live * per_layer * layers
-        gathered = 3 * self.max_batch * self.max_blocks_per_seq \
-            * per_layer * layers
-        return fused, gathered
+    def _kv_bytes(self, live: int, grid: int) -> tuple:
+        """Analytic KV traffic of both paged-attention paths for one call
+        (bytes), from its :meth:`_kv_pages` counts.
 
-    def _prefill_kv_bytes(self, seq) -> tuple:
-        """Analytic per-chunk KV traffic of both prefill paths (bytes).
-
-        fused: the chunked-prefill flash kernel streams the sequence's
-        own table-mapped blocks once per layer (int8 scale rows ride the
-        same DMA and are part of ``_kv_entry_bytes``).
-        gathered: the 1-row ``paged_view`` reads the row's full
-        ``max_blocks_per_seq`` capacity, writes the contiguous view and
-        ``blockwise_attention`` reads it back — 3 view-sized copies per
-        layer.  Same traffic model as ``_decode_kv_bytes``."""
+        fused: every live pool block (the pages each row can see) is read
+        exactly once per layer; the kernels' page loops stop at each
+        row's live bound.  gathered: ``paged_view`` reads every table
+        entry of every row (unallocated entries still fetch the trash
+        block), writes the contiguous view, and ``decode_attend`` /
+        ``blockwise_attention`` read it back — 3 view-sized copies per
+        layer however few blocks are live.  A traffic model, not a
+        measurement; benchmarks report it per token."""
         per_layer = self.block_size * self._kv_entry_bytes
         layers = self.model.cfg.n_layers
-        fused = len(seq.table) * per_layer * layers
-        gathered = 3 * self.max_blocks_per_seq * per_layer * layers
-        return fused, gathered
+        return (live * per_layer * layers, 3 * grid * per_layer * layers)
 
     def _request_key(self, req: Request, index: int):
         """Memoized :func:`request_key` (the base key is two fold-ins
@@ -659,10 +652,12 @@ class PagedServeEngine:
                 posv[seq.row] = seq.kv_len
             cache = set_block_tables(self.cache, tables)
             uids = [s.uid for s in plan.decode]
+            live, grid = self._kv_pages(tables, posv)
             t_disp = self.clock()
             with self.trace.span("decode_dispatch", track="engine/decode",
                                  rows=len(plan.decode),
-                                 path=self.decode_path, uids=uids):
+                                 path=self.decode_path, uids=uids,
+                                 kv_pages_live=live, kv_pages_grid=grid):
                 with self._attn_scope():
                     logits, self.cache = self._decode(
                         self.params, jnp.asarray(tokens), cache,
@@ -674,9 +669,10 @@ class PagedServeEngine:
                                  rows=len(plan.decode), uids=uids):
                 logits = np.asarray(logits)
             self.metrics.on_device_interval(t_disp, self.clock())
-            fused_b, gathered_b = self._decode_kv_bytes(plan.decode)
+            fused_b, gathered_b = self._kv_bytes(live, grid)
             self.metrics.on_decode_step(len(plan.decode), fused_b,
-                                        gathered_b, self.decode_path)
+                                        gathered_b, self.decode_path,
+                                        kv_pages=(live, grid))
             with self.trace.span("sample", track="engine/sample",
                                  rows=len(plan.decode), uids=uids):
                 for seq in plan.decode:
@@ -705,11 +701,13 @@ class PagedServeEngine:
         bucket = self.sched.bucket(clen)
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :clen] = seq.tokens[start:start + clen]
-        cache = set_block_tables(self.cache,
-                                 self.tables[seq.row:seq.row + 1])
+        tables = self.tables[seq.row:seq.row + 1]
+        cache = set_block_tables(self.cache, tables)
+        live, grid = self._kv_pages(tables, np.array([start + clen - 1]))
         with self.trace.span("prefill_chunk", track="engine/prefill",
                              uid=seq.uid, start=start, length=clen,
-                             bucket=bucket):
+                             bucket=bucket, kv_pages_live=live,
+                             kv_pages_grid=grid):
             with self._attn_scope():
                 logits, self.cache = self._prefill_chunk(
                     self.params, {"tokens": jnp.asarray(toks)}, cache,
@@ -717,9 +715,10 @@ class PagedServeEngine:
         self.trace.instant("prefill_chunk", track=req_track(seq.uid),
                            cat="request", uid=seq.uid, start=start,
                            length=clen)
-        fused_b, gathered_b = self._prefill_kv_bytes(seq)
+        fused_b, gathered_b = self._kv_bytes(live, grid)
         self.metrics.on_prefill_chunk(clen, fused_b, gathered_b,
-                                      self.prefill_path)
+                                      self.prefill_path,
+                                      kv_pages=(live, grid))
         seq.kv_len += clen
         return logits, seq
 
@@ -785,19 +784,22 @@ class PagedServeEngine:
                     # per-row sharding before the pjit call
                     inp = jax.device_put(inp, self._row_sh)
             cache = set_block_tables(self.cache, tables)
+            live, grid = self._kv_pages(tables, posv)
             t_disp = self.clock()
             with self.trace.span("decode_dispatch", track="engine/decode",
                                  rows=len(plan.decode), mode="async",
                                  path=self.decode_path,
-                                 uids=[s.uid for s in plan.decode]):
+                                 uids=[s.uid for s in plan.decode],
+                                 kv_pages_live=live, kv_pages_grid=grid):
                 with self._attn_scope():
                     cur_tokens, self.cache = self._decode_sample(
                         self.params, inp, cache, jnp.asarray(posv),
                         jnp.asarray(keys), jnp.asarray(temps),
                         jnp.asarray(topks))
-            fused_b, gathered_b = self._decode_kv_bytes(plan.decode)
+            fused_b, gathered_b = self._kv_bytes(live, grid)
             self.metrics.on_decode_step(len(plan.decode), fused_b,
-                                        gathered_b, self.decode_path)
+                                        gathered_b, self.decode_path,
+                                        kv_pages=(live, grid))
             for seq in plan.decode:
                 seq.kv_len += 1
                 seq.inflight += 1

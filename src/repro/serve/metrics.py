@@ -135,6 +135,8 @@ class ServeMetrics:
         self.active = Gauge()                  # concurrent running seqs
         self.sharing = Gauge()                 # logical/physical blocks
         self.prefix_cached = Gauge()           # cache-held blocks
+        # share of a full-table grid's page-steps the paged kernels read
+        self.kv_live_share = {"decode": Gauge(), "prefill": Gauge()}
         self._t_submit: Dict[int, float] = {}
         self._t_last_tok: Dict[int, float] = {}
         self._t0 = clock()
@@ -243,16 +245,23 @@ class ServeMetrics:
         while the engine ran one tick."""
         self.counters["programs_built"] += n
 
+    def _on_kv_pages(self, kind: str, kv_pages) -> None:
+        if kv_pages is not None and kv_pages[1]:
+            self.kv_live_share[kind].observe(kv_pages[0] / kv_pages[1])
+
     def on_prefill_chunk(self, tokens: int = 0, fused_bytes: int = 0,
                          gathered_bytes: int = 0,
-                         path: Optional[str] = None) -> None:
+                         path: Optional[str] = None,
+                         kv_pages: Optional[tuple] = None) -> None:
         """One chunked-prefill dispatch: ``tokens`` is the chunk length,
         plus the analytic KV traffic of BOTH prefill attention paths for
-        this chunk — the fused flash kernel streams only the sequence's
-        own table-mapped blocks (scale rows included on int8 pools),
-        while the gathered path materializes k/v/pos views over the full
-        per-sequence capacity.  ``path`` is the one actually taken; the
-        legacy zero-argument form just counts the chunk."""
+        this chunk — the fused flash kernel streams only the blocks the
+        chunk can see (scale rows included on int8 pools), while the
+        gathered path materializes k/v/pos views over the full
+        per-sequence capacity.  ``path`` is the one actually taken;
+        ``kv_pages`` is the call's (live, grid) page-steps.  The legacy
+        zero-argument form just counts the chunk."""
+        self._on_kv_pages("prefill", kv_pages)
         self.counters["prefill_chunks"] += 1
         self.counters["prefill_tokens"] += int(tokens)
         self.counters["prefill_kv_bytes_fused_est"] += int(fused_bytes)
@@ -262,12 +271,15 @@ class ServeMetrics:
                 self.prefill_path_chunks.get(path, 0) + 1
 
     def on_decode_step(self, tokens: int, fused_bytes: int,
-                       gathered_bytes: int, path: str) -> None:
+                       gathered_bytes: int, path: str,
+                       kv_pages: Optional[tuple] = None) -> None:
         """One decode batch: ``tokens`` rows advanced, plus the analytic
         KV traffic of BOTH paged decode paths for this step (the engine
         computes them from live block counts; see
-        ``PagedServeEngine._decode_kv_bytes``).  ``path`` is the one
-        actually taken."""
+        ``PagedServeEngine._kv_bytes``).  ``path`` is the one
+        actually taken; ``kv_pages`` is the step's (live, grid)
+        page-steps."""
+        self._on_kv_pages("decode", kv_pages)
         self.counters["decode_steps"] += 1
         self.counters["decode_tokens"] += int(tokens)
         self.counters["kv_bytes_fused_est"] += int(fused_bytes)
@@ -343,6 +355,8 @@ class ServeMetrics:
                     self.counters["prefill_kv_bytes_fused_est"] / npre,
                 "kv_bytes_per_prefill_token_gathered":
                     self.counters["prefill_kv_bytes_gathered_est"] / npre,
+                "kv_pages_live_share": {
+                    kind: g.mean() for kind, g in self.kv_live_share.items()},
             },
             "prefix_cache": {
                 "hit_rate": self.counters["prefix_hit_blocks"] / nq,

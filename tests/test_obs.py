@@ -419,6 +419,83 @@ print(json.dumps([e["args"]["cache_hit"] for e in tracer.events
 """
 
 
+@pytest.mark.parametrize("mode", ["sync", "async"])
+def test_kv_page_span_args_match_host_count(monkeypatch, mode):
+    """``kv_pages_live`` / ``kv_pages_grid`` on every ``decode_dispatch``
+    and ``prefill_chunk`` span equal a count made here from the tables
+    and positions each device call received: per row, the pages its
+    newest query sees (0 for a row parked on the trash block), and rows
+    x ``max_blocks_per_seq``.  Two requests on four rows leave idle
+    rows in every decode step."""
+    import jax
+    import jax.numpy as jnp
+    from repro.configs import get_reduced
+    from repro.models import Model
+    from repro.serve import PagedServeEngine, Request
+    import repro.serve.engine as engine_mod
+
+    cfg = get_reduced("opt_6_7b").replace(remat=False, dtype="float32")
+    model = Model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    tracer = Tracer()
+    bs, max_seq = 8, 64
+    eng = PagedServeEngine(model, params, num_blocks=24, block_size=bs,
+                           max_batch=4, max_seq_len=max_seq,
+                           prefill_buckets=(16,), tracer=tracer)
+    calls = {"decode": [], "prefill": []}
+    tables_seen = []
+    real_set = engine_mod.set_block_tables
+
+    def set_tables(cache, tables):
+        tables_seen.append(np.array(tables))
+        return real_set(cache, tables)
+    monkeypatch.setattr(engine_mod, "set_block_tables", set_tables)
+
+    def record(kind, fn, q_pos):
+        def wrapped(*args):
+            calls[kind].append((tables_seen[-1], q_pos(args)))
+            return fn(*args)
+        return wrapped
+    step = "_decode_sample" if mode == "async" else "_decode"
+    setattr(eng, step, record("decode", getattr(eng, step),
+                              lambda a: np.asarray(a[3])))
+    eng._prefill_chunk = record(
+        "prefill", eng._prefill_chunk,
+        lambda a: np.array([int(a[3]) + int(a[4])]))
+
+    rng = np.random.default_rng(5)
+    reqs = [Request(uid=i, prompt=rng.integers(0, cfg.vocab_size, (n,)),
+                    max_new_tokens=4) for i, n in enumerate((9, 21))]
+    done = (eng.run_async if mode == "async" else eng.run)(reqs,
+                                                          max_ticks=100)
+    set_active(None)
+    assert len(done) == 2 and all(r.error is None for r in done)
+
+    pages = max_seq // bs
+
+    def host_count(tables, q_pos):
+        live = 0
+        for row, qp in zip(tables, q_pos):
+            if row[0] >= 0 and qp >= 0:
+                live += min(int(qp) // bs + 1, pages)
+        return live, len(tables) * pages
+
+    for kind, span in (("decode", "decode_dispatch"),
+                       ("prefill", "prefill_chunk")):
+        got = [(e["args"]["kv_pages_live"], e["args"]["kv_pages_grid"])
+               for e in tracer.events if e["name"] == span and e["ph"] == "X"]
+        want = [host_count(*c) for c in calls[kind]]
+        assert got and got == want, (kind, got, want)
+        shares = [live / grid for live, grid in want]
+        summary = eng.metrics.summary()["paged_kernel"]
+        assert summary["kv_pages_live_share"][kind] == pytest.approx(
+            sum(shares) / len(shares))
+    # idle rows were parked on the trash block and counted 0 pages
+    assert any((t[:, 0] < 0).any() for t, _ in calls["decode"])
+    assert all(live < grid for live, grid in
+               (host_count(*c) for c in calls["decode"]))
+
+
 class TestProgramBuilds:
     def test_persistent_cache_load_is_marked(self, tmp_path):
         env = dict(os.environ, JAX_PLATFORMS="cpu")

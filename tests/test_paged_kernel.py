@@ -7,7 +7,8 @@ with per-slot dequant scales; the MLA latent decode kernel; and the
 chunked-prefill kernel against ``blockwise_attention`` — all on
 scrambled and *recycled* block tables (stale positions from a dead
 owner), ``pos == -1`` pads, -1 table entries and fully-idle rows,
-across the block_h launch-geometry space.
+across the block_h launch-geometry space; and at page and page-group
+edges, where the kernels' live bound must never read a block past it.
 
 Model level: ``decode_step``/``prefill_chunk`` with
 ``paged_kernel="fused"`` must be token/logit-equivalent to ``"gather"``
@@ -54,10 +55,54 @@ def _model(arch="opt_6_7b", **over):
     return m, _f32(m.init(RNG))
 
 
+#: the ragged-liveness case of the parametrised oracle tests: block 16,
+#: 12 pages (two page groups of the decode kernel, the second partial)
+EDGES = "edges"
+EDGE_BS, EDGE_PAGES = 16, 12
+#: each row's newest position: page edges (0, 15, 16), a mid page, the
+#: last slot of the first page group and the first of the second, the
+#: last slot of the table; None is an idle row between live ones
+EDGE_QPOS = (0, 15, None, 16, 77, 127, 128, EDGE_BS * EDGE_PAGES - 1)
+
+
+def _edge_case(*, h=8, hkv=4, d=16, dtype=jnp.float32, **_):
+    """Ragged liveness at page and page-group edges.  Past each row's
+    newest position: the rest of its last page holds stale positions
+    (each equal to its logical index, as a longer dead sequence left
+    them), and the row's next table entry is a recycled block whose
+    stale positions are likewise all logical — only causality (and the
+    kernels' live bound) keeps them out."""
+    bs, pages, b = EDGE_BS, EDGE_PAGES, len(EDGE_QPOS)
+    nb = 1 + sum(-(-(qp + 1) // bs) + 1 for qp in EDGE_QPOS
+                 if qp is not None)
+    rng = np.random.default_rng(11)
+    k = jnp.asarray(rng.normal(size=(nb, bs, hkv, d)), dtype)
+    v = jnp.asarray(rng.normal(size=(nb, bs, hkv, d)), dtype)
+    q = jnp.asarray(rng.normal(size=(b, h, d)), dtype)
+    tables = np.full((b, pages), -1, np.int32)
+    pos = np.full((nb, bs), -1, np.int32)
+    free = list(rng.permutation(np.arange(1, nb)))
+    positions = np.zeros(b, np.int32)
+    for row, qp in enumerate(EDGE_QPOS):
+        if qp is None:
+            continue
+        positions[row] = qp
+        n = qp // bs + 1
+        for j in range(min(n + 1, pages)):   # live pages + one stale
+            blk = free.pop()
+            tables[row, j] = blk
+            pos[blk] = j * bs + np.arange(bs)
+    return (q, k, v, jnp.asarray(pos), jnp.asarray(tables),
+            jnp.asarray(positions))
+
+
 def _pool_case(seed, *, b=3, h=8, hkv=4, d=16, nb=24, bs=4, pages=6,
                dtype=jnp.float32, recycle=True, idle_row=True):
     """Scrambled paged-decode problem: ragged live lengths, -1 table
-    pads, stale positions in recycled blocks, optionally an idle row."""
+    pads, stale positions in recycled blocks, optionally an idle row.
+    ``seed == EDGES`` gives :func:`_edge_case` instead."""
+    if seed == EDGES:
+        return _edge_case(h=h, hkv=hkv, d=d, dtype=dtype)
     assert nb > b * pages, "pool too small for worst-case live blocks"
     rng = np.random.default_rng(seed)
     k = jnp.asarray(rng.normal(size=(nb, bs, hkv, d)), dtype)
@@ -92,7 +137,7 @@ def _int8_pool_case(seed, **kw):
     """_pool_case with the K/V pools re-drawn as int8 + per-slot scales
     (same scrambled/recycled table layout)."""
     q, k, v, pos, tables, positions = _pool_case(seed, **kw)
-    rng = np.random.default_rng(seed + 100)
+    rng = np.random.default_rng(101 if seed == EDGES else seed + 100)
     nb, bs, hkv, d = k.shape
     k8 = jnp.asarray(np.clip(np.round(rng.normal(size=k.shape) * 40),
                              -127, 127), jnp.int8)
@@ -122,7 +167,7 @@ def _mla_pool_case(seed, *, b=3, h=8, lora=12, dr=8, nb=24, bs=4, pages=6):
 
 class TestFusedKernel:
     @pytest.mark.parametrize("h,hkv", [(8, 4), (4, 4), (6, 1)])  # GQA/MHA/MQA
-    @pytest.mark.parametrize("seed", [0, 1])
+    @pytest.mark.parametrize("seed", [0, 1, EDGES])
     def test_matches_gathered_oracle(self, h, hkv, seed):
         q, k, v, pos, tables, positions = _pool_case(seed, h=h, hkv=hkv)
         want = paged_decode_ref(q, k, v, pos, tables, positions)
@@ -186,7 +231,7 @@ class TestFusedKernel:
 
 class TestFusedInt8Kernel:
     @pytest.mark.parametrize("h,hkv", [(8, 4), (4, 4), (6, 1)])
-    @pytest.mark.parametrize("seed", [0, 1])
+    @pytest.mark.parametrize("seed", [0, 1, EDGES])
     def test_matches_gathered_int8_oracle(self, h, hkv, seed):
         """Per-slot scales fold in-kernel to the decode_attend ordering
         (bf16 compute -> atol at bf16-epsilon scale)."""
@@ -273,10 +318,12 @@ class TestFusedMlaKernel:
 
 
 class TestFusedPrefillKernel:
-    def _chunk(self, seed, positions, *, b, c, h, d):
+    @staticmethod
+    def _chunk(seed, positions, *, b, c, h, d):
         """A chunk of queries per row: positions[row]-c+1 .. positions[row]
         (clamped at -1 pads below position 0)."""
-        rng = np.random.default_rng(seed + 300)
+        b = len(positions)
+        rng = np.random.default_rng(303 if seed == EDGES else seed + 300)
         q = jnp.asarray(rng.normal(size=(b, c, h, d)), jnp.float32)
         cpos = (np.asarray(positions)[:, None]
                 - np.arange(c - 1, -1, -1)[None]).astype(np.int32)
@@ -284,7 +331,7 @@ class TestFusedPrefillKernel:
         return q, jnp.asarray(cpos)
 
     @pytest.mark.parametrize("h,hkv", [(8, 4), (4, 4), (6, 1)])
-    @pytest.mark.parametrize("seed", [0, 1])
+    @pytest.mark.parametrize("seed", [0, 1, EDGES])
     def test_matches_gathered_oracle(self, h, hkv, seed):
         _, k, v, pos, tables, positions = _pool_case(seed, h=h, hkv=hkv)
         q, cpos = self._chunk(seed, positions, b=3, c=5, h=h, d=16)
@@ -293,7 +340,7 @@ class TestFusedPrefillKernel:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=1e-5)
 
-    @pytest.mark.parametrize("seed", [0, 1])
+    @pytest.mark.parametrize("seed", [0, 1, EDGES])
     def test_int8_matches_gathered_oracle(self, seed):
         _, k8, v8, ks, vs, pos, tables, positions = _int8_pool_case(seed)
         q, cpos = self._chunk(seed, positions, b=3, c=5, h=8, d=16)
@@ -363,6 +410,58 @@ class TestFusedPrefillKernel:
         changed = np.asarray(cpos)[row] == qp
         assert np.abs(np.asarray(got2)[row][changed]
                       - np.asarray(got)[row][changed]).max() > 1e-4
+
+
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+@pytest.mark.parametrize("int8", [False, True], ids=["float", "int8"])
+def test_blocks_past_the_live_bound_are_never_read(kernel, int8):
+    """Every pool block wholly past a row's live bound — the stale block
+    after each row's last page, the unreferenced ones and the trash
+    block 0 — is filled with NaN (its K/V, or its int8 scales).  A
+    kernel that read one would poison the row through ``0 * NaN`` in
+    the PV product; both kernels stop at the live bound, so the output
+    is finite and equals the oracle on the pool before poisoning."""
+    if int8:
+        q, k, v, ks, vs, pos, tables, positions = _int8_pool_case(EDGES)
+    else:
+        q, k, v, pos, tables, positions = _pool_case(EDGES)
+        ks = vs = None
+    t, qp = np.asarray(tables), np.asarray(positions)
+    live = {int(t[r, j]) for r in range(len(t)) if t[r, 0] >= 0
+            for j in range(qp[r] // EDGE_BS + 1)}
+    dead = [blk for blk in range(k.shape[0]) if blk not in live]
+    assert 0 in dead and set(t[t >= 0].tolist()) - live  # stale blocks
+
+    def poison(x):
+        x = np.asarray(x).copy()
+        x[dead] = np.nan
+        return jnp.asarray(x)
+
+    if kernel == "decode":
+        if int8:
+            want = paged_decode_int8_ref(q, k, v, ks, vs, pos, tables,
+                                         positions)
+            got = paged_attention_int8(q, k, v, poison(ks), poison(vs), pos,
+                                       tables, positions, interpret=True)
+        else:
+            want = paged_decode_ref(q, k, v, pos, tables, positions)
+            got = paged_attention(q, poison(k), poison(v), pos, tables,
+                                  positions, interpret=True)
+    else:
+        qc, cpos = TestFusedPrefillKernel._chunk(EDGES, positions, b=None,
+                                                 c=5, h=8, d=16)
+        want = paged_prefill_ref(qc, k, v, pos, tables, cpos, k_scale=ks,
+                                 v_scale=vs)
+        if int8:
+            got = paged_prefill(qc, k, v, pos, tables, cpos,
+                                k_scale=poison(ks), v_scale=poison(vs),
+                                interpret=True)
+        else:
+            got = paged_prefill(qc, poison(k), poison(v), pos, tables, cpos,
+                                interpret=True)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=3e-2 if int8 else 1e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -187,3 +187,57 @@ def test_paged_prefill_compiles_long_chunk(compile_for):
         sds((1, 512, HEADS, HEAD_DIM), jnp.bfloat16), k, v, pos,
         sds((1, PAGES), jnp.int32), sds((1, 512), jnp.int32))
     assert "tpu_custom_call" in hlo
+
+
+# the chat cells' attention at their real shapes: max_batch 32, a
+# 2,048-token table of 16-token blocks, each config's KV pool
+CELL_ROWS, CELL_PAGES = 32, 128
+CELLS = {"opt-6.7b": (32, 32, 449), "phi4-mini": (24, 8, 1793)}
+
+
+def _cell_pools(cell, int8):
+    sds = jax.ShapeDtypeStruct
+    heads, kv_heads, blocks = CELLS[cell]
+    dt = jnp.int8 if int8 else jnp.bfloat16
+    kv = (sds((blocks, BLOCK_SIZE, kv_heads, HEAD_DIM), dt),) * 2
+    scales = (sds((blocks, BLOCK_SIZE, kv_heads), jnp.float32),) * 2
+    return heads, kv, (scales if int8 else ()), \
+        sds((blocks, BLOCK_SIZE), jnp.int32)
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["float", "int8"])
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_paged_decode_compiles_at_cell_shape(compile_for, cell, int8):
+    """The page-group decode kernel (manual double-buffered page copies,
+    a loop bounded by each row's live pages) at both cells' shapes: MHA
+    32 x 128 and GQA 8 kv heads with rep 3."""
+    from repro.kernels.paged_attention import (paged_attention,
+                                               paged_attention_int8)
+    sds = jax.ShapeDtypeStruct
+    heads, (k, v), scales, pos = _cell_pools(cell, int8)
+    q = sds((CELL_ROWS, heads, HEAD_DIM), jnp.bfloat16)
+    tables = sds((CELL_ROWS, CELL_PAGES), jnp.int32)
+    qpos = sds((CELL_ROWS,), jnp.int32)
+    if int8:
+        fn = lambda q, k, v, ks, vs, p, t, qp: paged_attention_int8(
+            q, k, v, ks, vs, p, t, qp, interpret=False)
+    else:
+        fn = lambda q, k, v, p, t, qp: paged_attention(
+            q, k, v, p, t, qp, interpret=False)
+    hlo = compile_for(fn, q, k, v, *scales, pos, tables, qpos)
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_paged_prefill_compiles_at_cell_shape(compile_for, cell):
+    """A 512-bucket chunk over the cells' 128-page tables, its page steps
+    bounded by the chunk's live pages."""
+    from repro.kernels.paged_attention import paged_prefill
+    sds = jax.ShapeDtypeStruct
+    heads, (k, v), _, pos = _cell_pools(cell, False)
+    hlo = compile_for(
+        lambda q, k, v, p, t, qp: paged_prefill(q, k, v, p, t, qp,
+                                                interpret=False),
+        sds((1, 512, heads, HEAD_DIM), jnp.bfloat16), k, v, pos,
+        sds((1, CELL_PAGES), jnp.int32), sds((1, 512), jnp.int32))
+    assert "tpu_custom_call" in hlo
