@@ -28,6 +28,13 @@ HF_KEYS = {
     "ffn_dim": "d_ff", "intermediate_size": "d_ff",
     "vocab_size": "vocab_size", "head_dim": "head_dim",
     "rope_theta": "rope_theta",
+    "kv_lora_rank": "kv_lora_rank", "q_lora_rank": "q_lora_rank",
+    "qk_nope_head_dim": "qk_nope_head_dim",
+    "qk_rope_head_dim": "qk_rope_head_dim", "v_head_dim": "v_head_dim",
+    "n_routed_experts": "n_experts", "n_shared_experts": "n_shared_experts",
+    "num_experts_per_tok": "experts_per_token",
+    "moe_intermediate_size": "moe_d_ff",
+    "first_k_dense_replace": "first_dense_layers",
 }
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -44,24 +51,21 @@ def log(msg: str) -> None:
 
 def model_config(c: dict):
     """The program's ModelConfig for a configuration file: the program's
-    own config of ``program.arch`` with every number of the file set."""
+    own config of ``program.arch`` with every number of the file set (a
+    published ``null``, as DeepSeek-V2-Lite's ``q_lora_rank``, is the
+    program's 0: not there)."""
     from repro.configs import get_config
     from repro.quant import QuantSpec
     prog = dict(c["program"])
     arch = prog.pop("arch")
-    fields = {f: c[k] for k, f in HF_KEYS.items() if k in c}
+    fields = {f: 0 if c[k] is None else c[k]
+              for k, f in HF_KEYS.items() if k in c}
     fields.setdefault("n_kv_heads", fields["n_heads"])
     fields.update(prog)
     q = c["quant"]
     qspec = QuantSpec(format=q["format"], bits=q["bits"],
                       group_size=q["group_size"], backend=q["backend"])
     return get_config(arch).replace(quant=qspec, **fields)
-
-
-def kv_entry_bytes(c: dict) -> int:
-    """KV bytes per (token, layer): K and V of every KV head, bf16."""
-    nkv = c.get("num_key_value_heads", c["num_attention_heads"])
-    return 2 * nkv * c["head_dim"] * 2
 
 
 def pool_blocks(c: dict) -> int:
@@ -91,8 +95,7 @@ def build(c: dict, seed: int) -> Setup:
 
     cfg = model_config(c)
     model = Model(cfg)
-    abstract = weights.abstract_quantized(model, cfg.quant)
-    params = weights.draw(abstract, seed)
+    params = weights.draw(model, cfg.quant, seed)
     jax.block_until_ready(params)
     wbytes = weights.total_bytes(params)
     e = c["engine"]
@@ -103,15 +106,8 @@ def build(c: dict, seed: int) -> Setup:
         prefill_buckets=tuple(e["prefill_buckets"]),
         paged_kernel=e["paged_kernel"], prefix_cache=e["prefix_cache"])
     fe = AsyncServeFrontend(eng, max_queue=e["max_queue"])
-    tok = params["embed"]["tok"]
-    shape = counts.Shape(
-        layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        n_heads=c["num_attention_heads"],
-        n_kv_heads=c.get("num_key_value_heads", c["num_attention_heads"]),
-        head_dim=c["head_dim"], vocab_rows=int(tok.shape[0]),
-        head_bytes=int(tok.size) * tok.dtype.itemsize,
-        kv_entry_bytes=kv_entry_bytes(c), block_size=e["block_size"])
-    return Setup(model, params, eng, fe, weights.linears(params), shape,
+    return Setup(model, params, eng, fe, weights.linears(params),
+                 counts.shape_of(c, weights.head(params)),
                  wbytes, nb)
 
 

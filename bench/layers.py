@@ -23,18 +23,22 @@ import collections
 import dataclasses
 import re
 import time
-from typing import List
+from typing import List, Optional
 
 from bench import counts, devtrace, peaks as peaks_mod
 
-# Device operations of each kernel family.  Read off a chip trace by hand
-# (PERF.md, section 3):
-# the Pallas calls appear in "XLA Ops" as ``%bcq_matmul_tiled.<n> = ...``
-# (``lut_gemm_tiled``, ``paged_attention_tiled``, ``paged_prefill_tiled``
-# alike), matched on the op's own name, not on its operands.
+# Device operations of each kernel family, by one name rule.  Read off a
+# chip trace by hand (PERF.md, section 3): each Pallas call appears in
+# "XLA Ops" under its kernel's name, ``%bcq_matmul_tiled.<n> = ...``, and
+# every kernel is named ``<what>_tiled``.  A name that starts ``paged_``
+# is paged attention (``paged_attention``, ``paged_prefill``,
+# ``paged_attention_mla``); one that holds ``_matmul`` or ``_gemm`` is a
+# quantized linear (``bcq_matmul``, ``lut_gemm``, ``ternary_matmul``, a
+# grouped expert ``*_matmul*``).  Matched on the op's own name, not on its
+# operands.
 KERNELS = {
-    "gemm": re.compile(r"(lut_gemm|bcq_matmul|ternary_matmul)_tiled\b"),
-    "attn": re.compile(r"(paged_attention|paged_prefill)_tiled\b"),
+    "gemm": re.compile(r"\w*_(matmul|gemm)\w*_tiled\b"),
+    "attn": re.compile(r"paged_\w+_tiled\b"),
 }
 # the programs the window drives, by jitted function name
 PROGRAMS = {"decode": "jit_decode_and_sample", "prefill": "jit_prefill_chunk"}
@@ -92,13 +96,19 @@ class Context:
         """Device steps dispatched in host ``[lo, hi]``, rebuilt from the
         engine's events: a row's context at a decode step is its prefill
         target plus the decode steps since its admission, plus the token
-        being decoded."""
+        being decoded.  A step span's ``step`` id joins the args of any
+        later ``step_counters`` instant that names it into the step's
+        ``counters``."""
         kv = {}
         out = []
+        by_id = {}
         for e in self._events:
             a = e.get("args", {})
             if e["name"] == "admit":
                 kv[a["uid"]] = a["prefill_target"]
+            elif e["name"] == "step_counters" and a.get("step") in by_id:
+                by_id[a["step"]].counters.update(
+                    (k, v) for k, v in a.items() if k not in ("step", "tick"))
             elif e["name"] in ("decode_dispatch", "prefill_chunk") \
                     and e["ph"] == "X":
                 t = self.obs_time(e)
@@ -111,6 +121,8 @@ class Context:
                 else:
                     step = counts.Step("prefill", [a["start"], a["length"]],
                                        a["bucket"])
+                if "step" in a:
+                    by_id[a["step"]] = step
                 if lo <= t <= hi:
                     out.append(step)
         return out
@@ -156,9 +168,25 @@ class Context:
                 "idle_gaps": named}
 
 
-def gemm_bound_s(m: int, ctx: Context) -> float:
+def gemm_bound_s(m: int, ctx: Context, head_rows: int = 0,
+                 experts_touched: Optional[int] = None) -> Optional[float]:
     """Least time of every quantized linear of one step on ``m`` rows,
-    each call bounded on its own."""
-    return sum(lin.layers * peaks_mod.bound_s(
+    each call bounded on its own: a routed expert role's call per layer
+    on ``m`` rows' top experts over the step's touched experts (``None``
+    without ``experts_touched``), and a head stored as a bundle on the
+    ``head_rows`` rows that produce logits."""
+    dense, routed = counts.split_routed(ctx.linears)
+    t = sum(lin.layers * peaks_mod.bound_s(
         counts.gemm(m, lin).flops, counts.gemm(m, lin).bytes, ctx.peaks)
-        for lin in ctx.linears)
+        for lin in dense)
+    if routed:
+        if experts_touched is None:
+            return None
+        per = counts.touched_per_layer(routed, experts_touched)
+        for lin in routed:
+            w = counts.routed_gemm(m, lin, ctx.shape.experts_per_token, per)
+            t += lin.layers * peaks_mod.bound_s(w.flops, w.bytes, ctx.peaks)
+    if ctx.shape.head_gemm and head_rows:
+        w = counts.lm_head(head_rows, ctx.shape)
+        t += peaks_mod.bound_s(w.flops, w.bytes, ctx.peaks)
+    return t

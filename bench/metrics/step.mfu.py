@@ -1,7 +1,9 @@
 """Model FLOP/s utilization of the whole window: the useful operations of
 every step dispatched in it (every linear on each live decode row and
 each real prefill token, attention over each token's context, the LM
-head where logits are produced) over the window times peak FLOP/s."""
+head where logits are produced) over the window times peak FLOP/s.
+Nothing is read for a model with routed experts whose steps carry no
+``experts_touched``."""
 from bench import counts
 
 UNIT = "%"
@@ -15,7 +17,8 @@ def read(ctx):
     steps = ctx.steps(*ctx.window)
     if not steps:
         return None
-    flops = sum(counts.step_model_work(s, ctx.linears, ctx.shape).flops
-                for s in steps)
+    work = counts.total_work(steps, ctx.linears, ctx.shape)
+    if work is None:
+        return None
     secs = ctx.window[1] - ctx.window[0]
-    return 100.0 * flops / (secs * ctx.peaks.bf16_flops)
+    return 100.0 * work.flops / (secs * ctx.peaks.bf16_flops)

@@ -41,15 +41,8 @@ class Planes:
     in_features: int = dataclasses.field(metadata=dict(static=True))
 
 
-def dequantize(w: Planes, layer=None) -> jax.Array:
-    """Dense float32 ``[out, in]`` of one layer of a quantized weight
-    (``layer`` indexes a leading stacked-layers axis)."""
-    packed, alpha, z = w.packed, w.alpha, w.z
-    if layer is not None:
-        packed, alpha = packed[layer], alpha[layer]
-        z = None if z is None else z[layer]
+def _dense(packed, alpha, z, g: int, in_features: int) -> jax.Array:
     q, out, nb = packed.shape
-    g = w.group_size
     shifts = jnp.arange(8, dtype=jnp.uint8)
     bits = (packed[..., None] >> shifts) & jnp.uint8(1)
     signs = bits.astype(jnp.float32).reshape(q, out, nb * 8) * 2.0 - 1.0
@@ -58,7 +51,23 @@ def dequantize(w: Planes, layer=None) -> jax.Array:
     if z is not None:
         dense = dense + jnp.repeat(z.astype(jnp.float32), g, axis=-1)[
             :, : nb * 8]
-    return dense[:, : w.in_features]
+    return dense[:, :in_features]
+
+
+def dequantize(w: Planes, layer=None) -> jax.Array:
+    """Dense float32 ``[out, in]`` of one layer of a quantized weight
+    (``layer`` indexes a leading stacked-layers axis); an expert bank,
+    with a leading experts axis, comes out ``[E, out, in]``."""
+    packed, alpha, z = w.packed, w.alpha, w.z
+    if layer is not None:
+        packed, alpha = packed[layer], alpha[layer]
+        z = None if z is None else z[layer]
+
+    def one(p, a, z):
+        return _dense(p, a, z, w.group_size, w.in_features)
+    if packed.ndim == 4:
+        return jax.vmap(one)(packed, alpha, z)
+    return one(packed, alpha, z)
 
 
 def fp8_rows(x: jax.Array) -> jax.Array:
@@ -113,15 +122,20 @@ def rope(x, theta: float):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-def stack_layers(stack) -> tuple:
-    """``(groups, n_layers, stacked)`` of a plain stack tree: either
-    ``{"scan": [group]}`` whose leaves carry a leading layers axis, or
-    ``{"layers": [layer, ...]}``."""
-    if "scan" in stack:
-        (group,) = stack["scan"]
-        n = jax.tree_util.tree_leaves(group)[0].shape[0]
-        return group, n, True
-    return stack["layers"], len(stack["layers"]), False
+def stack_layers(stack) -> list:
+    """Every layer of a plain stack tree in order, as ``(tree, index)``:
+    ``{"layers": [layer, ...]}`` gives each layer with index ``None``;
+    ``{"prefix": [layer, ...], "scan": [group, ...]}`` gives the prefix
+    layers, then layer ``i`` of every group in turn, ``i`` indexing the
+    groups' leading layers axis."""
+    if "layers" in stack:
+        return [(l, None) for l in stack["layers"]]
+    out = [(l, None) for l in stack.get("prefix", [])]
+    groups = stack.get("scan", [])
+    if groups:
+        n = jax.tree_util.tree_leaves(groups[0])[0].shape[0]
+        out += [(g, i) for i in range(n) for g in groups]
+    return out
 
 
 def head_stats(x_final: jax.Array, head_w: jax.Array, vocab: int,
@@ -156,19 +170,14 @@ def logit_stats(arch, w: dict, cfg: dict, tokens, targets, mode: str = "f32"):
         raise ValueError(f"mode {mode!r}; known: {MODES}")
     tokens = jnp.asarray(tokens, jnp.int32)
     targets = jnp.asarray(targets, jnp.int32)
-    group, n, stacked = stack_layers(w["stack"])
     with jax.default_matmul_precision("highest"):
         x = jax.jit(lambda we, t: arch.embed(we, t, cfg))(w["embed"], tokens)
-        if stacked:
-            step = jax.jit(lambda g, i, x: arch.block(
-                _dense_layer(g, i), x, cfg, mode))
-            for i in range(n):
-                x = step(group, i, x)
-        else:
-            step = jax.jit(lambda g, x: arch.block(
-                _dense_layer(g, None), x, cfg, mode))
-            for g in group:
-                x = step(g, x)
+        one = jax.jit(lambda g, x: arch.block(
+            _dense_layer(g, None), x, cfg, mode))
+        stacked = jax.jit(lambda g, i, x: arch.block(
+            _dense_layer(g, i), x, cfg, mode))
+        for g, i in stack_layers(w["stack"]):
+            x = one(g, x) if i is None else stacked(g, i, x)
 
         def stats(wf, x, tg):
             xn, head = arch.final(wf, x, cfg)

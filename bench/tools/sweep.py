@@ -71,6 +71,9 @@ def main():
             "no_first_token_at_close": late,
             "failed": sum(1 for r in w.recs if not r.ok),
             "compiles": w.compiles,
+            "ttft_ms_by_prompt_len": sorted(
+                [len(r.item.prompt), round((r.times[0] - r.due) * 1e3, 1)]
+                for r in w.recs if r.ok and r.times),
             "drain_s": w.drain_end - w.t1})
         print(json.dumps(rows[-1]), flush=True)
     knee = None                  # the highest rate below which all held

@@ -11,8 +11,10 @@ drawn.  One jitted call then fills every leaf on the device:
     (``0.016 * 2^-i``, times a uniform factor in [0.75, 1.25]), the
     profile a BCQ solve of N(0, 0.02^2) weights gives;
   * ``z`` offsets: small, N(0, 0.002^2);
-  * embeddings N(0, 0.02^2) in their stored dtype; norm scales near 1
-    and biases near 0, both random so that the check sees them.
+  * norm gains (every leaf the model declares with init ``"ones"``,
+    whatever its name) near 1: N(1, 0.1^2);
+  * every other leaf -- embeddings, biases, a router -- N(0, 0.02^2) in
+    its stored dtype, random so that the check sees it.
 
 The solver's output values change no work on the timed path; the
 bundles' shapes, dtypes and bytes do, and those are the program's.
@@ -26,7 +28,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from bench.counts import Linear
+from bench.counts import Head, Linear
 
 
 def seed_key(seed: int, salt: int = 0) -> jax.Array:
@@ -48,7 +50,15 @@ def _name(path) -> Tuple[str, ...]:
         k, "idx", k)))) for k in path)
 
 
-def _draw_leaf(key, names, sds):
+def norm_gains(model) -> frozenset:
+    """Names of the leaves the model declares with init ``"ones"``."""
+    from repro.models.module import is_desc
+    flat = jax.tree_util.tree_leaves_with_path(model.desc(), is_leaf=is_desc)
+    return frozenset(_name(p) for p, d in flat
+                     if is_desc(d) and d.init == "ones")
+
+
+def _draw_leaf(key, names, sds, gain: bool):
     leaf = names[-1]
     shape, dtype = sds.shape, sds.dtype
     if leaf == "packed":
@@ -62,24 +72,25 @@ def _draw_leaf(key, names, sds):
     if leaf == "z":
         return (0.002 * jax.random.normal(key, shape, jnp.float32)
                 ).astype(dtype)
-    if leaf == "scale":                       # norm gains
+    if gain:
         return (1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)
-                ).astype(dtype)
-    if leaf == "bias" or leaf.endswith("_b"):
-        return (0.02 * jax.random.normal(key, shape, jnp.float32)
                 ).astype(dtype)
     return (0.02 * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
 
 
-def draw(abstract, seed: int):
-    """Every leaf of ``abstract`` drawn on the device in one jitted call."""
-    flat, treedef = jax.tree_util.tree_flatten_with_path(abstract)
+def draw(model, spec, seed: int):
+    """Every leaf of the model's served tree drawn on the device in one
+    jitted call."""
+    flat, treedef = jax.tree_util.tree_flatten_with_path(
+        abstract_quantized(model, spec))
+    gains = norm_gains(model)
     names = [_name(p) for p, _ in flat]
     sdss = [s for _, s in flat]
 
     def fill(key):
         keys = jax.random.split(key, len(sdss))
-        return [_draw_leaf(k, n, s) for k, n, s in zip(keys, names, sdss)]
+        return [_draw_leaf(k, n, s, n in gains)
+                for k, n, s in zip(keys, names, sdss)]
 
     leaves = jax.jit(fill)(seed_key(seed))
     return jax.tree_util.tree_unflatten(treedef, leaves)
@@ -102,18 +113,23 @@ def bundle_bytes(b) -> int:
 
 
 def linears(params) -> List[Linear]:
-    """Every quantized linear role, read from the live tree."""
+    """Every quantized linear role of the stack, read from the live tree:
+    a leaf under ``scan`` carries a leading layers axis, and any axis
+    before a bundle's planes besides it is its experts.  A bundle under
+    ``embed`` is the LM head (:func:`head`), not one of them."""
     out = []
     for path, leaf in jax.tree_util.tree_leaves_with_path(
             params, is_leaf=is_bundle):
-        if not is_bundle(leaf):
+        names = _name(path)
+        if not is_bundle(leaf) or names[0] == "embed":
             continue
-        layers = int(np.prod(leaf.packed.shape[:-3])) \
-            if leaf.packed.ndim > 3 else 1
-        out.append(Linear(name="/".join(_name(path)),
+        lead = leaf.packed.shape[:-3]
+        layers = int(lead[0]) if "scan" in names and lead else 1
+        experts = int(np.prod(lead)) // layers
+        out.append(Linear(name="/".join(names),
                           n=int(leaf.out_features), k=int(leaf.in_features),
-                          stored_bytes=bundle_bytes(leaf) // layers,
-                          layers=layers))
+                          stored_bytes=bundle_bytes(leaf) // (layers * experts),
+                          layers=layers, experts=experts))
     return out
 
 
@@ -134,3 +150,13 @@ def plain(params):
 
 def total_bytes(params) -> int:
     return sum(_nbytes(a) for a in jax.tree_util.tree_leaves(params))
+
+
+def head(params) -> Head:
+    """The LM head of the live tree: the untied ``embed/unembed`` where
+    there is one, else the tied token embedding."""
+    emb = params["embed"]
+    h = emb.get("unembed", emb["tok"])
+    if is_bundle(h):
+        return Head(int(h.out_features), bundle_bytes(h), True)
+    return Head(int(h.shape[0]), _nbytes(h), False)

@@ -37,7 +37,7 @@ def test_control_fails_where_program_passes(name):
     cfg = harness.model_config(c)
     model = Model(cfg)
     ref = spec.reference(c["reference"])
-    params = weights.draw(weights.abstract_quantized(model, cfg.quant), 5)
+    params = weights.draw(model, cfg.quant, 5)
     toks = np.random.default_rng(5).integers(0, 8192, (2, 64)).astype(np.int32)
     logits = model.forward(params, {"tokens": jnp.asarray(toks)})
     served = np.asarray(logits).argmax(-1).astype(np.int32)

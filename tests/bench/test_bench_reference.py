@@ -9,6 +9,7 @@ bf16 rounding, not exactly.
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from bench import harness, spec, weights
@@ -22,7 +23,7 @@ def _program_and_reference(name, seed=3, b=2, t=24):
     c = tiny_config(name)
     cfg = harness.model_config(c)
     model = Model(cfg)
-    params = weights.draw(weights.abstract_quantized(model, cfg.quant), seed)
+    params = weights.draw(model, cfg.quant, seed)
     rng = np.random.default_rng(seed)
     toks = rng.integers(0, c["vocab_size"], (b, t)).astype(np.int32)
     tgts = rng.integers(0, c["vocab_size"], (b, t)).astype(np.int32)
@@ -60,6 +61,78 @@ def test_dequantize_rebuilds_the_planes():
     a1 = np.repeat([0.5, 0.25], 8)
     want = sign0 * a0 + sign1 * a1 + np.repeat([0.1, -0.1], 8)
     np.testing.assert_allclose(np.asarray(w)[0], want, rtol=1e-6)
+
+
+def _planes(rng, lead, q, out, inp, g):
+    """Random planes with leading axes ``lead``, and their dense weights
+    rebuilt here by numpy alone."""
+    nb = inp // 8
+    packed = rng.integers(0, 256, lead + (q, out, nb)).astype(np.uint8)
+    alpha = rng.uniform(0.1, 0.5, lead + (q, out, inp // g)).astype(
+        np.float32)
+    z = rng.normal(0, 0.05, lead + (out, inp // g)).astype(np.float32)
+    bits = np.unpackbits(packed, axis=-1, bitorder="little")
+    dense = ((2.0 * bits - 1.0) * np.repeat(alpha, g, -1)).sum(-3) \
+        + np.repeat(z, g, -1)
+    return Planes(jnp.asarray(packed), jnp.asarray(alpha), jnp.asarray(z),
+                  g, inp), dense
+
+
+class _ToyArch:
+    """A reference module of a toy decoder: a dense layer is ``x + x W^T``,
+    an MoE layer adds every expert's ``x W_e^T`` weighted by a softmax
+    over its router."""
+
+    @staticmethod
+    def embed(w, tokens, cfg):
+        return jnp.take(w["tok"], tokens, axis=0)
+
+    @staticmethod
+    def block(lw, x, cfg, mode):
+        from bench.reference.common import linear
+        if "router" in lw:
+            gates = jax.nn.softmax(jnp.einsum("bti,ei->bte", x, lw["router"]))
+            ys = jnp.einsum("bti,eoi->bteo", x, lw["experts"])
+            return x + jnp.einsum("bte,bteo->bto", gates, ys)
+        return x + linear(x, lw["w"], mode)
+
+    @staticmethod
+    def final(w, x, cfg):
+        return x * w["final_norm"]["scale"], w["embed"]["tok"]
+
+
+def test_reference_runs_a_dense_prefix_then_a_scanned_expert_group():
+    rng = np.random.default_rng(11)
+    d, v, e, layers, g = 16, 11, 3, 2, 8
+    dense_w, dense_np = _planes(rng, (), 2, d, d, g)
+    bank, bank_np = _planes(rng, (layers, e), 2, d, d, g)
+    router = rng.normal(0, 0.3, (layers, e, d)).astype(np.float32)
+    tok = rng.normal(0, 0.5, (v, d)).astype(np.float32)
+    scale = rng.uniform(0.5, 1.5, d).astype(np.float32)
+    w = {"embed": {"tok": jnp.asarray(tok)},
+         "stack": {"prefix": [{"w": dense_w}],
+                   "scan": [{"experts": bank,
+                             "router": jnp.asarray(router)}]},
+         "final_norm": {"scale": jnp.asarray(scale)}}
+    toks = rng.integers(0, v, (2, 5)).astype(np.int32)
+    tgts = rng.integers(0, v, (2, 5)).astype(np.int32)
+    best, at, top = logit_stats(_ToyArch, w, {"vocab_size": v}, toks, tgts)
+
+    # the plain loop: every layer in order, numpy float64
+    x = tok[toks].astype(np.float64)
+    x = x + x @ dense_np.T
+    for i in range(layers):
+        s = np.einsum("bti,ei->bte", x, router[i])
+        p = np.exp(s - s.max(-1, keepdims=True))
+        p /= p.sum(-1, keepdims=True)
+        x = x + np.einsum("bte,bteo->bto", p,
+                          np.einsum("bti,eoi->bteo", x, bank_np[i]))
+    logits = (x * scale) @ tok.T
+    np.testing.assert_allclose(np.asarray(best), logits.max(-1), rtol=1e-4)
+    np.testing.assert_allclose(
+        np.asarray(at), np.take_along_axis(logits, tgts[..., None], -1)[..., 0],
+        rtol=1e-4)
+    np.testing.assert_array_equal(np.asarray(top), logits.argmax(-1))
 
 
 def test_reference_imports_nothing_of_the_program():

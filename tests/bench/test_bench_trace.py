@@ -3,9 +3,11 @@
 A synthetic trace checks the arithmetic by hand; a small slice of a
 trace recorded on the chip (``data/trace_small.json.gz``, from
 ``bench/tools/trace_look.py``) checks that the real lines and names are
-read: program executions by jitted name, kernels by their names.
+read: program executions by jitted name, kernels by their names.  A
+synthetic tracer checks how engine events become steps.
 """
 import os
+import re
 
 import pytest
 
@@ -50,6 +52,80 @@ def test_programs_and_kernels_by_name():
     attn = [e.op for e in t.ops() if layers.KERNELS["attn"].match(e.op)]
     assert gemm == ["lut_gemm_tiled.2"]
     assert attn == ["paged_attention_tiled.3"]
+
+
+# the kernel families as the first benchmark matched them, name by name
+OLD_KERNELS = {
+    "gemm": re.compile(r"(lut_gemm|bcq_matmul|ternary_matmul)_tiled\b"),
+    "attn": re.compile(r"(paged_attention|paged_prefill)_tiled\b"),
+}
+# the top device ops of both cells' traced runs on the chip, and
+# the kernels the name rule is for
+SEEN = ["bcq_matmul_tiled.59", "constant_dynamic-slice_fusion.42",
+        "constant_dynamic-update-slice_fusion.8", "sort.5",
+        "paged_prefill_tiled.9", "paged_attention_tiled.3",
+        "lut_gemm_tiled.2", "ternary_matmul_tiled.4", "fusion.1",
+        "copy.12", "while.3", "reshape.562"]
+
+
+def _family(op, kernels):
+    return [f for f, pat in kernels.items() if pat.match(op)]
+
+
+def test_kernel_name_rule_keeps_every_op_it_knew(chip):
+    for op in SEEN + [e.op for e in chip.ops()]:
+        assert _family(op, layers.KERNELS) == _family(op, OLD_KERNELS), op
+
+
+@pytest.mark.parametrize("op,family", [
+    ("paged_attention_mla_tiled.7", "attn"),
+    ("bcq_matmul_grouped_tiled.3", "gemm"),
+    ("grouped_bcq_matmul_tiled.2", "gemm"),
+    ("lut_gemm_experts_tiled.5", "gemm")])
+def test_kernel_name_rule_takes_new_kernels(op, family):
+    assert _family(op, layers.KERNELS) == [family]
+
+
+def _tracer_context(events):
+    import time
+    from repro import obs
+    tr = obs.Tracer(clock=lambda: 0.0)
+    for name, ph, ts, args in events:
+        tr.emit(name, ph, ts, "engine/test", dur=1.0 if ph == "X" else None,
+                args=args)
+    ctx = layers.Context(engine={}, linears=[], shape=None,
+                         window=(0.0, 1.0), trace_window=(0.0, 1.0),
+                         device=Trace({}, {}), tracer=tr, recs=[],
+                         kind="TPU v5 lite")
+    zero = ctx.obs_time({"ts": 0.0})
+    assert zero == pytest.approx(time.perf_counter(), abs=60.0)
+    return ctx, zero
+
+
+def test_step_counters_join_their_step():
+    ctx, zero = _tracer_context([
+        ("admit", "i", 0.0, {"uid": 1, "prefill_target": 10}),
+        ("step_counters", "i", 5.0, {"step": 3, "experts_touched": 99}),
+        ("prefill_chunk", "X", 10.0, {"start": 0, "length": 10,
+                                      "bucket": 16, "step": 2}),
+        ("decode_dispatch", "X", 20.0, {"uids": [1], "step": 3}),
+        ("decode_dispatch", "X", 30.0, {"uids": [1], "step": 4}),
+        ("decode_dispatch", "X", 40.0, {"uids": [1]}),
+        ("step_counters", "i", 50.0, {"step": 3, "experts_touched": 40,
+                                      "tick": 7}),
+        ("step_counters", "i", 60.0, {"step": 2, "experts_touched": 300}),
+        ("step_counters", "i", 70.0, {"step": 8, "experts_touched": 1}),
+    ])
+    steps = ctx.steps(zero - 1.0, zero + 1.0)
+    assert [(s.kind, s.ctx) for s in steps] == [
+        ("prefill", [0, 10]), ("decode", [11]), ("decode", [12]),
+        ("decode", [13])]
+    # only later instants join; the tracer's tick is not a counter; a
+    # step with no instant, or no id, has no counters
+    assert [s.counters for s in steps] == [
+        {"experts_touched": 300}, {"experts_touched": 40}, {}, {}]
+    # steps dispatched outside the asked range are left out
+    assert ctx.steps(zero + 25e-6, zero + 1.0) == steps[2:]
 
 
 def test_json_round_trip(tmp_path):
