@@ -3,6 +3,7 @@ entry points, and check what comes out.
 
     python chip_smoke.py                # one chip (what CI on the chip runs)
     python chip_smoke.py --four-chips   # only the TP=4 sharded-serving check
+    python chip_smoke.py --arch deepseek_v2_lite   # another registered arch
 
 One process; the model is built by the launcher's own
 ``repro.launch.serve.build_model`` at OPT-6.7B's published width (32
@@ -27,6 +28,13 @@ line is printed only when every phase passed):
              the same bundles run through the XLA dequant path (``dense``
              backend, f32 weights, ``highest`` matmul precision, gathered
              paged attention).
+
+``--arch`` serves another registered configuration at its published
+width the same way.  For a mixture of experts (DeepSeek-V2-Lite) the
+kernel phase also runs the grouped expert kernel on the first MoE
+layer's gate bank against its oracle; a latent-attention (MLA) model
+prefills on the gathered view (the fused prefill kernel does not
+decompress latents), so only its decode must run fused.
 
 ``--four-chips`` runs only the sharded check: the same model on a 1x4
 ``("data", "model")`` mesh against ``devices[0]``, compared on logits,
@@ -96,13 +104,13 @@ def phase_device(jax):
     return dev
 
 
-def build(seed: int, backend: str = "auto"):
+def build(seed: int, backend: str = "auto", arch: str = "opt_6_7b"):
     import jax
     from repro.configs import get_config
     from repro.launch.serve import build_model
     from repro.quant import QuantSpec
 
-    cfg = get_config("opt_6_7b")
+    cfg = get_config(arch)
     # one alternating-refinement round: the solver's round count changes
     # the weights' quality, not the serving path under test, and each
     # round costs about a minute of chip time at this size
@@ -118,7 +126,8 @@ def build(seed: int, backend: str = "auto"):
 
 
 def layer0_bundles(params) -> dict:
-    """Layer 0's bundle of each of OPT's linear roles."""
+    """Layer 0's bundle of each of the four dense linear roles (attention
+    q and out, MLP up and down; an expert bank is not one)."""
     import jax
     from repro.core.plane import PlaneBundle
     from repro.quant.ptq import _walk
@@ -127,11 +136,61 @@ def layer0_bundles(params) -> dict:
     out = {}
     for path, leaf in _walk(params):
         role = roles.get(tuple(path[-2:]))
-        if role and isinstance(leaf, PlaneBundle):
-            out[role] = jax.tree_util.tree_map(lambda a: a[0], leaf) \
-                if leaf.packed.ndim == 4 else leaf
+        if role and role not in out and isinstance(leaf, PlaneBundle):
+            if "scan" in path:                  # a leading layers axis
+                leaf = jax.tree_util.tree_map(lambda a: a[0], leaf)
+            if leaf.packed.ndim == 3:
+                out[role] = leaf
     check(len(out) == 4, f"found linears {sorted(out)}")
     return out
+
+
+def first_expert_bank(params):
+    """The first MoE layer's routed gate bank ([E, q, out, in/8]), or
+    None for a model without routed experts."""
+    import jax
+    from repro.core.plane import PlaneBundle
+    from repro.quant.ptq import _walk
+    for path, leaf in _walk(params):
+        if tuple(path[-2:]) == ("mlp", "gate") \
+                and isinstance(leaf, PlaneBundle):
+            if "scan" in path:
+                leaf = jax.tree_util.tree_map(lambda a: a[0], leaf)
+            if leaf.packed.ndim == 4:
+                return leaf
+    return None
+
+
+def phase_grouped_kernel(params, seed: int):
+    """The grouped expert kernel on a decode step's assignments (32 rows x
+    top-k over the bank's experts, a few rows per expert)."""
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels.grouped_matmul import (bcq_grouped_matmul,
+                                              expert_layout, gather_rows,
+                                              ref, row_tile, scatter_rows)
+    bank = first_expert_bank(params)
+    if bank is None:
+        return
+    e, a = bank.packed.shape[0], 32 * 6
+    key = jax.random.PRNGKey(seed + 3)
+    x = jax.random.normal(key, (a, bank.in_features), jnp.bfloat16)
+    experts = jax.random.randint(jax.random.fold_in(key, 1), (a,), 0, e)
+
+    def run(x, experts, w):
+        lay = expert_layout(experts, experts >= 0, e, row_tile(a, e))
+        return gather_rows(bcq_grouped_matmul(
+            scatter_rows(x, lay), w, lay, interpret=False,
+            out_dtype=jnp.float32), lay)
+    t0 = time.time()
+    got = jax.block_until_ready(jax.jit(run)(x, experts, bank))
+    dt = time.time() - t0
+    want = ref.grouped_matmul_ref(x, experts, bank)
+    err = rel_err(got, want)
+    log(f"kernel bcq_grouped_matmul gate bank [{e}x{bank.out_features}x"
+        f"{bank.in_features}] rows={a}: max rel err {err:.3e} "
+        f"(tol {KERNEL_TOL:g}) [first call {dt:.1f}s, informational]")
+    check(err <= KERNEL_TOL, f"grouped kernel: rel err {err:.3e}")
 
 
 def phase_kernels(params, seed: int):
@@ -217,9 +276,11 @@ def phase_serve(model, params, seed: int, backend):
     label = backend or f"{model.cfg.quant.backend} (default)"
     t0 = time.time()
     eng = engine(model, params, backend=backend, tracer=tracer)
-    check(eng.decode_path == "fused" and eng.prefill_path == "fused",
+    # MLA prefill decompresses latents on the gathered view
+    want_prefill = "gather" if model.cfg.attention == "mla" else "fused"
+    check(eng.decode_path == "fused" and eng.prefill_path == want_prefill,
           f"{label}: decode path {eng.decode_path}, prefill path "
-          f"{eng.prefill_path} (want fused)")
+          f"{eng.prefill_path} (want fused, {want_prefill})")
     reqs = requests(model.cfg.vocab_size, seed)
     done = eng.run(reqs, max_ticks=2000)
     wall = time.time() - t0
@@ -231,7 +292,7 @@ def phase_serve(model, params, seed: int, backend):
               f"{label}: request {r.uid} got {len(r.out_tokens)} tokens")
     # the paths every step and chunk of the run took, not just the plan
     m = eng.metrics
-    check(m.decode_path == "fused" and m.prefill_path == "fused",
+    check(m.decode_path == "fused" and m.prefill_path == want_prefill,
           f"{label}: ran decode on {dict(m.decode_path_steps)}, prefill on "
           f"{dict(m.prefill_path_chunks)}")
     ev = tracer.events
@@ -312,6 +373,8 @@ def main() -> None:
     ap.add_argument("--four-chips", action="store_true",
                     help="run only the TP=4 sharded-serving check")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--arch", default="opt_6_7b",
+                    help="registered configuration to serve (one chip)")
     args = ap.parse_args()
 
     # heuristic launches only: the tune cache lives outside the checkout
@@ -327,11 +390,12 @@ def main() -> None:
 
     t_start = time.time()
     dev = phase_device(jax)
-    model, params = build(args.seed)
+    model, params = build(args.seed, arch=args.arch)
     if args.four_chips:
         phase_four_chips(model, params, args.seed)
     else:
         phase_kernels(params, args.seed)
+        phase_grouped_kernel(params, args.seed)
         served = phase_serve(model, params, args.seed, None)
         phase_serve(model, params, args.seed, "lut_pallas")
         phase_reference(model, params, served, args.seed)

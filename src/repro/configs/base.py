@@ -65,6 +65,14 @@ class ModelConfig:
     rope_theta: float = 10000.0
     pos: str = "rope"                 # rope | learned | none
 
+    # YaRN rotary scaling (rope_scaling type "yarn"); factor 0 -> none
+    rope_yarn_factor: float = 0.0
+    rope_yarn_original_max_pos: int = 0
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_yarn_mscale: float = 1.0
+    rope_yarn_mscale_all_dim: float = 0.0
+
     # MLA (deepseek-v2 / minicpm3)
     kv_lora_rank: int = 0
     q_lora_rank: int = 0
@@ -80,6 +88,8 @@ class ModelConfig:
     moe_layer_period: int = 1         # MoE every k-th layer
     first_dense_layers: int = 0       # leading dense layers (deepseek)
     capacity_factor: float = 1.25
+    norm_topk_prob: bool = True       # renormalise the top-k gates to sum 1
+    routed_scaling_factor: float = 1.0  # gates times this (deepseek)
 
     # SSM (mamba2 / jamba mamba layers)
     ssm_state: int = 0
@@ -145,6 +155,17 @@ class ModelConfig:
         used to synthesize a spec here were removed after their
         one-release deprecation window — set ``quant=QuantSpec(...)``."""
         return self.quant
+
+    @property
+    def yarn(self) -> Optional[tuple]:
+        """The YaRN parameters ``(factor, original_max_pos, beta_fast,
+        beta_slow, mscale, mscale_all_dim)``, or None without YaRN
+        (scalars, so the config stays hashable)."""
+        if not self.rope_yarn_factor:
+            return None
+        return (self.rope_yarn_factor, self.rope_yarn_original_max_pos,
+                self.rope_yarn_beta_fast, self.rope_yarn_beta_slow,
+                self.rope_yarn_mscale, self.rope_yarn_mscale_all_dim)
 
     @property
     def head_dim_(self) -> int:
@@ -232,6 +253,7 @@ ARCH_IDS = [
     "pixtral_12b", "deepseek_v2_236b", "mixtral_8x7b", "phi4_mini_3_8b",
     "stablelm_1_6b", "qwen1_5_32b", "minicpm3_4b", "mamba2_2_7b",
     "whisper_medium", "jamba_1_5_large_398b", "opt_6_7b",
+    "deepseek_v2_lite",
 ]
 
 

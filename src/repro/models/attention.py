@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.quantized_linear import linear_apply
-from repro.models.layers import apply_rope
+from repro.models.layers import apply_rope, yarn_mscale
 from repro.models.module import ParamDesc
 from repro.parallel.sharding import shard_act
 
@@ -936,6 +936,9 @@ def mla_apply(params, cfg, x, positions, *, cache=None, cache_at=None,
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     lora = cfg.kv_lora_rank
     scale = (dn + dr) ** -0.5
+    if cfg.yarn is not None:
+        # YaRN's temperature enters the softmax twice (DeepSeek-V2)
+        scale *= yarn_mscale(cfg.yarn[0], cfg.yarn[5]) ** 2
 
     # --- queries -----------------------------------------------------
     if cfg.q_lora_rank:
@@ -946,13 +949,13 @@ def mla_apply(params, cfg, x, positions, *, cache=None, cache_at=None,
         q = linear_apply(params["q"], x, backend=backend)
     q = q.reshape(b, s, h, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.yarn)
 
     # --- compressed KV ------------------------------------------------
     kv_a = linear_apply(params["kv_a"], x, backend=backend)   # [B,S,lora+dr]
     ckv = _rms(kv_a[..., :lora], params["kv_a_norm"])
     krope = apply_rope(kv_a[..., lora:][:, :, None, :], positions,
-                       cfg.rope_theta)[:, :, 0, :]            # shared head
+                       cfg.rope_theta, cfg.yarn)[:, :, 0, :]  # shared head
 
     w_kvb = params["kv_b"]
     # split decompression weight into W_uk [H, dn, lora], W_uv [H, dv, lora]

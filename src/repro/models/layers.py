@@ -8,6 +8,8 @@ all ten architectures.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -46,19 +48,67 @@ def norm_apply(params, x, eps=1e-5):
 # ---------------------------------------------------------------------------
 
 
-def rope_freqs(head_dim: int, theta: float) -> jax.Array:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, jnp.float32) / head_dim))
+def rope_freqs(head_dim: int, theta: float, yarn=None) -> jax.Array:
+    """Inverse rotary frequencies [D/2]; with ``yarn`` (a config's
+    ``ModelConfig.yarn``) the YaRN blend of arXiv:2309.00071 as
+    DeepSeek-V2 computes it: dimensions that turn fewer than
+    ``beta_slow`` times over the original context are interpolated by
+    ``factor``, those that turn more than ``beta_fast`` times keep their
+    frequency, and a linear ramp joins the two."""
+    base = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, jnp.float32)
+                            / head_dim))
+    if yarn is None:
+        return base
+    factor, orig, beta_fast, beta_slow = yarn[:4]
+    lo, hi = yarn_correction_range(beta_fast, beta_slow, head_dim, theta,
+                                   orig)
+    if lo == hi:
+        hi += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - lo)
+                    / (hi - lo), 0.0, 1.0)
+    keep = 1.0 - ramp                  # 1: extrapolate (keep the frequency)
+    return base / factor * (1.0 - keep) + base * keep
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: [B, S, H, D] (D even), positions: [B, S] or [S]."""
+def yarn_correction_range(beta_fast: float, beta_slow: float, dim: int,
+                          theta: float, orig: int) -> tuple:
+    """The rotary dimensions ``(low, high)`` between which YaRN ramps from
+    keeping to interpolating frequencies."""
+    def dim_for(turns):
+        return dim * math.log(orig / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    return (max(math.floor(dim_for(beta_fast)), 0),
+            min(math.ceil(dim_for(beta_slow)), dim - 1))
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term ``0.1 mscale ln(factor) + 1``."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def rope_attention_factor(yarn) -> float:
+    """The factor YaRN puts on cos and sin: mscale over mscale_all_dim
+    (1 for DeepSeek-V2, whose two are equal)."""
+    if yarn is None:
+        return 1.0
+    factor, _, _, _, mscale, mscale_all_dim = yarn
+    return yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               yarn=None) -> jax.Array:
+    """x: [B, S, H, D] (D even), positions: [B, S] or [S]; ``yarn`` as
+    :func:`rope_freqs` (None: plain rotary, unchanged)."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                       # [D/2]
+    freqs = rope_freqs(d, theta, yarn)                 # [D/2]
     if positions.ndim == 1:
         positions = positions[None, :]
     ang = positions[..., None].astype(jnp.float32) * freqs  # [B, S, D/2]
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if yarn is not None:
+        f = rope_attention_factor(yarn)
+        cos, sin = cos * f, sin * f
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

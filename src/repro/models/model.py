@@ -214,7 +214,7 @@ class Model:
         if "pos" in params["encoder"]:
             x = x + params["encoder"]["pos"][None, :s].astype(x.dtype)
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
-        x, _ = tfm.stack_apply(params["encoder"]["stack"], enc_cfg, x,
+        x, _, _ = tfm.stack_apply(params["encoder"]["stack"], enc_cfg, x,
                                positions, causal=False,
                                backend=cfg.backend_preference)
         return norm_apply(params["encoder"]["final_norm"], x)
@@ -226,8 +226,9 @@ class Model:
         if cfg.is_encdec:
             enc_out = self.encode(params, batch["frames"])
         x, positions = self._embed(params, batch)
-        x, _ = tfm.stack_apply(params["stack"], cfg, x, positions,
-                               enc_out=enc_out, backend=cfg.backend_preference)
+        x, _, _ = tfm.stack_apply(params["stack"], cfg, x, positions,
+                                  enc_out=enc_out,
+                                  backend=cfg.backend_preference)
         x = norm_apply(params["final_norm"], x)
         return unembed_apply(params["embed"], x, backend=cfg.backend_preference)
 
@@ -277,54 +278,70 @@ class Model:
         if cfg.is_encdec:
             enc_out = self.encode(params, batch["frames"])
         x, positions = self._embed(params, batch, start_pos)
-        x, cache = tfm.stack_apply(params["stack"], cfg, x, positions,
-                                   caches=cache,
-                                   cache_at=positions[:, 0],
-                                   enc_out=enc_out, backend=cfg.backend_preference)
+        x, cache, _ = tfm.stack_apply(params["stack"], cfg, x, positions,
+                                      caches=cache,
+                                      cache_at=positions[:, 0],
+                                      enc_out=enc_out,
+                                      backend=cfg.backend_preference,
+                                      valid=positions >= 0)
         x = norm_apply(params["final_norm"], x[:, -1:])
         logits = unembed_apply(params["embed"], x,
                                backend=cfg.backend_preference)[:, 0, : cfg.vocab_size]
         return logits, cache
 
-    def prefill_chunk(self, params, batch: dict, cache, start_pos, last_idx):
+    def prefill_chunk(self, params, batch: dict, cache, start_pos, last_idx,
+                      counters: bool = False):
         """One chunk of a chunked prefill: tokens [B, C] at absolute
         positions ``start_pos + [0, C)``, writing straight into the
         (typically paged) cache and attending over everything cached so
         far.  ``last_idx`` [B] selects the row's last *real* token
         (chunks are right-padded to a length bucket; padded positions
-        are dead writes).  Returns (logits at last_idx [B, V], cache).
+        are dead writes and route to no expert).  Returns (logits at
+        last_idx [B, V], cache), and the step's counters
+        (``tfm.stack_apply``'s stats) third with ``counters=True``.
         """
         cfg = self.cfg
         x, positions = self._embed(params, batch, start_pos)
-        x, cache = tfm.stack_apply(params["stack"], cfg, x, positions,
-                                   caches=cache, cache_at=positions[:, 0],
-                                   backend=cfg.backend_preference)
-        b = x.shape[0]
+        b, c = positions.shape
         idx = jnp.asarray(last_idx, jnp.int32)
         if idx.ndim == 0:
             idx = jnp.broadcast_to(idx, (b,))
+        valid = (positions >= 0) & \
+            (jnp.arange(c, dtype=jnp.int32)[None] <= idx[:, None])
+        x, cache, stats = tfm.stack_apply(params["stack"], cfg, x, positions,
+                                          caches=cache,
+                                          cache_at=positions[:, 0],
+                                          backend=cfg.backend_preference,
+                                          valid=valid)
         x = x[jnp.arange(b), idx][:, None]               # [B, 1, d]
         x = norm_apply(params["final_norm"], x)
         logits = unembed_apply(params["embed"], x,
                                backend=cfg.backend_preference)[:, 0, : cfg.vocab_size]
-        return logits, cache
+        return (logits, cache, stats) if counters else (logits, cache)
 
     def decode_and_sample(self, params, tokens, cache, pos, keys,
-                          temperature, top_k):
+                          temperature, top_k, counters: bool = False):
         """Fused decode + on-device sampling: one decode step followed by
         :func:`sample_tokens`, so only the sampled token ids (int32 [B])
         ever cross the host boundary — the async serving engine jits this
         instead of ``decode_step`` and defers the host sync by a full
         tick.  ``keys`` are per-row uint32 [B, 2] PRNG keys; rows with
         ``temperature <= 0`` ignore their key (greedy argmax).  Returns
-        (token ids int32 [B], cache).
+        (token ids int32 [B], cache), and the step's counters third with
+        ``counters=True``.
         """
-        logits, cache = self.decode_step(params, tokens, cache, pos)
-        return sample_tokens(logits, keys, temperature, top_k), cache
+        logits, cache, stats = self.decode_step(params, tokens, cache, pos,
+                                                counters=True)
+        toks = sample_tokens(logits, keys, temperature, top_k)
+        return (toks, cache, stats) if counters else (toks, cache)
 
-    def decode_step(self, params, tokens, cache, pos):
+    def decode_step(self, params, tokens, cache, pos,
+                    counters: bool = False):
         """One decode step. tokens: [B, 1]; pos: scalar or [B] absolute
-        position of the new token. Returns (logits [B, V], cache)."""
+        position of the new token. Returns (logits [B, V], cache), and
+        the step's counters third with ``counters=True``.  A row of a
+        paged cache whose block table is empty (an idle engine row)
+        routes to no expert."""
         cfg = self.cfg
         b = tokens.shape[0]
         pos_arr = jnp.asarray(pos, jnp.int32)
@@ -334,13 +351,26 @@ class Model:
         x = embed_apply(params["embed"], tokens,
                         jnp.maximum(positions, 0)
                         if cfg.pos == "learned" else None)
-        x, cache = tfm.stack_apply(params["stack"], cfg, x, positions,
-                                   caches=cache, cache_at=pos_arr,
-                                   backend=cfg.backend_preference)
+        valid = (positions >= 0) & _live_rows(cache, b)[:, None]
+        x, cache, stats = tfm.stack_apply(params["stack"], cfg, x, positions,
+                                          caches=cache, cache_at=pos_arr,
+                                          backend=cfg.backend_preference,
+                                          valid=valid)
         x = norm_apply(params["final_norm"], x)
         logits = unembed_apply(params["embed"], x,
                                backend=cfg.backend_preference)[:, 0, : cfg.vocab_size]
-        return logits, cache
+        return (logits, cache, stats) if counters else (logits, cache)
+
+
+def _live_rows(cache, batch: int):
+    """bool [B]: rows that hold a sequence -- in a paged cache, those
+    whose block table has a first block (the engine parks idle rows on
+    an all-empty table); every row of a contiguous cache."""
+    for path, leaf in jax.tree_util.tree_leaves_with_path(cache):
+        if getattr(path[-1], "key", None) == "block_tables":
+            tables = leaf.reshape(-1, *leaf.shape[-2:])[0]   # any layer's
+            return tables[:, 0] >= 0
+    return jnp.ones((batch,), bool)
 
 
 # ---------------------------------------------------------------------------

@@ -1,24 +1,45 @@
-"""Mixture-of-Experts: top-k router + capacity-based scatter dispatch.
+"""Mixture-of-Experts: top-k router, dropless grouped dispatch for
+serving, capacity-based scatter dispatch for training.
 
-Dataflow (dropless-style with a fixed per-expert capacity so shapes stay
-static for pjit):
+Routing (both paths): router logits -> softmax -> top-k; the k gates are
+renormalised to sum 1 only where the config says so (``norm_topk_prob``;
+DeepSeek-V2 does not) and scaled by ``routed_scaling_factor``.  Shared
+experts (deepseek-style) run on every token as dense linears.
 
-  1. router logits -> softmax -> top-k (gates renormalized over the k),
-  2. each (token, k) assignment gets a *position inside its expert* via a
+Serving (:func:`moe_dropless`: every step that passes the stack a
+``valid`` mask, as the engines' prefill and decode do) is dropless:
+every valid (token, k) assignment is sorted by expert into whole row
+tiles (``kernels.grouped_matmul.expert_layout``), the expert FFN runs
+once over the sorted rows, and the outputs are scatter-added back to
+their tokens weighted by gate.  There is no capacity, so no
+assignment is dropped, and padding tokens (idle decode rows, the pad of
+a prefill bucket) are not routed.  A BCQ expert bank runs through the
+grouped BCQ kernel, which reads only the planes of experts that got
+rows; any other bank (dense, or a backend without a Pallas kernel)
+through ``jax.lax.ragged_dot`` over the sorted rows.  The layer also
+returns each expert's valid rows, which the engine turns into a step's
+``experts_touched`` and ``expert_load_max`` counters.
+
+Training and the no-cache forward (:func:`moe_apply`) keep the GShard
+capacity dispatch, which keeps shapes static and the dispatch
+row-local under pjit:
+
+  1. each (token, k) assignment gets a position inside its expert via a
      cumulative count; assignments beyond capacity C are dropped
      (C = ceil(T * k / E) * capacity_factor),
-  3. expert inputs are gathered into [E, C, d] (scatter by (expert, pos)),
-  4. experts run as a batched einsum over E — with the "experts" logical
-     axis sharded over the model axis this is expert parallelism, and XLA
-     inserts the dispatch all-to-alls,
-  5. outputs are gathered back to token order, weighted by gates, summed
-     over k, and added to shared-expert output (deepseek-style) if present.
+  2. expert inputs are gathered into [E, C, d] (scatter by (expert, pos)),
+  3. experts run as a batched einsum over E — with the "experts" logical
+     axis sharded over the model axis this is expert parallelism,
+  4. outputs are gathered back to token order, weighted by gates, summed
+     over k.
 
 FIGLUT integration: every expert weight is a quantizable linear (the
 bit-plane format is per-2D-matrix, so the stacked [E, f, d] expert bank is
 quantized per expert by ``repro.quant.ptq``).
 """
 from __future__ import annotations
+
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -72,6 +93,105 @@ def _expert_bank(w, shape3d):
     return w
 
 
+def route(router, cfg, x):
+    """Gates and experts [..., k] of each token: softmax over the experts,
+    top-k, renormalised only under ``norm_topk_prob``, times
+    ``routed_scaling_factor``."""
+    logits = jnp.einsum("...d,ed->...e", x.astype(jnp.float32), router,
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, experts = jax.lax.top_k(probs, cfg.experts_per_token)
+    if cfg.norm_topk_prob:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    return gates * cfg.routed_scaling_factor, experts
+
+
+def _shared(params, x, backend):
+    from repro.core.quantized_linear import linear_apply
+    sg = linear_apply(params["shared_gate"], x, backend=backend)
+    su = linear_apply(params["shared_up"], x, backend=backend)
+    sh = jax.nn.silu(sg.astype(jnp.float32)).astype(x.dtype) * su
+    return linear_apply(params["shared_down"], sh,
+                        backend=backend).astype(jnp.float32)
+
+
+def _grouped_kernel_backend(bank, backend) -> Optional[bool]:
+    """For a BCQ expert bank: whether the grouped Pallas kernel runs it
+    (``None`` -> not at all, interpret -> ``True`` off the chip).  The
+    bank negotiates like one expert's matrix would: the preference's
+    chain must land on a Pallas plane kernel."""
+    from repro.core.lut_gemm import interpret_mode
+    from repro.quant.backends import get_backend, resolve_backend
+    if not isinstance(bank, BCQWeight) or bank.kind != "bcq" \
+            or bank.z is None or bank.packed.ndim != 4:
+        return None
+    one = BCQWeight(packed=bank.packed[0], alpha=bank.alpha[0], z=bank.z[0],
+                    group_size=bank.group_size,
+                    in_features=bank.in_features,
+                    out_features=bank.out_features, kind=bank.kind)
+    if get_backend(resolve_backend(backend, one)).kernel is None:
+        return None
+    return interpret_mode()
+
+
+def _swiglu(rows, mm):
+    """The routed SwiGLU over expert-sorted rows; ``mm(x, role)`` is one
+    role's grouped matmul."""
+    g = mm(rows, "gate")
+    u = mm(rows, "up")
+    h = jax.nn.silu(g.astype(jnp.float32)).astype(rows.dtype) * u
+    return mm(h, "down")
+
+
+def moe_dropless(params, cfg, x, valid, backend=None):
+    """The serving dispatch: x [B, S, d], valid bool [B, S] (tokens to
+    route).  Returns (y [B, S, d], load int32 [E]: valid rows each
+    expert ran)."""
+    from repro.kernels.grouped_matmul import (bcq_grouped_matmul,
+                                              expert_layout, gather_rows,
+                                              row_tile, scatter_rows)
+    b, s, d = x.shape
+    e, k = cfg.n_experts, cfg.experts_per_token
+    dt = x.dtype
+    xt = x.reshape(b * s, d)
+    gates, experts = route(params["router"], cfg, xt)      # [T, k]
+    a = b * s * k
+    flat_e = experts.reshape(a).astype(jnp.int32)
+    tok = jnp.arange(a, dtype=jnp.int32) // k
+    ok = valid.reshape(b * s)[tok]
+    interp = _grouped_kernel_backend(params["gate"], backend)
+    if interp is not None:
+        # BCQ banks: whole row tiles per expert, read by the kernel
+        layout = expert_layout(flat_e, ok, e, row_tile(a, e))
+        y_pad = _swiglu(scatter_rows(xt[tok], layout),
+                        lambda r, role: bcq_grouped_matmul(
+                            r, params[role], layout, interpret=interp,
+                            out_dtype=dt))
+        y_a, load = gather_rows(y_pad, layout), layout.load
+    else:
+        # any other bank: rows sorted by expert, the invalid ones last
+        # (past the groups' total, so ragged_dot leaves them zero)
+        key = jnp.where(ok, flat_e, e)
+        order = jnp.argsort(key, stable=True)
+        load = jnp.zeros((e + 1,), jnp.int32).at[key].add(1)[:e]
+        f = cfg.moe_d_ff or cfg.d_ff
+        shapes = {"gate": (e, f, d), "up": (e, f, d), "down": (e, d, f)}
+
+        def mm(r, role):
+            w = _expert_bank(params[role], shapes[role])
+            return jax.lax.ragged_dot(
+                r, jnp.swapaxes(w, 1, 2).astype(dt), load,
+                preferred_element_type=jnp.float32).astype(dt)
+        y_sorted = _swiglu(xt[tok[order]], mm)
+        y_a = jnp.zeros_like(y_sorted).at[order].set(y_sorted)
+    w = jnp.where(ok, gates.reshape(a), 0.0)
+    y = jnp.zeros((b * s, d), jnp.float32).at[tok].add(
+        y_a.astype(jnp.float32) * w[:, None])
+    if "shared_gate" in params:
+        y = y + _shared(params, x, backend).reshape(b * s, d)
+    return y.reshape(b, s, d).astype(dt), load
+
+
 def moe_apply(params, cfg, x, backend=None):
     """x: [B, S, d] -> [B, S, d].  Static shapes throughout (pjit-safe).
 
@@ -91,10 +211,7 @@ def moe_apply(params, cfg, x, backend=None):
     cap = max(4, min(cap, s))
 
     # ---- 1. route ----------------------------------------------------
-    logits = jnp.einsum("bsd,ed->bse", x.astype(jnp.float32), params["router"])
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, experts = jax.lax.top_k(probs, k)               # [B, S, k]
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    gates, experts = route(params["router"], cfg, x)       # [B, S, k]
 
     # ---- 2. positions within (row, expert) via per-row sort ranking ----
     flat_e = experts.reshape(b, n)                         # [B, S*k]
@@ -160,12 +277,7 @@ def moe_apply(params, cfg, x, backend=None):
     y = shard_act(y, ("batch", None, None))
 
     if "shared_gate" in params:
-        from repro.core.quantized_linear import linear_apply
-        sg = linear_apply(params["shared_gate"], x, backend=backend)
-        su = linear_apply(params["shared_up"], x, backend=backend)
-        sh = jax.nn.silu(sg.astype(jnp.float32)).astype(x.dtype) * su
-        y = y + linear_apply(params["shared_down"], sh, backend=backend
-                             ).astype(jnp.float32)
+        y = y + _shared(params, x, backend)
 
     return y.astype(x.dtype)
 

@@ -51,8 +51,12 @@ def block_desc(cfg, kind: str, mlp_kind: str, cross: bool = False,
 
 def block_apply(params, cfg, kind: str, mlp_kind: str, x, positions, *,
                 cache=None, cache_at=None, causal=True, enc_out=None,
-                backend=None):
-    """Returns (x, new_cache); cache is None on the train path."""
+                backend=None, valid=None):
+    """Returns (x, new_cache, load); cache is None on the train path.
+
+    ``valid`` (bool [B, S], the serving steps) routes an MoE layer's
+    valid tokens dropless and returns its per-expert rows as ``load``
+    (int32 [E]); otherwise ``load`` is None."""
     h = norm_apply(params["ln1"], x)
     if kind == "attn":
         mixer = attn.mla_apply if cfg.attention == "mla" else attn.gqa_apply
@@ -86,12 +90,18 @@ def block_apply(params, cfg, kind: str, mlp_kind: str, x, positions, *,
         h = attn.cross_attend(params["cross"], cfg, h, ck, cv, backend=backend)
         x = x + h.astype(x.dtype)
 
+    load = None
     if "mlp" in params:
         h = norm_apply(params["ln2"], x)
-        h = (moe_mod.moe_apply(params["mlp"], cfg, h, backend=backend)
-             if mlp_kind == "moe" else mlp_apply(params["mlp"], h, backend=backend))
+        if mlp_kind == "moe" and valid is not None:
+            h, load = moe_mod.moe_dropless(params["mlp"], cfg, h, valid,
+                                           backend=backend)
+        elif mlp_kind == "moe":
+            h = moe_mod.moe_apply(params["mlp"], cfg, h, backend=backend)
+        else:
+            h = mlp_apply(params["mlp"], h, backend=backend)
         x = x + h.astype(x.dtype)
-    return x, cache
+    return x, cache, load
 
 
 # ---------------------------------------------------------------------------
@@ -170,15 +180,15 @@ def map_stack(desc_or_params, fn_layer, cfg):
 
 
 def _run_block(bparams, cfg, kind, mlpk, x, positions, cache, cache_at,
-               causal, enc_out, backend):
+               causal, enc_out, backend, valid=None):
     if cfg.remat and cache is None:
         from repro.parallel.sharding import shard_act
 
         @functools.partial(jax.checkpoint, prevent_cse=False)
         def fn(bp, x_in):
-            y, _ = block_apply(bp, cfg, kind, mlpk, x_in, positions,
-                               cache=None, cache_at=None, causal=causal,
-                               enc_out=enc_out, backend=backend)
+            y, _, _ = block_apply(bp, cfg, kind, mlpk, x_in, positions,
+                                  cache=None, cache_at=None, causal=causal,
+                                  enc_out=enc_out, backend=backend)
             return y
         # shard the remat stash: the saved per-layer block input is the
         # dominant train-memory term ([L, B, S, d] bf16); sharding its
@@ -186,26 +196,48 @@ def _run_block(bparams, cfg, kind, mlpk, x, positions, cache, cache_at,
         # all-gather per layer in the backward recompute ("act_embed"
         # rule, enabled by the train launcher).
         x = shard_act(x, ("batch", None, "act_embed"))
-        return fn(bparams, x), None
+        return fn(bparams, x), None, None
     return block_apply(bparams, cfg, kind, mlpk, x, positions, cache=cache,
                        cache_at=cache_at, causal=causal, enc_out=enc_out,
-                       backend=backend)
+                       backend=backend, valid=valid)
+
+
+def _count(stats, load):
+    """Fold one MoE layer's per-expert rows into the step's counters."""
+    if load is None:
+        return stats
+    return {"experts_touched": stats["experts_touched"]
+            + jnp.sum(load > 0).astype(jnp.int32),
+            "expert_load_max": jnp.maximum(stats["expert_load_max"],
+                                           jnp.max(load).astype(jnp.int32))}
 
 
 def stack_apply(params, cfg, x, positions, *, caches=None, cache_at=None,
-                causal=True, enc_out=None, backend=None):
-    """Run the decoder stack; returns (x, new_caches-or-None)."""
+                causal=True, enc_out=None, backend=None, valid=None):
+    """Run the decoder stack; returns (x, new_caches-or-None, stats).
+
+    ``valid`` (bool [B, S]: the tokens a serving step computes for real)
+    turns on dropless MoE routing; ``stats`` then holds the step's
+    ``experts_touched`` (distinct (MoE layer, expert) pairs with a valid
+    row) and ``expert_load_max`` (most rows one expert got in one layer),
+    int32 scalars.  Without MoE layers or ``valid`` it is ``{}``."""
     plan = layer_plan(cfg)
+    stats = {}
+    if valid is not None and any(m == "moe" for _, m in plan):
+        stats = {"experts_touched": jnp.int32(0),
+                 "expert_load_max": jnp.int32(0)}
 
     if "layers" in params:                                   # unrolled
         new = [] if caches is not None else None
         for i, bp in enumerate(params["layers"]):
             c = caches["layers"][i] if caches is not None else None
-            x, c2 = _run_block(bp, cfg, *plan[i], x, positions, c, cache_at,
-                               causal, enc_out, backend)
+            x, c2, load = _run_block(bp, cfg, *plan[i], x, positions, c,
+                                     cache_at, causal, enc_out, backend,
+                                     valid)
+            stats = _count(stats, load)
             if new is not None:
                 new.append(c2)
-        return x, ({"layers": new} if new is not None else None)
+        return x, ({"layers": new} if new is not None else None), stats
 
     pre, period, reps = scan_grouping(cfg)
     new_caches = {} if caches is not None else None
@@ -214,8 +246,10 @@ def stack_apply(params, cfg, x, positions, *, caches=None, cache_at=None,
         outs = []
         for j, bp in enumerate(params["prefix"]):
             c = caches["prefix"][j] if caches is not None else None
-            x, c2 = _run_block(bp, cfg, *plan[j], x, positions, c, cache_at,
-                               causal, enc_out, backend)
+            x, c2, load = _run_block(bp, cfg, *plan[j], x, positions, c,
+                                     cache_at, causal, enc_out, backend,
+                                     valid)
+            stats = _count(stats, load)
             outs.append(c2)
         if new_caches is not None:
             new_caches["prefix"] = outs
@@ -224,21 +258,25 @@ def stack_apply(params, cfg, x, positions, *, caches=None, cache_at=None,
         groups = params["scan"]
         cstacks = caches["scan"] if caches is not None else None
 
-        def body(x_in, layer_slice):
+        def body(carry, layer_slice):
+            y, st = carry
             bps, cs = layer_slice
             new_cs = []
-            y = x_in
             for j in range(period):
                 kind, mlpk = plan[pre + j]
                 cj = cs[j] if cs is not None else None
-                y, c2 = _run_block(bps[j], cfg, kind, mlpk, y, positions,
-                                   cj, cache_at, causal, enc_out, backend)
+                y, c2, load = _run_block(bps[j], cfg, kind, mlpk, y,
+                                         positions, cj, cache_at, causal,
+                                         enc_out, backend, valid)
+                st = _count(st, load)
                 new_cs.append(c2)
-            return y, (new_cs if cs is not None else None)
+            return (y, st), (new_cs if cs is not None else None)
 
         if caches is None:
-            x, _ = jax.lax.scan(lambda c, g: body(c, (g, None)), x, groups)
+            (x, stats), _ = jax.lax.scan(
+                lambda c, g: body(c, (g, None)), (x, stats), groups)
         else:
-            x, cs_new = jax.lax.scan(body, x, (groups, cstacks))
+            (x, stats), cs_new = jax.lax.scan(body, (x, stats),
+                                              (groups, cstacks))
             new_caches["scan"] = cs_new
-    return x, new_caches
+    return x, new_caches, stats

@@ -40,6 +40,7 @@ weight-only-quantized LLM decode.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from collections import deque
 from contextlib import nullcontext as _null_scope
@@ -164,6 +165,28 @@ def supports_paging(cfg) -> bool:
     return (not cfg.is_encdec and not cfg.sliding_window
             and all(cfg.layer_kind(i) == "attn"
                     for i in range(cfg.n_layers)))
+
+
+def step_programs(model: Model):
+    """The engine's three device programs, each returning the step's
+    counters last: ``decode_step`` -> (logits, cache, counters),
+    ``decode_and_sample`` -> (tokens, cache, counters), ``prefill_chunk``
+    -> (logits, cache, counters).  A model with routed experts counts on
+    the device (``Model``'s ``counters=True``: ``experts_touched``,
+    ``expert_load_max``); a dense model's counters are ``{}`` and its
+    programs are the plain ones.  The programs keep the methods' names,
+    which name the jitted programs the benchmark's trace readers match."""
+    counted = bool(model.cfg.n_experts)
+
+    def counting(method):
+        @functools.wraps(method)
+        def program(*args):
+            if counted:
+                return method(*args, counters=True)
+            return (*method(*args), {})
+        return program
+    return (counting(model.decode_step), counting(model.decode_and_sample),
+            counting(model.prefill_chunk))
 
 
 @dataclasses.dataclass
@@ -318,17 +341,19 @@ class PagedServeEngine:
             self._build_sharded(num_blocks, shard_rules)
         else:
             self._attn_scope = _null_scope
-            self._decode = jax.jit(model.decode_step)
-            self._decode_sample = jax.jit(model.decode_and_sample)
-            self._prefill_chunk = jax.jit(model.prefill_chunk)
+            dec, dec_sample, prefill = step_programs(model)
+            self._decode = jax.jit(dec)
+            self._decode_sample = jax.jit(dec_sample)
+            self._prefill_chunk = jax.jit(prefill)
         self._sample_only = jax.jit(sample_tokens)
         self.ticks = 0
+        self.steps = 0                      # device steps dispatched
+        self._counters: list = []           # (step id, device counters)
         self.finished: list = []
 
     def _build_sharded(self, num_blocks: int, shard_rules) -> None:
         """Shard params + KV pool over the mesh and re-jit the device
         entry points with explicit in/out shardings."""
-        import functools
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.models import attention as attn
         from repro.parallel import sharding as shd
@@ -349,24 +374,25 @@ class PagedServeEngine:
         self._attn_scope = functools.partial(
             attn.paged_shard_scope, mesh, tp=self._tp,
             shard_batch=self._shard_batch)
+        dec, dec_sample, prefill = step_programs(model)
         # logits come back replicated: the sync engine samples on the
         # host every tick, so any vocab sharding would be gathered anyway
         self._decode = jax.jit(
-            model.decode_step,
+            dec,
             in_shardings=(p_sh, row_sh, c_sh, vec_sh),
-            out_shardings=(rep, c_sh))
+            out_shardings=(rep, c_sh, rep))
         # fused decode+sample: keys/temperature/top_k ride the batch rows
         # exactly like tokens/pos; the sampled id vector (a few bytes)
         # comes back replicated — it IS the host boundary now
         self._decode_sample = jax.jit(
-            model.decode_and_sample,
+            dec_sample,
             in_shardings=(p_sh, row_sh, c_sh, vec_sh, row_sh, vec_sh,
                           vec_sh),
-            out_shardings=(rep, c_sh))
+            out_shardings=(rep, c_sh, rep))
         self._prefill_chunk = jax.jit(
-            model.prefill_chunk,
+            prefill,
             in_shardings=(p_sh, {"tokens": rep}, c_sh, rep, rep),
-            out_shardings=(rep, c_sh))
+            out_shardings=(rep, c_sh, rep))
 
     # ------------------------------------------------------------------
     def attach_tracer(self, tracer) -> None:
@@ -598,6 +624,31 @@ class PagedServeEngine:
                         f"prefill would write shared block {blk}"
         return plan
 
+    def _next_step(self) -> int:
+        self.steps += 1
+        return self.steps
+
+    def _hold_counters(self, step: int, counters) -> None:
+        """Keep a dispatched step's device counters until a sync finds
+        them computed (only while a tracer records them)."""
+        if self.trace is not obs_trace.NULL:
+            self._counters.append((step, counters))
+
+    def _emit_counters(self, block: bool = False) -> None:
+        """One ``step_counters`` instant per held step whose counters the
+        device has computed (all of them with ``block``): read with the
+        step's outputs, never waited for."""
+        keep = []
+        for step, c in self._counters:
+            leaves = jax.tree_util.tree_leaves(c)
+            if block or all(x.is_ready() for x in leaves):
+                got = {k: int(v) for k, v in jax.device_get(c).items()}
+                self.trace.instant("step_counters", track="engine/sync",
+                                   step=step, **got)
+            else:
+                keep.append((step, c))
+        self._counters = keep
+
     def _masked_tables(self, decode) -> np.ndarray:
         tables = self.tables.copy()
         rows = {seq.row for seq in decode}
@@ -654,14 +705,17 @@ class PagedServeEngine:
             uids = [s.uid for s in plan.decode]
             live, grid = self._kv_pages(tables, posv)
             t_disp = self.clock()
+            step = self._next_step()
             with self.trace.span("decode_dispatch", track="engine/decode",
                                  rows=len(plan.decode),
                                  path=self.decode_path, uids=uids,
-                                 kv_pages_live=live, kv_pages_grid=grid):
+                                 kv_pages_live=live, kv_pages_grid=grid,
+                                 step=step):
                 with self._attn_scope():
-                    logits, self.cache = self._decode(
+                    logits, self.cache, counters = self._decode(
                         self.params, jnp.asarray(tokens), cache,
                         jnp.asarray(posv))
+            self._hold_counters(step, counters)
             # the host blocks HERE, not at dispatch: np.asarray forces
             # the device computation (step_async hides exactly this span
             # behind the next tick's planning and dispatch)
@@ -690,6 +744,7 @@ class PagedServeEngine:
                                        self.rng_seed)
                     self._emit_token(seq, tok)
 
+        self._emit_counters()
         self._tick_metrics()
 
     def _dispatch_prefill(self, pf):
@@ -704,14 +759,16 @@ class PagedServeEngine:
         tables = self.tables[seq.row:seq.row + 1]
         cache = set_block_tables(self.cache, tables)
         live, grid = self._kv_pages(tables, np.array([start + clen - 1]))
+        step = self._next_step()
         with self.trace.span("prefill_chunk", track="engine/prefill",
                              uid=seq.uid, start=start, length=clen,
                              bucket=bucket, kv_pages_live=live,
-                             kv_pages_grid=grid):
+                             kv_pages_grid=grid, step=step):
             with self._attn_scope():
-                logits, self.cache = self._prefill_chunk(
+                logits, self.cache, counters = self._prefill_chunk(
                     self.params, {"tokens": jnp.asarray(toks)}, cache,
                     jnp.int32(start), jnp.int32(clen - 1))
+        self._hold_counters(step, counters)
         self.trace.instant("prefill_chunk", track=req_track(seq.uid),
                            cat="request", uid=seq.uid, start=start,
                            length=clen)
@@ -786,16 +843,19 @@ class PagedServeEngine:
             cache = set_block_tables(self.cache, tables)
             live, grid = self._kv_pages(tables, posv)
             t_disp = self.clock()
+            step = self._next_step()
             with self.trace.span("decode_dispatch", track="engine/decode",
                                  rows=len(plan.decode), mode="async",
                                  path=self.decode_path,
                                  uids=[s.uid for s in plan.decode],
-                                 kv_pages_live=live, kv_pages_grid=grid):
+                                 kv_pages_live=live, kv_pages_grid=grid,
+                                 step=step):
                 with self._attn_scope():
-                    cur_tokens, self.cache = self._decode_sample(
+                    cur_tokens, self.cache, counters = self._decode_sample(
                         self.params, inp, cache, jnp.asarray(posv),
                         jnp.asarray(keys), jnp.asarray(temps),
                         jnp.asarray(topks))
+            self._hold_counters(step, counters)
             fused_b, gathered_b = self._kv_bytes(live, grid)
             self.metrics.on_decode_step(len(plan.decode), fused_b,
                                         gathered_b, self.decode_path,
@@ -859,6 +919,7 @@ class PagedServeEngine:
                              uids=prev.uids):
             toks = np.asarray(prev.tokens)
         self.metrics.on_device_interval(prev.t_dispatch, self.clock())
+        self._emit_counters()
         with self.trace.span("emit", track="engine/sample",
                              rows=len(prev.emits), uids=prev.uids):
             for seq, row in prev.emits:
@@ -902,6 +963,7 @@ class PagedServeEngine:
         new work (drain point for the frontend and for mode mixing)."""
         prev, self._inflight = self._inflight, None
         self._sync_prev(prev)
+        self._emit_counters(block=True)
 
     @property
     def has_inflight(self) -> bool:
@@ -963,7 +1025,7 @@ class PagedServeEngine:
         tables[0, :len(blocks)] = blocks
         try:
             with self._attn_scope():
-                logits, self.cache = self._prefill_chunk(
+                logits, self.cache, _ = self._prefill_chunk(
                     self.params,
                     {"tokens": jnp.asarray(np.asarray(prompt, np.int32)[None])},
                     set_block_tables(self.cache, tables[:1]),
@@ -974,7 +1036,7 @@ class PagedServeEngine:
                     tokens[0, 0] = int(continuation[t])
                     posv = np.zeros(self.max_batch, np.int32)
                     posv[0] = n + t
-                    logits, self.cache = self._decode(
+                    logits, self.cache, _ = self._decode(
                         self.params, jnp.asarray(tokens),
                         set_block_tables(self.cache, tables),
                         jnp.asarray(posv))

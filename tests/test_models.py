@@ -65,7 +65,8 @@ class TestArchSmoke:
 @pytest.mark.parametrize("arch", ["phi4_mini_3_8b", "minicpm3_4b",
                                   "mamba2_2_7b", "mixtral_8x7b",
                                   "jamba_1_5_large_398b", "whisper_medium",
-                                  "deepseek_v2_236b", "pixtral_12b"])
+                                  "deepseek_v2_236b", "deepseek_v2_lite",
+                                  "pixtral_12b"])
 @pytest.mark.parametrize("strict_f32", [False, True])
 def test_decode_matches_forward(arch, strict_f32):
     """prefill+decode logits == full-forward logits (teacher forcing).

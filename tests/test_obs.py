@@ -787,3 +787,82 @@ class TestTrajectoryGate:
             data = traj.load(path)
             fails, rows = traj.compare(data, data)
             assert fails == [] and rows, name
+
+
+def _step_events(tracer):
+    spans = [e for e in tracer.events if e["ph"] == "X"
+             and e["name"] in ("decode_dispatch", "prefill_chunk")]
+    counters = [e for e in tracer.events if e["name"] == "step_counters"]
+    return spans, counters
+
+
+@pytest.mark.parametrize("mode", ["sync", "async"])
+def test_step_ids_and_counters_of_a_routed_model(mode):
+    """Every dispatched step's span carries an increasing ``step`` id, and
+    one later ``step_counters`` instant names it with the step's
+    ``experts_touched`` and ``expert_load_max``.  One request on four
+    rows: each decode step routes one real token, so each MoE layer
+    touches exactly ``experts_per_token`` experts with one row each --
+    the three idle rows route nowhere."""
+    import jax
+    from repro.configs import get_reduced
+    from repro.models import Model
+    from repro.serve import PagedServeEngine, Request
+
+    cfg = get_reduced("deepseek_v2_lite").replace(remat=False)
+    model = Model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    tracer = Tracer()
+    eng = PagedServeEngine(model, params, num_blocks=24, block_size=8,
+                           max_batch=4, max_seq_len=64,
+                           prefill_buckets=(16,), tracer=tracer)
+    rng = np.random.default_rng(2)
+    reqs = [Request(uid=0, prompt=rng.integers(0, cfg.vocab_size, (21,)),
+                    max_new_tokens=5)]
+    done = (eng.run_async if mode == "async" else eng.run)(reqs,
+                                                          max_ticks=100)
+    set_active(None)
+    assert len(done) == 1 and done[0].error is None
+    spans, counters = _step_events(tracer)
+    ids = [e["args"]["step"] for e in sorted(spans, key=lambda e: e["ts"])]
+    assert ids == list(range(1, len(spans) + 1))
+    by_step = {e["args"]["step"]: e for e in counters}
+    assert sorted(by_step) == ids and len(counters) == len(ids)
+    moe_layers = sum(cfg.mlp_kind(i) == "moe" for i in range(cfg.n_layers))
+    k = cfg.experts_per_token
+    for span in spans:
+        c = by_step[span["args"]["step"]]
+        assert c["ts"] >= span["ts"] + span["dur"]     # read after the span
+        if span["name"] == "decode_dispatch":
+            assert c["args"]["experts_touched"] == moe_layers * k
+            assert c["args"]["expert_load_max"] == 1
+        else:                      # 16 real tokens of a chunk, then 5
+            n = span["args"]["length"]
+            assert k <= c["args"]["expert_load_max"] <= n
+            assert moe_layers * k <= c["args"]["experts_touched"] \
+                <= moe_layers * cfg.n_experts
+
+
+def test_dense_model_counters_carry_no_moe_keys():
+    import jax
+    from repro.configs import get_reduced
+    from repro.models import Model
+    from repro.serve import PagedServeEngine, Request
+
+    cfg = get_reduced("phi4_mini_3_8b").replace(remat=False)
+    model = Model(cfg)
+    tracer = Tracer()
+    eng = PagedServeEngine(model, model.init(jax.random.PRNGKey(0)),
+                           num_blocks=16, block_size=8, max_batch=2,
+                           max_seq_len=64, prefill_buckets=(16,),
+                           tracer=tracer)
+    rng = np.random.default_rng(4)
+    done = eng.run_async([Request(uid=0, prompt=rng.integers(
+        0, cfg.vocab_size, (9,)), max_new_tokens=3)], max_ticks=50)
+    set_active(None)
+    assert done[0].error is None
+    spans, counters = _step_events(tracer)
+    assert sorted(e["args"]["step"] for e in spans) == \
+        sorted(e["args"]["step"] for e in counters)
+    # the tracer's own tick stamp beside the step id, and nothing else
+    assert all(set(e["args"]) <= {"step", "tick"} for e in counters)

@@ -241,3 +241,56 @@ def test_paged_prefill_compiles_at_cell_shape(compile_for, cell):
         sds((1, 512, heads, HEAD_DIM), jnp.bfloat16), k, v, pos,
         sds((1, CELL_PAGES), jnp.int32), sds((1, 512), jnp.int32))
     assert "tpu_custom_call" in hlo
+
+
+# DeepSeek-V2-Lite's routed experts: (out, in) of gate/up and of down;
+# assignments of a 32-row decode step and of a 512-token prefill chunk
+# (6 experts a token), 64 experts
+EXPERT_SHAPES = [(1408, 2048), (2048, 1408)]
+EXPERT_ASSIGNMENTS = {"decode": 32 * 6, "prefill": 512 * 6}
+
+
+@pytest.mark.parametrize("rows", sorted(EXPERT_ASSIGNMENTS))
+@pytest.mark.parametrize("m,n", EXPERT_SHAPES)
+def test_grouped_expert_kernel_compiles(compile_for, m, n, rows):
+    """The grouped BCQ kernel over a 64-expert 3-bit bank at the row tile
+    the serving path picks (8 rows at decode, 96 at prefill), with the
+    expert-sorted layout around it."""
+    from repro.kernels.grouped_matmul import (bcq_grouped_matmul,
+                                              expert_layout, gather_rows,
+                                              row_tile, scatter_rows)
+    sds = jax.ShapeDtypeStruct
+    a, e = EXPERT_ASSIGNMENTS[rows], 64
+    bank = PlaneBundle(
+        packed=sds((e, BITS, m, n // 8), jnp.uint8),
+        alpha=sds((e, BITS, m, n // GROUP), jnp.float32),
+        z=sds((e, m, n // GROUP), jnp.float32),
+        group_size=GROUP, in_features=n, out_features=m)
+
+    def fn(x, experts, w):
+        lay = expert_layout(experts, experts >= 0, e, row_tile(a, e))
+        return gather_rows(bcq_grouped_matmul(scatter_rows(x, lay), w, lay),
+                           lay)
+    hlo = compile_for(fn, sds((a, n), jnp.bfloat16), sds((a,), jnp.int32),
+                      bank)
+    assert "tpu_custom_call" in hlo
+
+
+def test_paged_mla_decode_compiles_at_cell_shape(compile_for):
+    """The fused MLA absorbed decode at DeepSeek-V2-Lite's widths: 16
+    heads, a 512 latent and a 64 rotary key, 32 rows over 128-page
+    tables of the cell's 40,960-token pool."""
+    from repro.kernels.paged_attention import paged_attention_mla
+    sds = jax.ShapeDtypeStruct
+    blocks, heads, lora, rope = 40960 // BLOCK_SIZE, 16, 512, 64
+    hlo = compile_for(
+        lambda qe, qr, c, k, p, t, qp: paged_attention_mla(
+            qe, qr, c, k, p, t, qp, scale=0.1, interpret=False),
+        sds((CELL_ROWS, heads, lora), jnp.float32),
+        sds((CELL_ROWS, heads, rope), jnp.float32),
+        sds((blocks, BLOCK_SIZE, lora), jnp.bfloat16),
+        sds((blocks, BLOCK_SIZE, rope), jnp.bfloat16),
+        sds((blocks, BLOCK_SIZE), jnp.int32),
+        sds((CELL_ROWS, CELL_PAGES), jnp.int32),
+        sds((CELL_ROWS,), jnp.int32))
+    assert "tpu_custom_call" in hlo
